@@ -35,15 +35,21 @@ def main():
     ap.add_argument("--pp-microbatches", type=int, default=2)
     ap.add_argument("--dtype", default="float32",
                     choices=["float32", "bfloat16"])
-    ap.add_argument("--device", default="auto", choices=["auto", "cpu"])
+    ap.add_argument("--device", default="tpu", choices=["tpu", "cpu"],
+                    help="tpu (default) fails when jax shows no "
+                         "accelerator; cpu pins the CPU backend")
     args = ap.parse_args()
     if args.steps < 1:
         raise SystemExit("--steps must be >= 1")
 
-    import jax
-
     if args.device == "cpu":
         mx.context.pin_platform("cpu")
+    with mx.cpu() if args.device == "cpu" else mx.tpu():
+        train(args)
+
+
+def train(args):
+    import jax
 
     mx.random.seed(0)
     n_dev = args.dp * args.tp * args.pp

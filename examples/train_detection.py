@@ -68,7 +68,9 @@ def main():
     ap.add_argument("--num-classes", type=int, default=1)
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--device", default="auto", choices=["auto", "cpu"])
+    ap.add_argument("--device", default="tpu", choices=["tpu", "cpu"],
+                    help="tpu (default) fails when jax shows no "
+                         "accelerator; cpu pins the CPU backend")
     ap.add_argument("--rec", default=None,
                     help="detection RecordIO (packed det labels) -> "
                          "ImageDetIter input path; SSD only")
@@ -78,7 +80,11 @@ def main():
     args = ap.parse_args()
     if args.device == "cpu":
         mx.context.pin_platform("cpu")
+    with mx.cpu() if args.device == "cpu" else mx.tpu():
+        train(args)
 
+
+def train(args):
     mx.random.seed(0)
     B, S = args.batch_size, args.image_size
     x = nd.array(np.random.RandomState(0).rand(B, 3, S, S)

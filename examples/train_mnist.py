@@ -55,13 +55,18 @@ def main():
     ap.add_argument("--epochs", type=int, default=3)
     ap.add_argument("--batch-size", type=int, default=64)
     ap.add_argument("--lr", type=float, default=0.01)
-    ap.add_argument("--device", default="auto", choices=["auto", "cpu"])
+    ap.add_argument("--device", default="tpu", choices=["tpu", "cpu"],
+                    help="tpu (default) fails when jax shows no "
+                         "accelerator; cpu pins the CPU backend")
     args = ap.parse_args()
     if args.device == "cpu":
         mx.context.pin_platform("cpu")
+    with (mx.cpu() if args.device == "cpu" else mx.tpu()) as ctx:
+        train(args, ctx)
 
+
+def train(args, ctx):
     mx.random.seed(42)
-    ctx = mx.current_context()
     X, y = synthetic_mnist()
     net = build_net(args.network)
     net.initialize(mx.init.Xavier(), ctx=ctx)

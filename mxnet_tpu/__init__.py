@@ -14,6 +14,21 @@ Usage mirrors the reference::
 """
 __version__ = "0.1.0"
 
+import os as _os
+
+import jax as _jax
+
+# The ONE compile-cache rule.  Where JAX_COMPILATION_CACHE_DIR is set, jax
+# has read it and nothing in this tree sets another directory.  Otherwise
+# the persistent compilation cache lives at one fixed path inside the
+# checkout (.gitignore lists it): the path is part of the cache key, so a
+# directory that moves between runs never hits.
+if _jax.config.jax_compilation_cache_dir is None:
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
+
 from .base import MXNetError
 from .context import (Context, cpu, gpu, tpu, cpu_pinned, current_context,
                       num_gpus, num_tpus)

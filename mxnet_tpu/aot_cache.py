@@ -126,11 +126,15 @@ def store(key: str, compiled, meta: Optional[Dict[str, Any]] = None) -> bool:
         return False
 
 
-def load(key: str):
+def load(key: str, platform: Optional[str] = None, device_ids: Tuple = ()):
     """Deserialize the entry under ``key`` -> (loaded_executable, info)
     or (None, info).  ``info['cache_corrupt']`` marks an entry that
     existed but could not be loaded (truncated, garbled, wrong version) —
-    the caller falls back to a fresh compile, which overwrites it."""
+    the caller falls back to a fresh compile, which overwrites it.
+
+    ``device_ids`` are the devices the executable was compiled for (the
+    ids :func:`cache_key` hashes): it loads onto exactly those, where
+    jax's default is every device of the backend."""
     info: Dict[str, Any] = {}
     path = entry_path(key)
     if path is None or not os.path.exists(path):
@@ -147,8 +151,11 @@ def load(key: str):
             raise ValueError("entry metadata mismatch")
         from jax.experimental import serialize_executable as se
 
+        by_id = {int(d.id): d for d in jax.devices(platform)}
         loaded = se.deserialize_and_load(
-            rec["payload"], rec["in_tree"], rec["out_tree"])
+            rec["payload"], rec["in_tree"], rec["out_tree"],
+            backend=platform,
+            execution_devices=[by_id[i] for i in device_ids] or None)
         info["cache_hit"] = True
         info["deserialize_ms"] = round(
             (time.perf_counter() - t0) * 1e3, 3)
@@ -190,7 +197,7 @@ def get_or_compile(jitted, args, fingerprint: str, platform: str,
         return None, {}
     try:
         key = cache_key(fingerprint, platform, mesh_shape, device_ids)
-        compiled, info = load(key)
+        compiled, info = load(key, platform, device_ids)
         if meta_fn is None:
             # only sites that persist structural meta consume it; the
             # others forward info verbatim into compile telemetry

@@ -195,10 +195,11 @@ ENV_VARS: Dict[str, Tuple[str, str]] = {
         "cadence — the host never blocks per token "
         "(serving/engine.py)"),
     "MX_SERVE_FLASH": (
-        "honored", "paged-attention path: 'auto' (default) fuses through "
-        "the Pallas ragged paged kernel only where it compiles natively "
-        "(TPU), 1 forces it (interpret-mode tests), 0 pins the XLA "
-        "gather path — the bitwise-parity path "
+        "honored", "paged-attention path: 'auto' (default) and 0 take the "
+        "XLA gather path — the bitwise-parity path — on every platform; "
+        "1 forces the Pallas ragged paged kernel (interpret-mode tests; "
+        "on a TPU it does not lower through Mosaic and fails at compile "
+        "time until ROADMAP A4 rewrites it page-blocked) "
         "(serving/engine.py _serve_fused)"),
     # serving front door (docs/SERVING.md §Front door / §Sampling /
     # §Prefix cache / §Speculative decoding) — everything defaults OFF

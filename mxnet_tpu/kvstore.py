@@ -358,10 +358,8 @@ class KVStore:
         entry = self._psum_cache.get(key)
         cold = entry is None
         if entry is None:
-            from .parallel.sharding import shard_map_compat
-
             mesh = Mesh(np.array(devices), ("kv",))
-            fn = jax.jit(shard_map_compat(
+            fn = jax.jit(jax.shard_map(
                 lambda x: jax.lax.psum(x, "kv")[0],
                 mesh=mesh, in_specs=P("kv"),
                 out_specs=P(*([None] * len(shape)))))

@@ -39,12 +39,6 @@ class _Cfg(NamedTuple):
     interpret: bool
 
 
-def _interpret() -> bool:
-    from . import use_compiled
-
-    return not use_compiled()
-
-
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
@@ -279,10 +273,12 @@ def flash_attention(q, k, v, causal: bool = False,
     lk = k.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
+    from . import interpret
+
     bq = _pick_block(lq, block_q)
     bk = _pick_block(lk, block_k)
     lq_p, lk_p = _round_up(lq, bq), _round_up(lk, bk)
-    cfg = _Cfg(bool(causal), float(sm_scale), bq, bk, lq, lk, _interpret())
+    cfg = _Cfg(bool(causal), float(sm_scale), bq, bk, lq, lk, interpret())
     pad = lambda x, L: jnp.pad(x, ((0, 0), (0, L - x.shape[1]), (0, 0)))
     qp, kp, vp = pad(q, lq_p), pad(k, lk_p), pad(v, lk_p)
     if return_lse:

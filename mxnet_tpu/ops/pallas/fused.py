@@ -21,13 +21,31 @@ from jax.experimental import pallas as pl
 
 
 def _interpret() -> bool:
-    from . import use_compiled
+    from . import interpret
 
-    return not use_compiled()
+    return interpret()
 
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+# Mosaic double-buffers every blocked operand inside a 16 MiB scoped-VMEM
+# limit (v5e); half of it for the row blocks leaves the kernel's own
+# temporaries their room.
+_BLOCK_VMEM_BYTES = 8 << 20
+
+
+def _row_block(n: int, row_bytes: int) -> int:
+    """Rows per grid step for a row-wise kernel: at most 256, fewer when
+    the rows are wide.  ``row_bytes`` is what one row occupies across all
+    the (bn, C) operands, inputs and outputs, at lane-padded width."""
+    fit = _BLOCK_VMEM_BYTES // (2 * row_bytes) // 8 * 8
+    return max(8, min(256, fit, _round_up(n, 8)))
+
+
+def _lane_bytes(c: int, *dtypes) -> int:
+    return _round_up(c, 128) * sum(jnp.dtype(d).itemsize for d in dtypes)
 
 
 # ---------------------------------------------------------------------------
@@ -49,7 +67,7 @@ def _sce_kernel(ignore_label, x_ref, y_ref, loss_ref):
 
 def _sce_fwd_impl(logits, labels, ignore_label):
     n, c = logits.shape
-    bn = min(256, _round_up(n, 8))
+    bn = _row_block(n, _lane_bytes(c, logits.dtype))
     n_p = _round_up(n, bn)
     x = jnp.pad(logits, ((0, n_p - n), (0, 0)))
     y = jnp.pad(labels.astype(jnp.int32), ((0, n_p - n),))[:, None]
@@ -110,7 +128,7 @@ def _ln_kernel(eps, x_ref, g_ref, b_ref, o_ref, mu_ref, rstd_ref):
 
 def _ln_fwd_impl(x, gamma, beta, eps):
     n, c = x.shape
-    bn = min(256, _round_up(n, 8))
+    bn = _row_block(n, _lane_bytes(c, x.dtype, x.dtype))
     n_p = _round_up(n, bn)
     xp = jnp.pad(x, ((0, n_p - n), (0, 0)))
     out, mu, rstd = pl.pallas_call(
@@ -179,7 +197,7 @@ def _aln_kernel(eps, x_ref, r_ref, g_ref, b_ref, o_ref, mu_ref, rstd_ref):
 
 def _aln_fwd_impl(x, res, gamma, beta, eps):
     n, c = x.shape
-    bn = min(256, _round_up(n, 8))
+    bn = _row_block(n, _lane_bytes(c, x.dtype, res.dtype, x.dtype))
     n_p = _round_up(n, bn)
     xp = jnp.pad(x, ((0, n_p - n), (0, 0)))
     rp = jnp.pad(res, ((0, n_p - n), (0, 0)))

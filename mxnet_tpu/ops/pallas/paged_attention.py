@@ -80,7 +80,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths,
                            sm_scale=None):
     """softmax(q @ K_pages^T * sm_scale) @ V_pages per slot, masked to
     each slot's own ``lengths`` — see the module docstring for shapes."""
-    from . import use_compiled
+    from . import interpret
     from jax.experimental.pallas import tpu as pltpu
 
     S, H, hd = q.shape
@@ -103,7 +103,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths,
         functools.partial(_kernel, ps, P, float(sm_scale)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, hd), q.dtype),
-        interpret=not use_compiled(),
+        interpret=interpret(),
     )
     return call(page_table.reshape(-1).astype(jnp.int32),
                 lengths.astype(jnp.int32), q, k_pool, v_pool)

@@ -62,23 +62,21 @@ def registered_ops():
     return sorted(_KERNELS)
 
 
-def _current_platform() -> str:
-    import jax
-
-    from . import _platform_override
-
-    return _platform_override.get() or jax.default_backend()
-
-
 def substitution(op_name: str,
                  platform: Optional[str] = None) -> Optional[Callable]:
     """The kernel to substitute for ``op_name`` on ``platform`` (default:
-    the platform the current trace targets), or None."""
+    the platform the current trace targets), or None.  None too inside a
+    TPU program that GSPMD partitions over several devices: a Mosaic call
+    cannot be partitioned automatically, so the stock op stays."""
+    from . import gspmd_partitioned, platform as traced_platform
+
     entry = _KERNELS.get(op_name)
     if entry is None:
         return None
-    plat = platform if platform is not None else _current_platform()
-    return entry.fn if plat in entry.platforms else None
+    plat = platform if platform is not None else traced_platform()
+    if plat not in entry.platforms or (plat == "tpu" and gspmd_partitioned()):
+        return None
+    return entry.fn
 
 
 # ---------------------------------------------------------------------------

@@ -43,8 +43,14 @@ class Operator:
         self._jit_cache: Dict[Any, Callable] = {}
         self._bwd_cache: Dict[Any, Callable] = {}
 
-    def jitted(self, attrs: dict) -> Callable:
-        key = canonical_kwargs(attrs)
+    def jitted(self, attrs: dict,
+               platform: Optional[str] = None) -> Callable:
+        """The cached eager jit for this attr combo.  ``platform`` is
+        "cpu" for a host context and None for the process's default
+        backend: an op that picks a Pallas kernel decides at trace time,
+        and jax's trace cache does not see devices, so on a TPU host the
+        host contexts get a jit (and a kernel decision) of their own."""
+        key = (canonical_kwargs(attrs), platform)
         jfn = self._jit_cache.get(key)
         if jfn is None:
             # first sight of this attr combo: typed validation (reference
@@ -56,7 +62,12 @@ class Operator:
 
             @functools.wraps(fn)
             def call(*arrays):
-                return fn(*arrays, **attrs)
+                if platform is None:
+                    return fn(*arrays, **attrs)
+                from .pallas import compute_on
+
+                with compute_on(platform):
+                    return fn(*arrays, **attrs)
 
             import jax
 
@@ -199,9 +210,9 @@ def _invoke_impl(op: Operator, inputs: Sequence, out=None, ctx=None, **attrs):
         import jax
 
         with jax.default_device(ctx.jax_device):
-            outs = op.jitted(attrs)()
+            outs = op.jitted(attrs, _host_platform(ctx))()
     else:
-        jfn = op.jitted(attrs)
+        jfn = op.jitted(attrs, _host_platform(ctx))
         if (op.name == "Embedding" and attrs.get("sparse_grad")
                 and autograd.is_recording()):
             # row_sparse backward: record a custom pullback that yields a
@@ -273,6 +284,11 @@ def _invoke_impl(op: Operator, inputs: Sequence, out=None, ctx=None, **attrs):
         out._set_data(results[0]._data)
         return out
     return results if multi else results[0]
+
+
+def _host_platform(ctx) -> Optional[str]:
+    """``Operator.jitted``'s platform argument for an eager call on ctx."""
+    return "cpu" if ctx.device_type.startswith("cpu") else None
 
 
 def _vjp(jfn, arrays):

@@ -578,8 +578,14 @@ class DataParallelStep:
             names = [n for n, _ in self._param_items]
             shapes = {n: tuple(p.data().shape) for n, p in self._param_items}
             self._shardings = self.plan.rules.shardings(self.mesh, shapes)
+            # a COPY of the block's parameters: the step donates its
+            # params from step 1 on, and device_put hands back the source
+            # buffer (as the whole array on one device, as the source
+            # device's shard on a mesh, whatever may_alias says), which
+            # would leave the Gluon Parameter holding a deleted array
             params = {
-                n: _global_put(p.data()._data, self._shardings[n])
+                n: _global_put(jax.numpy.copy(p.data()._data),
+                               self._shardings[n])
                 for n, p in self._param_items
             }
             if self._optimizer == "sgd":
@@ -593,8 +599,11 @@ class DataParallelStep:
                                     self._shardings[n]) for n in names}
                 z2 = {n: _global_put(np.zeros(shapes[n], np.float32),
                                      self._shardings[n]) for n in names}
-                self.opt_state = (z, z2,
-                                  jax.numpy.zeros((), jax.numpy.int32))
+                # on the mesh like every other leaf: an off-mesh counter
+                # comes back mesh-typed from step 1, and step 2 would
+                # retrace and recompile the whole program
+                self.opt_state = (z, z2, _global_put(
+                    np.zeros((), np.int32), replicated(self.mesh)))
             if self._loss_scale_cfg is not None and \
                     self.scaler_state is None:
                 from ..precision import loss_scale as _ls
@@ -1130,14 +1139,16 @@ class DataParallelStep:
 
         # Pallas kernels must lower for the platform the MESH runs on
         # (a CPU mesh under a TPU default backend needs interpret
-        # mode); the flag is baked in at trace time, so scope the
-        # override around the jit call.
+        # mode), and over several devices this jit leaves partitioning
+        # to GSPMD, which cannot split a Mosaic call; both facts are
+        # baked in at trace time, so scope them around the jit call.
         ring_cm, pp_cm = self._dispatch_scopes(sp_active)
         mesh_platform = next(iter(self.mesh.devices.flat)).platform
         try:
             for s in step_nos:
                 fault.on_dispatch(s)
-            with _pk.compute_on(mesh_platform), ring_cm, pp_cm:
+            with _pk.compute_on(mesh_platform, self.mesh.size > 1), \
+                    ring_cm, pp_cm:
                 run = fn
                 if resolve_aot is not None:
                     aot = resolve_aot(call_args, mesh_platform)
@@ -1878,14 +1889,12 @@ class DataParallelStep:
                     n: place_slot(slot("mom", n), self._shardings[n])
                     for n, _ in self._param_items}
             else:
-                import jax.numpy as jnp
-
                 m = {n: place_slot(slot("mean", n), self._shardings[n])
                      for n, _ in self._param_items}
                 v = {n: place_slot(slot("var", n), self._shardings[n])
                      for n, _ in self._param_items}
-                t = jnp.asarray(int(np.asarray(opt.get("t", 0))),
-                                jnp.int32)
+                t = _global_put(np.asarray(opt.get("t", 0), np.int32),
+                                replicated(self.mesh))
                 opt_state = (m, v, t)
             if self._loss_scale_cfg is not None:
                 from ..precision import loss_scale as _ls

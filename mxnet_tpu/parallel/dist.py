@@ -95,10 +95,7 @@ def init_from_env(force_cpu: Optional[bool] = None) -> bool:
     # the default ("none") makes every multiprocess computation fail with
     # "Multiprocess computations aren't implemented on the CPU backend".
     # Harmless on TPU (the flag only affects CPU client creation).
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # jax versions without the flag pick gloo themselves
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     _initialize_with_retry(coord, int(n), int(rank))
     _initialized = True
     return jax.process_count() > 1

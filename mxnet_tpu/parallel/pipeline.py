@@ -130,8 +130,6 @@ def pipeline_apply(mesh, fn: Callable, stacked_params, x_micro,
             lambda l, sp: jax.device_put(l, NamedSharding(mesh, sp)),
             stacked_params, spec_p)
         x_micro = jax.device_put(x_micro, NamedSharding(mesh, spec_x))
-    from .sharding import shard_map_compat
-
-    fn_sm = shard_map_compat(ranked, mesh=mesh, in_specs=(spec_p, spec_x),
-                             out_specs=spec_x, check_vma=False)
+    fn_sm = jax.shard_map(ranked, mesh=mesh, in_specs=(spec_p, spec_x),
+                          out_specs=spec_x, check_vma=False)
     return fn_sm(stacked_params, x_micro)

@@ -165,10 +165,8 @@ def ring_self_attention(mesh, q, k, v, causal: bool = False,
     """
     from jax.sharding import PartitionSpec as P
 
-    from .sharding import shard_map_compat
-
     spec = P(tuple(batch_axes) if batch_axes else None, axis, None)
     fn = functools.partial(ring_attention, axis_name=axis, causal=causal,
                            sm_scale=sm_scale)
-    return shard_map_compat(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

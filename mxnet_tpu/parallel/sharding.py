@@ -9,32 +9,8 @@ from __future__ import annotations
 import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
-__all__ = ["ShardingRules", "replicated", "shard_batch", "shard_map_compat",
+__all__ = ["ShardingRules", "replicated", "shard_batch",
            "tensor_parallel_plan"]
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, **kwargs):
-    """jax.shard_map across jax versions: 0.4.x only ships it as
-    jax.experimental.shard_map.shard_map (top-level jax.shard_map appeared
-    later), and the replication-check kwarg was renamed check_rep ->
-    check_vma along the way.  Every shard_map in this tree must go through
-    here — calling jax.shard_map directly breaks on the pinned jax."""
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kwargs)
-    except TypeError:
-        for old, new in (("check_rep", "check_vma"),
-                         ("check_vma", "check_rep")):
-            if old in kwargs:
-                kwargs[new] = kwargs.pop(old)
-                break
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kwargs)
 
 
 def _P(*args):

@@ -92,7 +92,5 @@ def ulysses_self_attention(mesh, q, k, v, causal: bool = False,
         return head2seq(out)
 
     spec = P(tuple(batch_axes) if batch_axes else None, axis, None)
-    from .sharding import shard_map_compat
-
-    return shard_map_compat(fn, mesh=mesh, in_specs=(spec,) * 3,
-                            out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)

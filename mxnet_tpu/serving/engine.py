@@ -54,18 +54,14 @@ __all__ = ["ServingAdapter", "TransformerAdapter", "FullPrefixAdapter",
 
 
 def _serve_fused() -> bool:
-    """MX_SERVE_FLASH: 'auto' (default) fuses paged attention through the
-    Pallas kernel only where it compiles natively (TPU); 1 forces it
-    (interpret-mode tests); 0 pins the XLA gather path (the bitwise-
-    parity path)."""
-    raw = os.environ.get("MX_SERVE_FLASH", "auto").lower()
-    if raw in ("0", "false", "off"):
-        return False
-    if raw in ("1", "true", "on"):
-        return True
-    from ..ops import pallas
-
-    return pallas.enabled() and pallas.use_compiled()
+    """MX_SERVE_FLASH: 'auto' (default) and 0 take the XLA gather path (the
+    bitwise-parity path) on every platform; 1 forces the Pallas paged
+    kernel.  The kernel runs interpreted off-TPU (the tests), but Mosaic
+    rejects its head-batched dot and it maps the whole pool as one VMEM
+    block, so on a TPU forcing it fails at compile time and 'auto' does
+    not select it until ROADMAP A4 rewrites it page-blocked."""
+    return os.environ.get("MX_SERVE_FLASH", "auto").lower() in (
+        "1", "true", "on")
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,269 @@
+"""What the chip bring-up decided, checked from the CPU in seconds.
+
+* a TPU context never computes on the host: ``mx.tpu()`` raises without an
+  accelerator;
+* one compile-cache rule: a set JAX_COMPILATION_CACHE_DIR is left alone,
+  unset the cache is one fixed directory inside the checkout;
+* every exported Pallas kernel, and the serving engine's decode step under
+  the decision ``_serve_fused()`` takes, LOWERS for the TPU.  Pallas-to-Mosaic
+  lowering runs in jaxlib, so ``jax.export`` for platform "tpu" exercises it
+  here without libtpu or a chip (this is the test that would have caught the
+  paged kernel's head-batched dot);
+* Mosaic calls are not selected where GSPMD would have to partition them;
+* ``chip_smoke.py`` fails, and prints no result, without a TPU;
+* the fused step neither retraces on step 2 nor shares buffers it donates.
+"""
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import gluon, nd
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.ops import pallas
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BF16 = jnp.bfloat16
+
+
+def _mosaic_calls(fn, *structs, partitioned=False):
+    """Kernel names of the Mosaic calls in ``fn`` lowered for the TPU."""
+    import re
+
+    with pallas.compute_on("tpu", partitioned):
+        exp = jax.export.export(jax.jit(fn), platforms=["tpu"])(*structs)
+    return re.findall(r'kernel_name = "(\w+)"', exp.mlir_module())
+
+
+def _s(shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# ---------------------------------------------------------------------------
+# contexts
+# ---------------------------------------------------------------------------
+def test_accelerator_context_raises_on_cpu_only_process():
+    for ctx in (mx.tpu(), mx.gpu(), mx.tpu(3)):
+        with pytest.raises(MXNetError, match="no accelerator"):
+            ctx.jax_device
+    assert mx.cpu().jax_device.platform == "cpu"
+    assert mx.num_tpus() == 0
+
+
+# ---------------------------------------------------------------------------
+# the compile-cache rule (fresh interpreters: the rule runs at import)
+# ---------------------------------------------------------------------------
+def test_compile_cache_rule():
+    code = ("import jax, mxnet_tpu; "
+            "print(jax.config.jax_compilation_cache_dir)")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_REPO)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    procs = [subprocess.Popen([sys.executable, "-c", code], env=e, cwd="/",
+                              stdout=subprocess.PIPE, text=True)
+             for e in (env, dict(env, JAX_COMPILATION_CACHE_DIR="/some/dir"))]
+    unset, was_set = (p.communicate(timeout=120)[0].strip() for p in procs)
+    assert all(p.returncode == 0 for p in procs)
+    assert unset == os.path.join(_REPO, ".jax_cache")
+    assert was_set == "/some/dir"
+    with open(os.path.join(_REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+# ---------------------------------------------------------------------------
+# kernels lower through Mosaic
+# ---------------------------------------------------------------------------
+def test_flash_attention_lowers_for_tpu_forward_and_backward():
+    def loss(q, k, v):
+        return pallas.flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    q = _s((4, 256, 64))
+    calls = _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert sorted(set(calls)) == ["_dkv_kernel", "_dq_kernel", "_fwd_kernel"]
+
+
+def test_layer_norm_kernels_lower_for_tpu():
+    x, g = _s((64, 768)), _s((768,))
+    assert _mosaic_calls(pallas.layer_norm, x, g, g) == ["_ln_kernel"]
+    assert _mosaic_calls(pallas.add_layer_norm, x, x, g, g) == ["_aln_kernel"]
+
+
+def test_softmax_cross_entropy_lowers_and_sizes_rows_from_width():
+    from mxnet_tpu.ops.pallas import fused
+
+    calls = _mosaic_calls(pallas.softmax_cross_entropy, _s((1024, 30522)),
+                          _s((1024,), jnp.int32))
+    assert calls == ["_sce_kernel"]
+    # the double-buffered (bn, C) block stays inside the budget at the
+    # repo's own vocabulary, and narrow rows keep the 256-row block
+    wide = fused._row_block(1024, fused._lane_bytes(30522, BF16))
+    assert 8 <= wide < 256 and wide % 8 == 0
+    assert 2 * wide * fused._lane_bytes(30522, BF16) <= fused._BLOCK_VMEM_BYTES
+    assert fused._row_block(16384, fused._lane_bytes(768, BF16, BF16)) == 256
+    assert fused._row_block(5, fused._lane_bytes(768, BF16)) == 8
+
+
+def test_paged_kernel_is_not_selected_and_fails_loudly_when_forced(
+        monkeypatch):
+    from mxnet_tpu.serving.engine import _serve_fused
+
+    monkeypatch.delenv("MX_SERVE_FLASH", raising=False)
+    with pallas.compute_on("tpu"):
+        assert _serve_fused() is False
+    monkeypatch.setenv("MX_SERVE_FLASH", "1")
+    assert _serve_fused() is True
+    # forcing it reaches Mosaic lowering, which rejects the head-batched
+    # dot (ROADMAP A4 rewrites the kernel page-blocked)
+    with pytest.raises(Exception, match="(?i)non.contracting|mosaic|lower"):
+        _mosaic_calls(pallas.paged_decode_attention, _s((4, 4, 64),
+                                                        jnp.float32),
+                      _s((9, 16, 4, 64), jnp.float32),
+                      _s((9, 16, 4, 64), jnp.float32),
+                      _s((4, 3), jnp.int32), _s((4,), jnp.int32))
+
+
+def test_serving_decode_step_lowers_for_tpu_with_default_settings(
+        monkeypatch):
+    """The engine as the chip would build it (fused-kernel pass resolved
+    for "tpu", MX_SERVE_FLASH unset): prefill and decode lower for the TPU,
+    with Mosaic layer norms and without the paged kernel."""
+    from mxnet_tpu.models.transformer import Transformer
+    from mxnet_tpu.serving import ServingEngine, TransformerAdapter
+
+    monkeypatch.delenv("MX_SERVE_FLASH", raising=False)
+    monkeypatch.delenv("MX_PALLAS_FUSED", raising=False)
+    net = Transformer(64, units=32, hidden_size=64, num_heads=2,
+                      num_layers=1)
+    net.initialize(mx.init.Xavier())
+    with pallas.compute_on("tpu"):
+        eng = ServingEngine(TransformerAdapter(net, src_max_len=8), slots=4,
+                            page_size=8, max_len=16)
+        assert eng._adapter._resolved_fused() is False
+        assert eng._pipeline.get("fused_kernels") is not None
+    eng._adapter.warmup(eng._ctx)
+    params = tuple(_s(a.shape, a.dtype) for a in eng._params())
+    state = tuple(_s(a.shape, a._data.dtype) for a in eng._state.values())
+    decode = _mosaic_calls(eng._traced(eng._decode_body), params, *state)
+    assert decode and set(decode) <= {"_ln_kernel", "_aln_kernel"}
+
+    def prefill(nds):
+        from mxnet_tpu import ndarray as F
+
+        out = eng._adapter.prefill(F, nds[0])
+        return [out[k] for k in eng._adapter.prefill_names]
+
+    assert _mosaic_calls(eng._traced(prefill), params,
+                         _s((1, 8), jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# Mosaic calls and GSPMD
+# ---------------------------------------------------------------------------
+def test_kernels_are_not_selected_where_gspmd_partitions():
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from mxnet_tpu.ops.pallas import registry
+
+    with pallas.compute_on("tpu"):
+        assert pallas.use_compiled() and not pallas.interpret()
+        assert registry.substitution("LayerNorm") is not None
+    with pallas.compute_on("tpu", partitioned=True):
+        assert not pallas.use_compiled()
+        assert registry.substitution("LayerNorm") is None
+        with pytest.raises(MXNetError, match="shard_map"):
+            pallas.interpret()
+        # the stock op, not a Mosaic call, inside the partitioned program
+        x, g = _s((8, 16, 128)), _s((128,))
+        assert _mosaic_calls(lambda x, g, b: nd.LayerNorm(
+            nd.NDArray(x), nd.NDArray(g), nd.NDArray(b))._data,
+            x, g, g, partitioned=True) == []
+        # a shard_map body is per-device code again
+        seen = []
+        mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
+
+        def body(x):
+            seen.append(pallas.use_compiled())
+            return x
+
+        jax.eval_shape(jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
+                                     out_specs=P("dp")), _s((4, 8)))
+        assert seen == [True]
+    # interpreted kernels (off-TPU) partition like any HLO
+    with pallas.compute_on("cpu", partitioned=True):
+        assert pallas.interpret() is True
+        assert registry.substitution("LayerNorm") is not None
+
+
+def test_host_context_ops_trace_for_the_host():
+    """On a TPU host an eager op on ``mx.cpu()`` must not trace the kernel
+    the default backend would get (the chip's consistency sweep met "Only
+    interpret mode is supported on CPU backend" in LayerNorm): the per-op
+    jit is keyed by the context's platform."""
+    from mxnet_tpu.ops.registry import get_op
+
+    op, attrs = get_op("LayerNorm"), {"axis": -1, "eps": 1e-5}
+    host, default = op.jitted(attrs, "cpu"), op.jitted(attrs, None)
+    assert host is not default and host is op.jitted(attrs, "cpu")
+    x, g = _s((16, 128)), _s((128,))
+    with pallas.compute_on("tpu"):  # the process default, as on the chip
+        on_host = jax.export.export(host, platforms=["cpu"])(x, g, g)
+        on_chip = jax.export.export(default, platforms=["tpu"])(x, g, g)
+    assert "tpu_custom_call" not in on_host.mlir_module()
+    assert 'kernel_name = "_ln_kernel"' in on_chip.mlir_module()
+
+
+# ---------------------------------------------------------------------------
+# the smoke itself
+# ---------------------------------------------------------------------------
+def test_chip_smoke_fails_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, os.path.join(_REPO,
+                                                       "chip_smoke.py")],
+                         env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode != 0
+    assert out.stdout.splitlines()[0] == "platform: cpu"
+    for line in out.stdout.splitlines():
+        with pytest.raises(ValueError):  # no JSON result on any line
+            json.loads(line)
+
+
+# ---------------------------------------------------------------------------
+# what donation and a second compile would have cost on the chip
+# ---------------------------------------------------------------------------
+def test_fused_step_owns_its_params_and_traces_once():
+    from mxnet_tpu.parallel import DataParallelStep, local_mesh
+
+    net = gluon.nn.Dense(4, in_units=8)
+    net.initialize(mx.init.Xavier())
+    step = DataParallelStep(
+        net, gluon.loss.L2Loss(), mesh=local_mesh(devices=jax.devices()[:1]),
+        optimizer="adam", optimizer_params={"learning_rate": 1e-2})
+    x, y = nd.ones((2, 8)), nd.ones((2, 4))
+    for _ in range(3):
+        step.step(x, y)
+    step.drain()
+    # Adam's step counter starts on the mesh: step 2 reuses step 1's
+    # executable (on the chip a retrace doubled BERT's compile time)
+    assert step._jitted._cache_size() == 1
+    # the step donates its params on an accelerator; a buffer shared with
+    # the block would leave the Gluon Parameter deleted after step 1 (the
+    # four-chip run met exactly that: device_put aliases the source
+    # device's shard of a replicated array)
+    mine = {p.data()._data.unsafe_buffer_pointer()
+            for p in net.collect_params().values()}
+
+    def held(s):
+        return {sh.data.unsafe_buffer_pointer() for a in s.params.values()
+                for sh in a.addressable_shards}
+
+    assert not mine & held(step)
+    step4 = DataParallelStep(
+        net, gluon.loss.L2Loss(), mesh=local_mesh(devices=jax.devices()[:4]))
+    step4._ensure_state((x,))
+    assert len(held(step4)) == 4 * len(mine) and not mine & held(step4)

@@ -2,9 +2,9 @@
 the baseline round-trip, and the tier-1 full-tree gate.
 
 The full-tree test at the bottom is the actual invariant: the rules that
-six PRs paid for (no host sync in dispatch bodies, shard_map only via the
-compat shim, perf_counter for durations, no imports in signal handlers,
-registered env vars, ...) fail CI the moment a change breaks them.
+six PRs paid for (no host sync in dispatch bodies, perf_counter for
+durations, no imports in signal handlers, registered env vars, ...) fail CI
+the moment a change breaks them.
 """
 import importlib.util
 import json
@@ -145,36 +145,6 @@ def test_hot_sync_memory_apis_allowed_off_hot_path(tmp_path):
             def on_step_boundary(self, dev):
                 return dev.memory_stats(), jax.live_arrays()
         """, hot_entries=HOT)
-    assert findings == []
-
-
-# ---------------------------------------------------------------------------
-# raw-shard-map
-# ---------------------------------------------------------------------------
-def test_raw_shard_map_import_and_call_flagged(tmp_path):
-    findings, _ = lint_src(tmp_path, """
-        import jax
-        from jax.experimental.shard_map import shard_map
-
-        def f(fn, mesh, spec):
-            return jax.shard_map(fn, mesh=mesh, in_specs=spec,
-                                 out_specs=spec)
-        """)
-    assert rules_of(findings).count("raw-shard-map") >= 2
-
-
-def test_raw_shard_map_allowed_in_shim_home_and_via_compat(tmp_path):
-    findings, _ = lint_src(tmp_path, """
-        from jax.experimental.shard_map import shard_map
-        """, relpath="mxnet_tpu/parallel/sharding.py")
-    assert findings == []
-    findings, _ = lint_src(tmp_path, """
-        from mxnet_tpu.parallel.sharding import shard_map_compat
-
-        def f(fn, mesh, spec):
-            return shard_map_compat(fn, mesh=mesh, in_specs=spec,
-                                    out_specs=spec)
-        """)
     assert findings == []
 
 

@@ -67,7 +67,7 @@ def test_check_numeric_gradient_detects_wrong_grad():
 
 def test_check_consistency_cpu_contexts():
     """Same computation across contexts (cpu vs cpu here; the tpu row runs
-    under the real-chip environment via test_tpu_consistency.py)."""
+    on a TPU host via tools/check_consistency.py)."""
     inputs = [np.random.RandomState(0).rand(4, 6).astype(np.float32)]
 
     def fn(x):

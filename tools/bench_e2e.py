@@ -6,8 +6,8 @@ the coupling the reference's ImageRecordIter + executor pipeline provides
 (SURVEY §3.6), which per-component benches (bench_io.py, bench.py) can't
 see.
 
-    python tools/bench_e2e.py                    # CPU sanity shapes
-    python tools/bench_e2e.py --tpu --crop 224 --batch-size 256 \
+    python tools/bench_e2e.py --device cpu       # CPU sanity shapes
+    python tools/bench_e2e.py --device tpu --crop 224 --batch-size 256 \
         --model resnet50_v1b --dtype bfloat16    # the real config-2 loop
 
 The step dispatches asynchronously (PjRt), so the host's time splits into
@@ -37,14 +37,10 @@ def main():
     ap.add_argument("--num-classes", type=int, default=100)
     ap.add_argument("--dtype", default="float32",
                     choices=["float32", "bfloat16"])
-    ap.add_argument("--tpu", action="store_true",
-                    help="run the step on the TPU backend (default: CPU)")
+    ap.add_argument("--device", required=True, choices=["cpu", "tpu"],
+                    help="tpu fails when jax shows no accelerator; cpu "
+                         "pins the CPU backend")
     args = ap.parse_args()
-
-    import jax
-
-    if not args.tpu:
-        jax.config.update("jax_platforms", "cpu")
 
     import numpy as np
 
@@ -55,9 +51,9 @@ def main():
     from mxnet_tpu.io import native as native_mod
     from mxnet_tpu.parallel import DataParallelStep, local_mesh
 
-    if not args.tpu:
+    if args.device == "cpu":
         mx.context.pin_platform("cpu")
-    ctx = mx.tpu() if args.tpu else mx.cpu()
+    ctx = mx.cpu() if args.device == "cpu" else mx.tpu()
     mx.context.Context._default_ctx.value = ctx
     mx.random.seed(0)
 
@@ -120,9 +116,7 @@ def main():
         "value": round(n / total, 1), "unit": "images/sec",
         "input_stall_pct": round(100.0 * fetch_s / total, 1),
         "final_loss": round(final, 4),
-        # measured backend, not the requested flag (relay_watch keys off it)
-        "platform": ("cpu" if jax.devices()[0].platform == "cpu" else "tpu"),
-        "requested": "tpu" if args.tpu else "cpu",
+        "platform": ctx.jax_device.platform,
         "native_io": native_mod.available(),
         "model": args.model, "batch": args.batch_size, "crop": args.crop,
         "threads": args.threads,

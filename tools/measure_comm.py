@@ -38,17 +38,15 @@ def main():
     x = jnp.arange(n * elems, dtype=jnp.float32).reshape(n, elems)
     x = jax.device_put(x, NamedSharding(mesh, P("dp", None)))
 
-    from mxnet_tpu.parallel.sharding import shard_map_compat
-
     @jax.jit
     def allreduce(v):
-        return shard_map_compat(
+        return jax.shard_map(
             lambda s: jax.lax.psum(s, "dp"), mesh=mesh,
             in_specs=P("dp", None), out_specs=P(None, None))(v)
 
     @jax.jit
     def allgather(v):
-        return shard_map_compat(
+        return jax.shard_map(
             lambda s: jax.lax.all_gather(s, "dp"), mesh=mesh,
             in_specs=P("dp", None), out_specs=P(None, "dp", None))(v)
 

@@ -16,9 +16,6 @@ full catalog with provenance):
                        step boundaries) reachable from a per-step
                        dispatch body (PR 4: one stray sync stalls the
                        whole async pipeline)
-  raw-shard-map        any shard_map import/call outside
-                       parallel/sharding.py's shard_map_compat shim
-                       (PR 2: raw jax.shard_map fails on the pinned jax)
   wall-clock-duration  subtracting two time.time() reads for a duration
                        (PR 2: wall-clock steps gave negative samples/sec)
   retrace-hazard       jax.jit constructed inside a per-step function, or
@@ -68,7 +65,6 @@ DEFAULT_PATHS = ("mxnet_tpu", "tools", "examples")
 
 RULES = {
     "hot-sync": "host readback reachable from a per-step dispatch body",
-    "raw-shard-map": "shard_map outside parallel/sharding.py's compat shim",
     "wall-clock-duration": "time.time() subtraction used as a duration",
     "retrace-hazard": "jax.jit built per step / unhashable static argument",
     "signal-unsafe": "import, lock acquire or open() inside a signal handler",
@@ -166,10 +162,6 @@ JAX_FREE_ENTRIES = {
         "_ReplicaHandler.do_GET", "_ReplicaHandler.do_POST",
         "_RouterHandler.do_GET", "_RouterHandler.do_POST"),
 }
-
-# the shard_map_compat shim's home — the ONLY file allowed to touch
-# jax.shard_map directly
-SHARD_MAP_HOME = "mxnet_tpu/parallel/sharding.py"
 
 # env-unregistered applies where the registry contract always has:
 # the package and the tools (examples set vars, they don't define knobs)
@@ -440,7 +432,6 @@ class FileLint:
             return self.findings
         passes = (
             ("env-unregistered", self.rule_env_unregistered),
-            ("raw-shard-map", self.rule_raw_shard_map),
             ("wall-clock-duration", self.rule_wall_clock_duration),
             ("silent-except", self.rule_silent_except),
             ("signal-unsafe", self.rule_signal_unsafe),
@@ -478,32 +469,6 @@ class FileLint:
                     f"env var {node.value!r} is read/exported here but not "
                     f"registered in mxnet_tpu/env_vars.py ENV_VARS (add an "
                     f"entry with disposition + use-site)")
-
-    # -- raw-shard-map -----------------------------------------------------
-    def rule_raw_shard_map(self):
-        if self.path == SHARD_MAP_HOME:
-            return
-        for node in self.all_nodes:
-            if isinstance(node, ast.ImportFrom) and node.module and \
-                    "shard_map" in node.module:
-                self._emit("raw-shard-map", node.lineno, node.col_offset,
-                           None,
-                           "import of jax shard_map outside "
-                           f"{SHARD_MAP_HOME} — use shard_map_compat "
-                           "(raw jax.shard_map breaks on the pinned jax)")
-            elif isinstance(node, ast.ImportFrom) and node.module:
-                for a in node.names:
-                    if a.name == "shard_map" and \
-                            "sharding" not in node.module:
-                        self._emit("raw-shard-map", node.lineno,
-                                   node.col_offset, None,
-                                   "import of shard_map outside "
-                                   f"{SHARD_MAP_HOME} — use shard_map_compat")
-            if isinstance(node, ast.Attribute) and node.attr == "shard_map":
-                self._emit("raw-shard-map", node.lineno, node.col_offset,
-                           None,
-                           "direct jax.shard_map use — route through "
-                           "parallel/sharding.py shard_map_compat")
 
     # -- wall-clock-duration ----------------------------------------------
     def _is_wall_call(self, node):
