@@ -344,7 +344,7 @@ def kernels_phase(ctx, on_path, fused_paged_attention, rows, units, heads,
         d_bwd = max(delta(a, b) for a, b in zip(got[1], want[1]))
         check(d_fwd < 3e-2 and d_bwd < 3e-2,
               f"flash_attention differs: forward {d_fwd}, backward {d_bwd}")
-        line("flash_attention", ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"),
+        line("flash_attention", ("mx_flash_fwd", "mx_flash_dq", "mx_flash_dkv"),
              f"alone at {tuple(q.shape)} bf16, relative delta to dense "
              f"attention {d_fwd:.1e} forward, {d_bwd:.1e} backward (BERT's "
              f"training step takes the dense path while attention-"
@@ -355,13 +355,13 @@ def kernels_phase(ctx, on_path, fused_paged_attention, rows, units, heads,
         b = jnp.zeros((units,), jnp.bfloat16)
         d_ln = delta(jax.jit(pallas.layer_norm)(x, g, b), ln_ref(x, g, b))
         check(d_ln < 3e-2, f"layer_norm differs by {d_ln}")
-        line("layer_norm", ("_ln_kernel",),
+        line("layer_norm", ("mx_layer_norm_fwd",),
              f"alone at {tuple(x.shape)} bf16, relative delta {d_ln:.1e}")
         d_aln = delta(jax.jit(pallas.add_layer_norm)(x, r, g, b),
                       ln_ref(x.astype(jnp.float32) + r.astype(jnp.float32),
                              g, b))
         check(d_aln < 3e-2, f"add_layer_norm differs by {d_aln}")
-        line("add_layer_norm", ("_aln_kernel",),
+        line("add_layer_norm", ("mx_add_layer_norm_fwd",),
              f"alone at {tuple(x.shape)} bf16, relative delta {d_aln:.1e}")
 
         # exported only; at the repo's own vocabulary, where its row block
@@ -375,7 +375,7 @@ def kernels_phase(ctx, on_path, fused_paged_attention, rows, units, heads,
         d_sce = delta(jax.jit(pallas.softmax_cross_entropy)(logits, labels),
                       want)
         check(d_sce < 1e-3, f"softmax_cross_entropy differs by {d_sce}")
-        line("softmax_cross_entropy", ("_sce_kernel",),
+        line("softmax_cross_entropy", ("mx_softmax_xent",),
              f"alone at {tuple(logits.shape)} bf16, relative delta "
              f"{d_sce:.1e} (exported only: no model calls it)")
 
@@ -456,7 +456,7 @@ def main():
     def bert(c):
         return bert_job(c, bert_base, 30522, 32, 512)
 
-    bert_kernels = ("_ln_kernel",)
+    bert_kernels = ("mx_layer_norm_fwd",)
     bert1 = train_phase("train bert_base bf16 32x512 adam", ctx, one_chip,
                         bert, "adam", {"learning_rate": 1e-4}, steps=5,
                         mosaic_kernels=bert_kernels)

@@ -325,9 +325,11 @@ ENV_VARS: Dict[str, Tuple[str, str]] = {
         "honored", "0 disables span tracing (the nested "
         "span_begin/span_end events threaded through "
         "DataParallelStep.step, kvstore.push_bucketed, FusedUpdater, "
-        "checkpoints, and the async ring) while keeping step events and "
-        "heartbeats; default on whenever the recorder is on "
-        "(telemetry.py spans_enabled)"),
+        "checkpoints, and the async ring: about ten events a step, five "
+        "of them DataParallelStep.step's) while keeping step events and "
+        "heartbeats; default on whenever the recorder is on or a "
+        "jax.profiler session is live, where the spans also land in the "
+        "trace as mx:<name> (telemetry.py spans_enabled)"),
     "MX_TRACE_EXPORT": (
         "honored", "default off; 1/true exports a merged Chrome/Perfetto "
         "trace.json (rank 0) plus per-rank OpenMetrics metrics-<R>.prom "

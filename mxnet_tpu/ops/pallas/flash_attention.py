@@ -100,6 +100,7 @@ def _fwd(cfg: _Cfg, q, k, v):
     nqb = lq // cfg.block_q
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, cfg),
+        name="mx_flash_fwd",
         grid=(n, nqb),
         in_specs=[
             pl.BlockSpec((1, cfg.block_q, d), lambda b, i: (b, i, 0)),
@@ -207,6 +208,7 @@ def _bwd_impl(cfg: _Cfg, q, k, v, out, lse, do):
                                lambda b, i: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, cfg),
+        name="mx_flash_dq",
         grid=(n, lq // cfg.block_q),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, cfg.block_q, d), lambda b, i: (b, i, 0)),
@@ -219,6 +221,7 @@ def _bwd_impl(cfg: _Cfg, q, k, v, out, lse, do):
     dkv_specs[2] = pl.BlockSpec((1, cfg.block_k, d), lambda b, j: (b, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, cfg),
+        name="mx_flash_dkv",
         grid=(n, lk // cfg.block_k),
         in_specs=dkv_specs,
         out_specs=[

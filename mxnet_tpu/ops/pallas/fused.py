@@ -73,6 +73,7 @@ def _sce_fwd_impl(logits, labels, ignore_label):
     y = jnp.pad(labels.astype(jnp.int32), ((0, n_p - n),))[:, None]
     loss = pl.pallas_call(
         functools.partial(_sce_kernel, ignore_label),
+        name="mx_softmax_xent",
         grid=(n_p // bn,),
         in_specs=[pl.BlockSpec((bn, c), lambda i: (i, 0)),
                   pl.BlockSpec((bn, 1), lambda i: (i, 0))],
@@ -133,6 +134,7 @@ def _ln_fwd_impl(x, gamma, beta, eps):
     xp = jnp.pad(x, ((0, n_p - n), (0, 0)))
     out, mu, rstd = pl.pallas_call(
         functools.partial(_ln_kernel, eps),
+        name="mx_layer_norm_fwd",
         grid=(n_p // bn,),
         in_specs=[pl.BlockSpec((bn, c), lambda i: (i, 0)),
                   pl.BlockSpec((1, c), lambda i: (0, 0)),
@@ -203,6 +205,7 @@ def _aln_fwd_impl(x, res, gamma, beta, eps):
     rp = jnp.pad(res, ((0, n_p - n), (0, 0)))
     out, mu, rstd = pl.pallas_call(
         functools.partial(_aln_kernel, eps),
+        name="mx_add_layer_norm_fwd",
         grid=(n_p // bn,),
         in_specs=[pl.BlockSpec((bn, c), lambda i: (i, 0)),
                   pl.BlockSpec((bn, c), lambda i: (i, 0)),

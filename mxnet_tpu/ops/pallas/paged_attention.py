@@ -101,6 +101,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths,
     )
     call = pl.pallas_call(
         functools.partial(_kernel, ps, P, float(sm_scale)),
+        name="mx_paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, hd), q.dtype),
         interpret=interpret(),

@@ -887,12 +887,14 @@ class DataParallelStep:
         `data` may be a single NDArray or a tuple/list of NDArrays for
         multi-input blocks (e.g. the seq2seq Transformer's (src, tgt)).
 
-        With telemetry spans on (docs/OBSERVABILITY.md §Tracing), the call
-        is traced as a ``train_step`` span with ``block_wait`` /
-        ``input_stage`` / ``dispatch`` sub-spans — the per-phase timing
+        With telemetry spans on (the recorder, or a running jax.profiler
+        trace: docs/OBSERVABILITY.md §Tracing), the whole call is a
+        ``train_step`` span with ``block_wait`` / ``input_stage`` /
+        ``step_prep`` / ``dispatch`` sub-spans — the per-phase timing
         ``tools/trace_report.py`` aggregates into the gang-wide step
-        breakdown.  Spans observe only; the computation is bitwise
-        identical with ``MX_TELEMETRY_SPANS=0``.
+        breakdown, and ``mx:<name>`` events in the profiler's trace.
+        Spans observe only; the computation is bitwise identical with
+        ``MX_TELEMETRY_SPANS=0``.
 
         Superstep mode (``MX_SUPERSTEP=K``, docs/PERFORMANCE.md
         §Superstep): ``step()`` transparently buffers the batch and
@@ -914,10 +916,11 @@ class DataParallelStep:
             # MX_SUPERSTEP flipped off mid-run with steps still buffered:
             # land them first so dispatch order matches call order
             self.flush()
-        with telemetry.span("train_step", executor=self._tele_name):
+        with telemetry.span("train_step", executor=self._tele_name,
+                            step_num=self._step_count + 1):
             handle = self._step_impl(data, label)
-        self._book_pending_compile()
-        memwatch.on_step(self._step_count)
+            self._book_pending_compile()
+            memwatch.on_step(self._step_count)
         return handle
 
     def _book_pending_compile(self) -> None:
@@ -977,18 +980,12 @@ class DataParallelStep:
         limit = inflight_limit()
         block_wait_s = 0.0
         if limit > 0:
-            bw0 = time.perf_counter()
-            # wait_span=False: the interval below IS this step's
-            # block_wait span; the inner wait emitting loss_wait over the
-            # same wall would double-count the phase breakdown
-            block_wait_s = self._inflight.make_room(limit,
-                                                    wait_span=False)
-            if block_wait_s > 0.0:
-                # retro span: a non-blocking make_room (the common case
-                # once the pipeline is in steady state with a free slot)
-                # must not pay a begin/end event pair for a 0ms fact
-                telemetry.record_span("block_wait", bw0,
-                                      bw0 + block_wait_s)
+            # a live span, open while the host waits.  wait_span=False:
+            # this IS the step's wait; the inner wait emitting loss_wait
+            # over the same wall would double-count the phase breakdown
+            with telemetry.span("block_wait"):
+                block_wait_s = self._inflight.make_room(limit,
+                                                        wait_span=False)
         with telemetry.span("input_stage"):
             data_arrs = tuple(d._data for d in datas)
             label_arr = label._data if isinstance(label, NDArray) else label
@@ -1005,8 +1002,11 @@ class DataParallelStep:
             label_arr, pre = _maybe_put(label_arr, label_sh)
             if pre:
                 overlapped += int(getattr(label_arr, "nbytes", 0))
-        key = _random.next_key()
-        lr_val = np.float32(self._current_lr(self._step_count + 1))
+        # the key draw is an eager device dispatch of its own every step:
+        # under its own span, train_step's self time is Python bookkeeping
+        with telemetry.span("step_prep"):
+            key = _random.next_key()
+            lr_val = np.float32(self._current_lr(self._step_count + 1))
         with telemetry.span("dispatch", step=self._step_count + 1,
                             traced=traced):
             scaled = self.scaler_state is not None
@@ -1339,13 +1339,15 @@ class DataParallelStep:
         if group is self._open_group:
             self._open_group = None
         with telemetry.span("train_step", executor=self._tele_name,
-                            superstep=len(group.entries)):
+                            superstep=len(group.entries),
+                            step_num=group.entries[-1]["step"]):
             handle = self._superstep_impl(group)
-        # release the K placed input buffers NOW: loss views (and their
-        # dispatch closures) outlive the group, and retaining an epoch's
-        # worth of staged batches would grow device memory without bound
-        group.entries = []
-        self._book_pending_compile()
+            # release the K placed input buffers NOW: loss views (and
+            # their dispatch closures) outlive the group, and retaining an
+            # epoch's worth of staged batches would grow device memory
+            # without bound
+            group.entries = []
+            self._book_pending_compile()
         return handle
 
     def _superstep_impl(self, group) -> StackedAsyncLoss:
@@ -1366,13 +1368,12 @@ class DataParallelStep:
         limit = inflight_limit()
         block_wait_s = 0.0
         if limit > 0:
-            bw0 = time.perf_counter()
-            block_wait_s = self._inflight.make_room(limit, wait_span=False)
-            if block_wait_s > 0.0:
-                telemetry.record_span("block_wait", bw0,
-                                      bw0 + block_wait_s)
+            with telemetry.span("block_wait"):
+                block_wait_s = self._inflight.make_room(limit,
+                                                        wait_span=False)
         with telemetry.span("input_stage"):
             datas, label_arr, sp_active = self._stack_group(entries)
+        with telemetry.span("step_prep"):
             keys = jnp.stack([e["key"] for e in entries])
             # per-step scalars become SCANNED arrays: an lr schedule
             # steps inside the compiled program exactly as it would

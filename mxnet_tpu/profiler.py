@@ -228,16 +228,18 @@ def _telemetry_rollup_lines() -> str:
 
 
 class scope:
-    """Named annotation scope (reference: profiler.scope)."""
+    """Named annotation scope (reference: profiler.scope): ``mx:<name>`` in
+    a running jax.profiler trace, beside the program's own telemetry
+    spans."""
 
     def __init__(self, name="<unk>", append_mode=False):
         self._name = name
         self._ctx = None
 
     def __enter__(self):
-        import jax
+        from . import telemetry
 
-        self._ctx = jax.profiler.TraceAnnotation(self._name)
+        self._ctx = telemetry.trace_annotation(self._name)
         self._ctx.__enter__()
         return self
 
@@ -254,9 +256,9 @@ class Task:
         self._ctx = None
 
     def start(self):
-        import jax
+        from . import telemetry
 
-        self._ctx = jax.profiler.TraceAnnotation(self.name)
+        self._ctx = telemetry.trace_annotation(self.name)
         self._ctx.__enter__()
 
     def stop(self):

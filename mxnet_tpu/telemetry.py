@@ -57,8 +57,12 @@ clue.  A ``clock_anchor``
 event — a ``(time.time(), perf_counter())`` pair written at enable() and
 re-emitted on every flush — lets the analysis side (``export_chrome_trace``,
 ``tools/trace_report.py``) merge per-rank files onto ONE wall timeline
-despite rank start-time skew.  Spans are on whenever the recorder is on;
-``MX_TELEMETRY_SPANS=0`` is the kill switch.  ``export_chrome_trace(dir)``
+despite rank start-time skew.  Spans are on whenever the recorder is on
+or a ``jax.profiler`` session is live; ``MX_TELEMETRY_SPANS=0`` is the kill
+switch for both.  A live span also lies in the profiler's ``.xplane.pb`` as
+``mx:<name>`` (the device's clock) and in a bounded in-memory store that
+``spans_between(t0, t1)`` reads on ``perf_counter``.
+``export_chrome_trace(dir)``
 merges every rank's stream into a Chrome/Perfetto trace-event JSON (one
 track per rank, spans nested, collectives as flow events);
 ``render_prometheus(mode)`` renders an OpenMetrics exposition of the
@@ -77,10 +81,11 @@ import json
 import logging
 import math
 import os
+import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 __all__ = ["enabled", "enable", "disable", "record", "record_step",
            "record_collective", "record_fused_update", "record_block_wait",
@@ -88,17 +93,24 @@ __all__ = ["enabled", "enable", "disable", "record", "record_step",
            "record_serve_cause", "recent_requests",
            "heartbeat", "note_signature", "summary", "flight_tail", "flush",
            "reset", "rank", "event_path", "heartbeat_path", "RING_SIZE",
-           "span", "record_span", "spans_enabled", "export_chrome_trace",
-           "export_prometheus", "render_prometheus", "health_snapshot",
-           "stale_after_sec"]
+           "span", "record_span", "spans_enabled", "spans_between",
+           "SpanRecord", "SPAN_STORE_SIZE", "TRACE_PREFIX",
+           "trace_annotation", "export_chrome_trace", "export_prometheus",
+           "render_prometheus", "health_snapshot", "stale_after_sec"]
 
 _LOG = logging.getLogger("mxnet_tpu.telemetry")
 
 # flight-recorder depth (in-process ring; the supervisor reads the JSONL
 # file's tail instead, so this only bounds summary()/flight_tail())
 RING_SIZE = 256
+# finished spans kept in memory for spans_between(): a traced benchmark
+# window holds a few hundred, so this bounds a long job, not a reader
+SPAN_STORE_SIZE = 8192
+# what the program's own spans are called in a jax.profiler trace
+# (telemetry.span, mx.profiler.scope/Task): "mx:<name>"
+TRACE_PREFIX = "mx:"
 # nudge the flusher thread awake when this many events are pending, so
-# serialization + disk I/O happen OFF the hot path (span tracing at ~10
+# serialization + disk I/O happen OFF the hot path (span tracing at ~12
 # events/step would otherwise pay an inline flush every dozen steps)
 _FLUSH_PENDING_MAX = 128
 # hard backstop: if the flusher thread cannot keep up (or died), the
@@ -209,6 +221,8 @@ class _State:
         self.retraces: Dict[str, Dict[str, Any]] = {}
         # span name -> {count, total_ms, max_ms}
         self.spans: Dict[str, Dict[str, float]] = {}
+        # finished spans, oldest first out (spans_between)
+        self.finished: deque = deque(maxlen=SPAN_STORE_SIZE)
         self.flusher: Optional[threading.Thread] = None
         # record() sets this when pending crosses _FLUSH_PENDING_MAX so
         # the flusher wakes immediately instead of at its next cadence
@@ -335,15 +349,59 @@ _SPAN_IDS = itertools.count(1)
 _span_local = threading.local()  # per-thread nesting stack of span ids
 
 
+class SpanRecord(NamedTuple):
+    """One finished span as ``spans_between`` returns it: ``t0``/``t1`` on
+    ``time.perf_counter()``, ``parent`` the id of the span that was open
+    on the same thread when this one began (0 for none)."""
+
+    name: str
+    span: int
+    parent: int
+    t0: float
+    t1: float
+
+
+def _profiler_live() -> bool:
+    """True while a ``jax.profiler`` session takes host annotations.  A
+    process that never imported ``jax.profiler`` has none, and is not made
+    to import it."""
+    prof = sys.modules.get("jax.profiler")
+    return prof is not None and prof.TraceAnnotation.is_enabled()
+
+
 def spans_enabled() -> bool:
-    """Spans ride the recorder: on whenever telemetry is on, unless
-    ``MX_TELEMETRY_SPANS=0`` kills them (the knob exists so a production
-    run can keep step events + heartbeats while dropping the ~8 extra
-    events per step the span layer adds)."""
-    if not _state.enabled:
+    """ONE liveness rule: spans are on while the JSONL recorder is on or a
+    ``jax.profiler`` session is live (somebody is tracing the program, so
+    it names its own phases in that trace), unless ``MX_TELEMETRY_SPANS=0``
+    kills them (the knob exists so a production run can keep step events +
+    heartbeats while dropping the ~10 extra events per step the span layer
+    adds: five in ``DataParallelStep.step`` alone, a 0 ms ``block_wait``
+    among them)."""
+    if not (_state.enabled or _profiler_live()):
         return False
     return os.environ.get("MX_TELEMETRY_SPANS", "1").lower() not in (
         "0", "false", "off")
+
+
+_STAT_SEPARATORS = str.maketrans("#,=", "___")
+
+
+def trace_annotation(name: str, **attrs):
+    """The ``jax.profiler`` annotation the program writes for ``name``:
+    ``mx:<name>`` with the scalar attrs as the event's stats; a
+    ``StepTraceAnnotation`` where ``step_num`` is among them, so the
+    profiler's own tools group by step.  Shared by ``span()`` and
+    ``mx.profiler.scope`` / ``Task``."""
+    from jax import profiler
+
+    cls = (profiler.StepTraceAnnotation if "step_num" in attrs
+           else profiler.TraceAnnotation)
+    # the profiler packs an event as "name#k=v,k=v#": a string holding one
+    # of its separators (an executor's "Dense#1") would cut the stats short
+    return cls(TRACE_PREFIX + name,
+               **{k: v.translate(_STAT_SEPARATORS) if isinstance(v, str)
+                  else v for k, v in attrs.items()
+                  if isinstance(v, (bool, int, float, str))})
 
 
 class _NullSpan:
@@ -363,9 +421,25 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _book(name: str, sid: int, parent: int, t0: float,
+          t1: float) -> float:
+    """A finished span into the in-memory store and, with the recorder
+    on, the ``summary()`` aggregates; returns its duration in ms."""
+    dur_ms = (t1 - t0) * 1e3
+    with _state.lock:
+        _state.finished.append(SpanRecord(name, sid, parent, t0, t1))
+        if _state.enabled:
+            agg = _state.spans.setdefault(
+                name, {"count": 0, "total_ms": 0.0, "max_ms": 0.0})
+            agg["count"] += 1
+            agg["total_ms"] += dur_ms
+            agg["max_ms"] = max(agg["max_ms"], dur_ms)
+    return dur_ms
+
+
 class _Span:
     __slots__ = ("_name", "_attrs", "_id", "_t0", "_parent", "_depth",
-                 "_paired")
+                 "_paired", "_ann")
 
     def __init__(self, name: str, attrs: dict, paired: bool):
         self._name = name
@@ -389,7 +463,14 @@ class _Span:
         self._depth = len(stack)
         stack.append(self._id)
         self._t0 = time.perf_counter()
-        if self._paired:
+        # open in the profiler's trace for as long as the region lasts, on
+        # the clock the device's ``XLA Ops`` line is on
+        self._ann = None
+        if _profiler_live():
+            self._ann = trace_annotation(self._name, span=self._id,
+                                         parent=self._parent, **self._attrs)
+            self._ann.__enter__()
+        if self._paired and _state.enabled:
             # mono is THE ordering/merge key (export_chrome_trace aligns
             # it to the gang wall timeline via the clock_anchor events);
             # the event's own "t" stays the wall stamp for humans reading
@@ -401,8 +482,9 @@ class _Span:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         t1 = time.perf_counter()
-        dur_ms = (t1 - self._t0) * 1e3
         stack = getattr(_span_local, "stack", None)
         if stack and stack[-1] == self._id:
             stack.pop()
@@ -410,12 +492,9 @@ class _Span:
             # a nested span leaked past its parent's exit (exception taking
             # a non-local path): unwind to self so nesting self-heals
             del stack[stack.index(self._id):]
-        with _state.lock:
-            agg = _state.spans.setdefault(
-                self._name, {"count": 0, "total_ms": 0.0, "max_ms": 0.0})
-            agg["count"] += 1
-            agg["total_ms"] += dur_ms
-            agg["max_ms"] = max(agg["max_ms"], dur_ms)
+        dur_ms = _book(self._name, self._id, self._parent, self._t0, t1)
+        if not _state.enabled:
+            return False  # only the profiler is live: nothing for the sink
         if self._paired:
             end = dict(name=self._name, span=self._id,
                        tid=threading.get_ident(), mono=round(t1, 6),
@@ -438,29 +517,35 @@ class _Span:
 
 def record_span(name: str, t0: float, t1: float, **attrs) -> None:
     """Retroactively emit one completed span from a measured
-    ``perf_counter`` interval — the zero-cost-when-idle form for hot-path
-    waits that usually DON'T happen (a non-blocking ``make_room``): the
-    caller times the interval with two perf_counter reads and records a
-    span only when it actually waited, instead of paying events per step
-    for a 0ms fact.  Emitted with correct nesting metadata (parent = the
-    caller's current open span) so the merged trace renders it exactly
-    like a ``span()`` region."""
+    ``perf_counter`` interval — for regions whose ends are known only
+    afterwards (the serving engine's per-request ``serve_queue`` /
+    ``serve_prefill`` / ``serve_decode`` tree, built at burst cadence from
+    the request's own stamps).  Emitted with correct nesting metadata
+    (parent = the caller's current open span) so the merged trace renders
+    it exactly like a ``span()`` region.  It reaches the JSONL sink and
+    the in-memory store; a region that is over cannot be opened in a
+    profiler trace, so a wait the host is still in takes ``span()``."""
     if not spans_enabled():
         return
-    dur_ms = (t1 - t0) * 1e3
     sid = next(_SPAN_IDS)
     stack = getattr(_span_local, "stack", None)
     parent = stack[-1] if stack else 0
     depth = len(stack) if stack else 0
+    dur_ms = _book(name, sid, parent, t0, t1)
     record("span", name=name, span=sid, parent=parent, depth=depth,
            tid=threading.get_ident(), mono=round(t0, 6),
            dur_ms=round(dur_ms, 3), **attrs)
+
+
+def spans_between(t0: float, t1: float) -> List[SpanRecord]:
+    """The finished spans lying wholly inside ``[t0, t1]``, by start time.
+    The interval is on ``time.perf_counter()``, the clock a caller stamps
+    its own window with, so a reader needs no anchor.  Only the newest
+    ``SPAN_STORE_SIZE`` finished spans are kept."""
     with _state.lock:
-        agg = _state.spans.setdefault(
-            name, {"count": 0, "total_ms": 0.0, "max_ms": 0.0})
-        agg["count"] += 1
-        agg["total_ms"] += dur_ms
-        agg["max_ms"] = max(agg["max_ms"], dur_ms)
+        kept = [r for r in _state.finished if r.t0 >= t0 and r.t1 <= t1]
+    kept.sort(key=lambda r: (r.t0, r.span))
+    return kept
 
 
 def span(name: str, paired: bool = False, **attrs):
@@ -468,8 +553,14 @@ def span(name: str, paired: bool = False, **attrs):
     id, the parent span's id, nesting ``depth``, the thread id, and the
     monotonic clock — everything ``export_chrome_trace`` /
     ``tools/trace_report.py`` need to rebuild the gang timeline.  Returns
-    a shared no-op object when spans are off, so hot paths pay one env
-    check when disabled.
+    a shared no-op object when spans are off (``spans_enabled``), so hot
+    paths pay two flag reads when nobody listens.
+
+    A live span (one ``_Span``) does three things: it is open in a running
+    ``jax.profiler`` trace as ``mx:<name>`` (``trace_annotation``), it
+    lands in the in-memory store ``spans_between`` reads, and — with the
+    recorder on, and only then — it feeds the JSONL sink and the
+    ``summary()`` aggregates.
 
     By default the whole region lands as ONE complete ``span`` event at
     exit (half the event volume — the per-step hot-path form).

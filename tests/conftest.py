@@ -19,9 +19,12 @@ os.environ["XLA_FLAGS"] = (
 # is imported, so the package sets nothing and the subprocess children (dist
 # workers, examples, launcher tests: most of the suite's wall time) inherit
 # it.  It stays where earlier runs on this box left it warm; the suite is
-# compile-heavy and tier-1 is cut by a timeout.
+# compile-heavy and tier-1 is cut by a timeout.  (Not the directory the
+# suite used up to PR 25, /tmp/mxnet_tpu_test_jax_cache: runs on this box
+# left tiny programs there while a test held jax's thresholds at 0, see
+# _cache_thresholds below.)
 os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/mxnet_tpu_test_jax_cache")
+                      "/tmp/mxnet_tpu_tests_jax_cache")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
 
 import jax
@@ -39,6 +42,27 @@ def _seed():
 
     mx.random.seed(42)
     yield
+
+
+_CACHE_THRESHOLDS = ("jax_persistent_cache_min_compile_time_secs",
+                     "jax_persistent_cache_min_entry_size_bytes")
+
+
+@pytest.fixture(autouse=True)
+def _cache_thresholds():
+    """What a test changes of jax's persistent-cache thresholds ends with
+    the test.  ``benchmark/run.py``'s ``configure_jax()`` stores EVERY
+    program; a test that calls its ``main()`` in this process left that on
+    for the worker's life, so every later test's tiny programs landed in
+    the shared directory.  One of them, loaded back by the next run and then
+    serialized by ``aot_cache.store``, is an executable the CPU cannot run
+    (NOT_FOUND: ... fusion not found; ``test_aot_cache_through_plan_path``
+    failed on every run after the one that stored its step)."""
+    before = [getattr(jax.config, k) for k in _CACHE_THRESHOLDS]
+    yield
+    for key, value in zip(_CACHE_THRESHOLDS, before):
+        if getattr(jax.config, key) != value:
+            jax.config.update(key, value)
 
 
 # ---------------------------------------------------------------------------

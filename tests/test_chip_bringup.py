@@ -85,13 +85,15 @@ def test_flash_attention_lowers_for_tpu_forward_and_backward():
 
     q = _s((4, 256, 64))
     calls = _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
-    assert sorted(set(calls)) == ["_dkv_kernel", "_dq_kernel", "_fwd_kernel"]
+    assert sorted(set(calls)) == ["mx_flash_dkv", "mx_flash_dq",
+                                  "mx_flash_fwd"]
 
 
 def test_layer_norm_kernels_lower_for_tpu():
     x, g = _s((64, 768)), _s((768,))
-    assert _mosaic_calls(pallas.layer_norm, x, g, g) == ["_ln_kernel"]
-    assert _mosaic_calls(pallas.add_layer_norm, x, x, g, g) == ["_aln_kernel"]
+    assert _mosaic_calls(pallas.layer_norm, x, g, g) == ["mx_layer_norm_fwd"]
+    assert _mosaic_calls(pallas.add_layer_norm, x, x, g, g) == [
+        "mx_add_layer_norm_fwd"]
 
 
 def test_softmax_cross_entropy_lowers_and_sizes_rows_from_width():
@@ -99,7 +101,7 @@ def test_softmax_cross_entropy_lowers_and_sizes_rows_from_width():
 
     calls = _mosaic_calls(pallas.softmax_cross_entropy, _s((1024, 30522)),
                           _s((1024,), jnp.int32))
-    assert calls == ["_sce_kernel"]
+    assert calls == ["mx_softmax_xent"]
     # the double-buffered (bn, C) block stays inside the budget at the
     # repo's own vocabulary, and narrow rows keep the 256-row block
     wide = fused._row_block(1024, fused._lane_bytes(30522, BF16))
@@ -150,7 +152,8 @@ def test_serving_decode_step_lowers_for_tpu_with_default_settings(
     params = tuple(_s(a.shape, a.dtype) for a in eng._params())
     state = tuple(_s(a.shape, a._data.dtype) for a in eng._state.values())
     decode = _mosaic_calls(eng._traced(eng._decode_body), params, *state)
-    assert decode and set(decode) <= {"_ln_kernel", "_aln_kernel"}
+    assert decode and set(decode) <= {"mx_layer_norm_fwd",
+                                      "mx_add_layer_norm_fwd"}
 
     def prefill(nds):
         from mxnet_tpu import ndarray as F
@@ -215,7 +218,7 @@ def test_host_context_ops_trace_for_the_host():
         on_host = jax.export.export(host, platforms=["cpu"])(x, g, g)
         on_chip = jax.export.export(default, platforms=["tpu"])(x, g, g)
     assert "tpu_custom_call" not in on_host.mlir_module()
-    assert 'kernel_name = "_ln_kernel"' in on_chip.mlir_module()
+    assert 'kernel_name = "mx_layer_norm_fwd"' in on_chip.mlir_module()
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,8 @@ executable-fingerprint splits on precision config, env parsing, and
 ``Plan.precision`` + scaler state surviving checkpoint save -> elastic
 reshard -> restore.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -51,6 +53,15 @@ def _make_step(precision=None, optimizer="sgd", lr=0.1, mesh=None,
 
 def _host(x):
     return np.asarray(x)
+
+
+def _in_order(params):
+    """``params.items()`` in creation order.  Gluon's name counters are
+    global to the process (``dense9``, then ``dense10``), so a plain sort
+    pairs two nets' layers wrongly whenever a counter gains a digit between
+    them."""
+    return sorted(params.items(), key=lambda kv: [
+        int(t) if t.isdigit() else t for t in re.split(r"(\d+)", kv[0])])
 
 
 # ---------------------------------------------------------------------------
@@ -153,8 +164,8 @@ def test_amp_off_is_bitwise_f32():
     assert a.plan.precision is None and a.scaler_state is None
     # gluon name counters differ between the two nets (dense0 vs dense2);
     # sorted order still pairs corresponding params
-    for (_, arr_a), (_, arr_b) in zip(sorted(a.params.items()),
-                                      sorted(b.params.items())):
+    for (_, arr_a), (_, arr_b) in zip(_in_order(a.params),
+                                      _in_order(b.params)):
         assert np.asarray(arr_a).dtype == np.float32
         np.testing.assert_array_equal(np.asarray(arr_a),
                                       np.asarray(arr_b))
@@ -245,8 +256,8 @@ def test_loss_scale_composes_with_clip_global_norm():
         la = float(a.step(nd.array(x), nd.array(y)))
         lb = float(b.step(nd.array(x), nd.array(y)))
         np.testing.assert_allclose(la, lb, rtol=2e-6)
-    for (_, arr_a), (_, arr_b) in zip(sorted(a.params.items()),
-                                      sorted(b.params.items())):
+    for (_, arr_a), (_, arr_b) in zip(_in_order(a.params),
+                                      _in_order(b.params)):
         np.testing.assert_allclose(_host(arr_a), _host(arr_b),
                                    rtol=2e-5, atol=1e-7)
 
@@ -276,8 +287,8 @@ def test_superstep_scan_carries_scaler_faithfully(monkeypatch):
         assert seq_losses[i] == sup_losses[i], (i, seq_losses, sup_losses)
     for k in ("scale", "growth", "skipped"):
         assert _host(seq.scaler_state[k]) == _host(sup.scaler_state[k]), k
-    for (_, pa), (_, pb) in zip(sorted(seq.params.items()),
-                                sorted(sup.params.items())):
+    for (_, pa), (_, pb) in zip(_in_order(seq.params),
+                                _in_order(sup.params)):
         np.testing.assert_array_equal(_host(pa), _host(pb))
 
 
@@ -342,8 +353,8 @@ def test_scaler_and_precision_survive_elastic_reshard(tmp_path):
     assert float(_host(step2.scaler_state["scale"])) == 32.0
     assert int(_host(step2.scaler_state["growth"])) == \
         int(_host(step.scaler_state["growth"]))
-    for (_, pa), (_, pb) in zip(sorted(step.params.items()),
-                                sorted(step2.params.items())):
+    for (_, pa), (_, pb) in zip(_in_order(step.params),
+                                _in_order(step2.params)):
         np.testing.assert_array_equal(_host(pa), _host(pb))
     # training continues on the new mesh with the restored scale
     v = float(step2.step(nd.array(x), nd.array(y)))

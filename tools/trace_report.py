@@ -8,8 +8,8 @@ human (or the launch.py supervisor, or CI) actually asks after a run:
 
   * **per-step breakdown** — compile vs steady-state step counts and
     wall, and where a steady step's time goes (``dispatch`` /
-    ``input_stage`` / ``block_wait`` / ``loss_wait`` span phases, H2D
-    bytes and how much of them a prefetcher overlapped);
+    ``input_stage`` / ``step_prep`` / ``block_wait`` / ``loss_wait`` span
+    phases, H2D bytes and how much of them a prefetcher overlapped);
   * **per-rank skew table with straggler flagging** — two rules, because
     sync-SGD hides stragglers two different ways:
       - *idle-gap skew* (checked first): wall-clock run span minus time
@@ -475,7 +475,8 @@ def build_report(directory: str, window: Optional[int] = None,
     per_rank = {r: _rank_stats(events, window)
                 for r, events in ranks.items()}
     # gang-wide phase breakdown: where a steady step's time goes
-    phase_names = ("input_stage", "dispatch", "block_wait", "loss_wait")
+    phase_names = ("input_stage", "step_prep", "dispatch", "block_wait",
+                   "loss_wait")
     phases = {}
     steady_total = sum(s["steady_steps"] for s in per_rank.values())
     for name in phase_names:
