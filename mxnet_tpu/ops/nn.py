@@ -534,8 +534,12 @@ def dropout(data, key, p=0.5, mode="training", axes=(), training=False,
     # `axes` = variational dropout: the mask is broadcast along those axes
     shape = [1 if i in axes else data.shape[i] for i in range(data.ndim)]
     keep = 1.0 - p
-    mask = jax.random.bernoulli(key, keep, tuple(shape)).astype(data.dtype)
-    return data * mask / keep
+    # the barrier holds the mask as one value: without it XLA clones the
+    # threefry generator into every fusion that reads the mask, the
+    # backward's matmul fusions among them (PERF.md section 5)
+    mask = jax.lax.optimization_barrier(
+        jax.random.bernoulli(key, keep, tuple(shape)))
+    return data * mask.astype(data.dtype) / keep
 
 
 # ---------------------------------------------------------------------------
