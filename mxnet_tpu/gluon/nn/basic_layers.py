@@ -17,7 +17,7 @@ from ..block import Block, HybridBlock
 from ..parameter import record_aux_update
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
-           "Embedding", "LayerNorm", "GroupNorm", "InstanceNorm", "Flatten",
+           "Embedding", "LayerNorm", "RMSNorm", "GroupNorm", "InstanceNorm", "Flatten",
            "Lambda", "HybridLambda", "Activation", "LeakyReLU", "PReLU",
            "ELU", "SELU", "Swish", "GELU"]
 
@@ -274,6 +274,26 @@ class LayerNorm(HybridBlock):
 
     def hybrid_forward(self, F, x, gamma, beta):
         return F.LayerNorm(x, gamma, beta, axis=self._axis, eps=self._epsilon)
+
+
+class RMSNorm(HybridBlock):
+    """``x / sqrt(mean(x^2) + epsilon) * gamma`` over the last axis (no
+    centring, no offset); computed in float32 whatever the input's type."""
+
+    def __init__(self, epsilon=1e-5, gamma_initializer="ones", in_channels=0,
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                         init=gamma_initializer,
+                                         allow_deferred_init=True)
+
+    def infer_shape(self, x, *args):
+        self.gamma._set_shape_if_deferred((int(x.shape[-1]),))
+
+    def hybrid_forward(self, F, x, gamma):
+        return F._contrib_rms_norm(x, gamma, eps=self._epsilon)
 
 
 class GroupNorm(HybridBlock):

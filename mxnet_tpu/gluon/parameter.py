@@ -181,6 +181,17 @@ class Parameter:
             data._grad_req = self._grad_req
             autograd.register_leaf(data)
 
+    def release_grad(self) -> None:
+        """Free the gradient buffers (as large as the parameter itself).  A
+        compiled step that owns the training state never writes them;
+        ``_init_grad`` brings them back (``DataParallelStep.sync_to_block``
+        does, and so does the first ``grad()``)."""
+        if self._grad is None:
+            return
+        for data in self._data.values():
+            data._grad = None
+        self._grad = None
+
     def _finish_deferred_init(self) -> None:
         if self._deferred is None:
             return
@@ -237,18 +248,21 @@ class Parameter:
         return list(self._data.values())
 
     def grad(self, ctx: Optional[Context] = None):
+        grads = self._live_grad()
+        return grads[next(iter(grads)) if ctx is None else ctx]
+
+    def _live_grad(self) -> OrderedDict:
+        """ctx -> gradient buffer; buffers a compiled step released
+        (``release_grad``) come back here."""
         self._check_initialized()
-        if self._grad is None:
+        if self._grad_req == "null":
             raise MXNetError(f"parameter {self.name} has grad_req='null'")
-        if ctx is None:
-            ctx = next(iter(self._grad))
-        return self._grad[ctx]
+        if self._grad is None:
+            self._init_grad()
+        return self._grad
 
     def list_grad(self) -> List:
-        self._check_initialized()
-        if self._grad is None:
-            raise MXNetError(f"parameter {self.name} has grad_req='null'")
-        return list(self._grad.values())
+        return list(self._live_grad().values())
 
     def list_ctx(self) -> List[Context]:
         self._check_initialized()

@@ -21,3 +21,5 @@ from . import contrib_ops  # noqa: F401
 from . import cv_ops  # noqa: F401
 from . import quantization  # noqa: F401
 from . import warp_ops  # noqa: F401
+from . import ssm_ops  # noqa: F401
+from . import moe_ops  # noqa: F401

@@ -28,7 +28,10 @@ def _dense_attention(q, k, v, causal, sm_scale):
 
 @register("_contrib_flash_attention")
 def flash_attention_op(q, k, v, causal=False, sm_scale=None):
-    """Fused softmax(q k^T) v.  q/k/v: (N, L, D) or (B, H, L, D).
+    """Fused softmax(q k^T) v.  q/k/v: (N, L, D) or (B, H, L, D); with 4-d
+    inputs k/v may carry fewer heads, (B, Hkv, L, D), H a multiple of Hkv
+    (grouped-query attention): the Pallas kernel reads a key-value head for
+    its H / Hkv query heads by index, the dense path repeats K and V.
 
     Pallas blockwise kernel on TPU; dense jnp composition elsewhere
     (XLA still fuses the chain, it just materialises scores).  Inside a
@@ -73,6 +76,9 @@ def flash_attention_op(q, k, v, causal=False, sm_scale=None):
         return _pk.flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
     if q.ndim == 4:
         b, h = q.shape[:2]
+        if k.shape[1] != h:      # grouped-query heads: the dense path repeats
+            k = jnp.repeat(k, h // k.shape[1], axis=1)
+            v = jnp.repeat(v, h // v.shape[1], axis=1)
         out = _dense_attention(q.reshape(b * h, *q.shape[2:]),
                                k.reshape(b * h, *k.shape[2:]),
                                v.reshape(b * h, *v.shape[2:]),

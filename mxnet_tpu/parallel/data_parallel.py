@@ -505,6 +505,12 @@ class DataParallelStep:
             for n, p in self._param_items
         }
 
+        # aux leaves a block marks as router load (models/nemotron_h.py):
+        # read at drain, never inside a step
+        self._moe_load_names = [
+            n for n, p in self._param_items
+            if getattr(p, "telemetry", None) == "moe_load"]
+
         if optimizer not in ("sgd", "adam"):
             raise MXNetError(f"fused step supports sgd/adam, got {optimizer}")
         # per-instance telemetry key: two fused steps over same-class
@@ -588,6 +594,14 @@ class DataParallelStep:
                                self._shardings[n])
                 for n, p in self._param_items
             }
+            if self._donate and next(
+                    iter(self.mesh.devices.flat)).platform != "cpu":
+                # where the step donates, the block is a stale copy from
+                # step 1 on and nothing writes its gradient buffers: free
+                # them (a model's worth of device memory) until
+                # sync_to_block() hands the state back
+                for _, p in self._param_items:
+                    p.release_grad()
             if self._optimizer == "sgd":
                 self.opt_state = {
                     n: _global_put(np.zeros(shapes[n], np.float32),
@@ -1552,6 +1566,22 @@ class DataParallelStep:
         the first deferred failure."""
         self.flush()
         self._inflight.drain()
+        self._record_moe_load()
+
+    def _record_moe_load(self) -> None:
+        """Hand the expert layers' load counters (aux state the steps wrote
+        on the device) to telemetry: a sync, so only here, where every step
+        in flight has just been forced."""
+        if not self._moe_load_names or self.params is None:
+            return
+        import jax
+
+        from .. import telemetry
+
+        values = jax.device_get(
+            {n: self.params[n] for n in self._moe_load_names})
+        for name, v in values.items():
+            telemetry.record_moe_load(name, [float(x) for x in v])
 
     @property
     def inflight_depth(self) -> int:
@@ -1589,6 +1619,8 @@ class DataParallelStep:
         for name, p in self._param_items:
             host = np.asarray(jax.device_get(self.params[name]))
             p.set_data(host)
+            if p.grad_req != "null" and p._grad is None:
+                p._init_grad()           # released in _ensure_state
 
     # ------------------------------------------------------------------
     # checkpointable sharded state (docs/FAULT_TOLERANCE.md §Elastic

@@ -219,6 +219,9 @@ class _State:
         # executor -> {"sigs": set, "traces": int, "warned_at": int,
         #              "last_sig": str}
         self.retraces: Dict[str, Dict[str, Any]] = {}
+        # expert layers' load counters, newest reading per aux leaf
+        # (record_moe_load; kept whether or not the recorder is enabled)
+        self.moe_load: Dict[str, List[float]] = {}
         # span name -> {count, total_ms, max_ms}
         self.spans: Dict[str, Dict[str, float]] = {}
         # finished spans, oldest first out (spans_between)
@@ -698,6 +701,24 @@ def record_collective(op: str, nbytes: int, wall_s: float,
             _state.coll["total_ms"] += wall_s * 1e3
     record("collective", op=op, nbytes=int(nbytes),
            wall_ms=round(wall_s * 1e3, 3), traced=bool(traced), **fields)
+
+
+def record_moe_load(name: str, values: List[float]) -> None:
+    """Newest reading of one expert layer's load counter (an aux leaf named
+    ``...load``: pairs landed on each held expert in the last step, or
+    ``...load_max``: their running maximum; both relative to an even spread
+    over all the layer's experts).  ``DataParallelStep.drain`` calls this
+    after its sync; nothing calls it inside a step.  Kept in memory
+    (``moe_load()``) whether or not the recorder is enabled."""
+    with _state.lock:
+        _state.moe_load[name] = list(values)
+    record("moe_load", name=name, values=list(values))
+
+
+def moe_load() -> Dict[str, List[float]]:
+    """name -> the newest reading ``record_moe_load`` was given."""
+    with _state.lock:
+        return {k: list(v) for k, v in _state.moe_load.items()}
 
 
 def record_fused_update(n_params: int, n_buckets: int, nbytes: int,

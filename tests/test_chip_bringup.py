@@ -89,6 +89,42 @@ def test_flash_attention_lowers_for_tpu_forward_and_backward():
                                   "mx_flash_fwd"]
 
 
+def test_grouped_query_flash_attention_lowers_at_the_published_heads():
+    """32 query heads over 2 key-value heads of 128 at 8,192 positions (the
+    nemotron_h attention layer): three Mosaic calls, K and V at their own
+    head count in the lowered module (no 16-fold repeat)."""
+    def loss(q, k, v):
+        return pallas.flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    q, kv = _s((1, 32, 8192, 128)), _s((1, 2, 8192, 128))
+    with pallas.compute_on("tpu"):
+        exp = jax.export.export(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+                                platforms=["tpu"])(q, kv, kv)
+    text = exp.mlir_module()
+    import re
+
+    assert sorted(set(re.findall(r'kernel_name = "(\w+)"', text))) == [
+        "mx_flash_dkv", "mx_flash_dq", "mx_flash_fwd"]
+    assert "32x8192x128xbf16" in text and "2x8192x128xbf16" in text
+    assert [tuple(o.shape) for o in exp.out_avals] == [
+        (1, 32, 8192, 128), (1, 2, 8192, 128), (1, 2, 8192, 128)]
+
+
+def test_expert_products_lower_as_grouped_kernels_at_the_published_widths():
+    from mxnet_tpu.ops import moe_ops
+
+    def loss(x, idx, w, up, down):
+        out, _landed = moe_ops.moe_experts(x, idx, w, up, down, first=0)
+        return out.astype(jnp.float32).sum()
+
+    calls = _mosaic_calls(
+        jax.grad(loss, argnums=(0, 3, 4)), _s((1024, 2688)),
+        _s((1024, 6), jnp.int32), _s((1024, 6), jnp.float32),
+        _s((8, 2688, 1856)), _s((8, 1856, 2688)))
+    assert len(calls) >= 5 and set(calls) == {"kernel"}   # megablox gmm, tgmm
+
+
 def test_layer_norm_kernels_lower_for_tpu():
     x, g = _s((64, 768)), _s((768,))
     assert _mosaic_calls(pallas.layer_norm, x, g, g) == ["mx_layer_norm_fwd"]
