@@ -350,6 +350,42 @@ def kernels_phase(ctx, on_path, fused_paged_attention, rows, units, heads,
              f"training step takes the dense path while attention-"
              f"probability dropout is on)")
 
+        # the Mamba-2 chunked scan, forward and backward, against its einsum
+        # form at the nemotron_h widths (heads of 64, state 128, chunk 128)
+        from mxnet_tpu.ops import ssm_ops
+
+        sx, sdt = rand(2, 1024, 16, 64), rand(2, 1024, 16)
+        sb, sc, sw = rand(2, 1024, 2, 128), rand(2, 1024, 2, 128), \
+            rand(2, 1024, 16, 64)
+        heads_f32 = [jax.device_put(jnp.asarray(v, jnp.float32), dev)
+                     for v in (np.log(np.arange(1.0, 17.0)), np.ones(16),
+                               np.full(16, -2.0))]
+
+        def scan_loss(scan):
+            def f(x, dt, b, c, a_log, d, dt_bias):
+                y = scan(x, dt, a_log, b, c, d, dt_bias)
+                return (y.astype(jnp.float32) * sw.astype(jnp.float32)).sum()
+            return jax.jit(jax.value_and_grad(f, argnums=tuple(range(7))))
+
+        scan_args = (sx, sdt, sb, sc, *heads_f32)
+        by_kernel = lambda *a: ssm_ops.ssd_scan(*a, chunk=128)
+        by_einsums = lambda *a: ssm_ops._ssd_scan(*a, 128)
+        as_op = lambda scan: jax.jit(lambda x, dt, b, c, a_log, d, dt_bias:
+                                     scan(x, dt, a_log, b, c, d, dt_bias))
+        d_fwd = delta(as_op(by_kernel)(*scan_args),
+                      as_op(by_einsums)(*scan_args))
+        got = scan_loss(by_kernel)(*scan_args)
+        want = scan_loss(by_einsums)(*scan_args)
+        d_bwd = max(delta(a, b) for a, b in zip(got[1], want[1]))
+        # the per-head parameter gradients are sums that cancel: a few
+        # bf16 roundings of their terms, not of their value
+        check(d_fwd < 3e-2 and d_bwd < 5e-2,
+              f"ssd_scan differs: forward {d_fwd}, backward {d_bwd}")
+        line("ssd_scan", ("mx_ssd_fwd", "mx_ssd_bwd"),
+             f"alone at {tuple(sx.shape)} bf16 over 2 groups of state 128, "
+             f"relative delta to the einsum form {d_fwd:.1e} forward, "
+             f"{d_bwd:.1e} over its seven gradients")
+
         x, r = rand(rows, units), rand(rows, units)
         g = jnp.ones((units,), jnp.bfloat16)
         b = jnp.zeros((units,), jnp.bfloat16)

@@ -94,7 +94,19 @@ def ssd_scan(x, dt, a_log, b, c, d, dt_bias, chunk=128):
     heads).  Returns y (B, L, H, P) in x's type.  Decays are computed in
     f32; the matrix products take their operands in x's type and
     accumulate in f32.
+
+    On a TPU, where the code is per-device and the shapes are whole tiles
+    (``pallas.ssd_scan.supported``), the scan is two Mosaic kernels that keep
+    a chunk's intermediates on chip (``ops/pallas/ssd_scan.py``); anywhere
+    else it is the einsum form below.  Same equations, same precision.
     """
+    from . import pallas as _pk
+    from .pallas import ssd_scan as _kernel
+
+    if _pk.enabled() and _pk.use_compiled() \
+            and _kernel.supported(x, b, int(chunk)):
+        return _kernel.ssd_scan(x, dt, a_log, b, c, d, dt_bias,
+                                chunk=int(chunk))
     with jax.named_scope("mx_ssd_scan"):
         return _ssd_scan(x, dt, a_log, b, c, d, dt_bias, int(chunk))
 

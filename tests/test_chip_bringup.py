@@ -111,6 +111,57 @@ def test_grouped_query_flash_attention_lowers_at_the_published_heads():
         (1, 32, 8192, 128), (1, 2, 8192, 128), (1, 2, 8192, 128)]
 
 
+def test_chunked_scan_lowers_as_its_kernels_at_the_published_shapes():
+    """The scan's gradient at the Nemotron cell's shapes (64 heads of 64 over
+    8 groups, state 128, 2 x 8,192 positions in chunks of 128): only
+    ``mx_ssd_*`` Mosaic calls, each with an operand or result the cell's
+    ``ssd_scan`` trace pattern finds (HLO prints ``f32[2,64,8,8,...]``), and
+    outside the calls no array of batch x chunks x heads x 128 x 128 elements
+    and no f32 copy of x or y."""
+    import re
+
+    from mxnet_tpu.ops import ssm_ops
+
+    def grads(ct, *args):
+        y, vjp = jax.vjp(lambda *a: ssm_ops.ssd_scan(*a, chunk=128), *args)
+        return (y,) + vjp(ct)
+
+    x, bc = _s((2, 8192, 64, 64)), _s((2, 8192, 8, 128))
+    head = _s((64,), jnp.float32)
+    with pallas.compute_on("tpu"):
+        exp = jax.export.export(jax.jit(grads), platforms=["tpu"])(
+            x, x, _s((2, 8192, 64)), head, bc, bc, head, head)
+    text = exp.mlir_module()
+    calls = [ln for ln in text.splitlines() if "kernel_name" in ln]
+    assert [re.search(r'kernel_name = "(\w+)"', ln).group(1)
+            for ln in calls] == ["mx_ssd_fwd", "mx_ssd_bwd"]
+    with open(os.path.join(_REPO, "benchmark", "checks",
+                           "nemotron_twotower_30b_a3b.train_2x8k.json")) as f:
+        pattern = json.load(f)["kernels"]["ssd_scan"]
+
+    def as_hlo(mlir_type):              # 2x64x8x8x64x128xf32 -> f32[2,64,...]
+        *dims, dtype = mlir_type.split("x")
+        return f"{dtype}[{','.join(dims)}]"
+
+    for ln in calls:
+        operands, results = re.search(
+            r" : \((.*?)\) -> \(?(.*?)\)? loc", ln).groups()
+        shapes = [as_hlo(t) for t in re.findall(r"tensor<([\w]+)>",
+                                                operands + results)]
+        assert "f32[2,64,8,8,64,128]" in shapes      # the chunks' start states
+        assert re.search(pattern, f"%mx_ssd.1 = ({', '.join(shapes)}) "
+                                  f"custom-call()")
+    outside = "\n".join(ln for ln in text.splitlines()
+                        if "kernel_name" not in ln)
+    sizes = {int(np.prod([int(d) for d in t.split("x")[:-1]]))
+             for t in re.findall(r"tensor<([0-9x]+x[a-z]\w*)>", outside)}
+    assert max(sizes) < 2 * 64 * 64 * 128 * 128
+    assert not re.search(r"tensor<2x8192x(4096|64x64)xf32>", outside)
+    assert [tuple(o.shape) for o in exp.out_avals][:2] == [
+        (2, 8192, 64, 64)] * 2
+    assert exp.out_avals[0].dtype == BF16
+
+
 def test_expert_products_lower_as_grouped_kernels_at_the_published_widths():
     from mxnet_tpu.ops import moe_ops
 
