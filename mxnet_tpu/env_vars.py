@@ -146,30 +146,6 @@ ENV_VARS: Dict[str, Tuple[str, str]] = {
         "parallel/async_loss.py; honored by DataParallelStep.step (lazy "
         "AsyncLoss), gluon Trainer.step and module.Module.update (step "
         "fences)"),
-    # superstep compiled training + AOT executable cache
-    # (docs/PERFORMANCE.md §Superstep & AOT executable cache)
-    "MX_SUPERSTEP": (
-        "honored", "transparent superstep group size K: every K "
-        "DataParallelStep.step() calls dispatch as ONE compiled "
-        "lax.scan over the step program (per-step lr/RNG become scanned "
-        "arrays; losses return as lazy per-step views).  0/unset = off; "
-        "defaults off on CPU meshes regardless of K — XLA:CPU runs scan "
-        "bodies ~4.7x slower (parallel/data_parallel.py superstep_k)"),
-    "MX_SUPERSTEP_FORCE_CPU": (
-        "honored", "1 overrides the CPU-mesh gate of MX_SUPERSTEP (the "
-        "CPU parity-test/bench override; production CPU meshes should "
-        "leave it off — see the MX_SUPERSTEP caveat)"),
-    "MX_EXECUTABLE_CACHE_DIR": (
-        "honored", "directory of the persistent AOT executable cache: "
-        "DataParallelStep/FusedUpdater jit sites lower ahead-of-time and "
-        "serialize the compiled program here, keyed by "
-        "(memwatch.fingerprint, jax version, platform, mesh shape); a "
-        "restarted process deserializes instead of recompiling "
-        "(aot_cache.py).  Unset = no persistence"),
-    "MX_EXECUTABLE_CACHE": (
-        "honored", "0 kills all AOT executable persistence even when "
-        "MX_EXECUTABLE_CACHE_DIR is set — no loads, no stores, plain "
-        "jit dispatch (aot_cache.enabled)"),
     # inference serving: continuous batching + paged KV cache
     # (docs/SERVING.md)
     "MX_SERVE_SLOTS": (
@@ -207,7 +183,7 @@ ENV_VARS: Dict[str, Tuple[str, str]] = {
     "MX_SERVE_SAMPLING": (
         "honored", "1 builds the engine with per-slot sampling state "
         "(temperature/top-k/top-p/RNG as device decode state; default 0 "
-        "= greedy-only, trace and AOT fingerprint unchanged); a "
+        "= greedy-only, trace and fingerprint unchanged); a "
         "temperature-0 request on a sampling engine is still BITWISE "
         "greedy (serving/engine.py)"),
     "MX_SERVE_SPEC_K": (
@@ -378,9 +354,8 @@ ENV_VARS: Dict[str, Tuple[str, str]] = {
         "honored", "int8 (or 1) routes maybe_quantize_adapter to build a "
         "calibrated int8 serving adapter — Dense/Conv in the traced "
         "decode/prefill graphs lower onto the ops/quantization.py int8 "
-        "primitives; the quant config joins the AOT-cache fingerprint "
-        "so a restart under different settings misses "
-        "(precision/quantize.py)"),
+        "primitives; the quant config joins the executable's "
+        "fingerprint (precision/quantize.py)"),
     "MX_QUANT_CALIB": (
         "honored", "calibration mode for MX_QUANTIZE: naive (per-layer "
         "min/max, default) or entropy (KL-optimal threshold over a "

@@ -285,14 +285,6 @@ class CachedOp:
         self._aux_params: Dict[Any, List[Parameter]] = {}
         self._out_treedef: Dict[Any, Any] = {}
         self._n_out: Dict[Any, int] = {}
-        # persistent AOT executables per (cache_key, input signature)
-        # (MX_EXECUTABLE_CACHE_DIR): a restarted process deserializes the
-        # compiled forward instead of re-tracing + re-compiling; False =
-        # resolution failed, stay on the plain jit path.  The entry meta
-        # carries the trace-time structural facts (n_out, output treedef,
-        # aux param names) a no-trace warm load cannot otherwise know.
-        self._aot_execs: Dict[Any, Any] = {}
-        self._aot_info: Dict[str, Any] = {}
 
     def _ensure_params(self, ctx):
         if self._param_items is None:
@@ -349,60 +341,6 @@ class CachedOp:
             return tuple(o._data for o in out_nds) + tuple(aux_vals)
 
         return jax.jit(fn)
-
-    def _resolve_aot(self, cache_key, shape_sig, jfn, call_args, ctx):
-        """Persistent AOT executable for (cache_key, input signature),
-        or None (plain jit dispatch).  On a MISS ``get_or_compile``
-        lowers ``jfn`` — the trace populates the structural output
-        dicts as a side effect — and persists them as entry meta via
-        ``meta_fn``; on a warm HIT in a fresh process those facts are
-        restored from the meta, so the python forward is NEVER traced
-        (the restart win).  Failed resolutions are negative-cached."""
-        akey = (cache_key, shape_sig)
-        entry = self._aot_execs.get(akey)
-        if entry is not None:
-            return entry if entry is not False else None
-        from .. import aot_cache, memwatch
-
-        train, in_treedef = cache_key
-        parts = ("cachedop", type(self.block).__name__, bool(train),
-                 str(in_treedef), shape_sig,
-                 tuple((tuple(a.shape), str(a.dtype))
-                       for a in call_args[0]))
-
-        def meta_fn():
-            # runs after the fresh lower+compile: jfn traced, so the
-            # output structure is known — persist it for warm restarts
-            name_of = {id(p): n
-                       for n, p in self.block.collect_params().items()}
-            return {
-                "n_out": self._n_out[cache_key],
-                "out_treedef": self._out_treedef[cache_key],
-                "aux_names": [name_of[id(p)]
-                              for p in self._aux_params[cache_key]],
-            }
-
-        dev = ctx.jax_device
-        exec_, info = aot_cache.get_or_compile(
-            jfn, call_args, fingerprint=memwatch.fingerprint(parts),
-            platform=dev.platform, mesh_shape=(),
-            device_ids=(int(dev.id),), meta_fn=meta_fn)
-        self._aot_info = info
-        if exec_ is not None and cache_key not in self._n_out:
-            # warm hit, fresh process: restore the structural facts from
-            # the entry meta — without them the outputs can't be
-            # unflattened and the executable is unusable
-            meta = info.get("meta") or {}
-            try:
-                params = self.block.collect_params()
-                self._out_treedef[cache_key] = meta["out_treedef"]
-                self._aux_params[cache_key] = [params[n]
-                                               for n in meta["aux_names"]]
-                self._n_out[cache_key] = int(meta["n_out"])
-            except (KeyError, TypeError):
-                exec_ = None
-        self._aot_execs[akey] = exec_ if exec_ is not None else False
-        return exec_
 
     def __call__(self, *inputs):
         import jax.tree_util as jtu
@@ -473,28 +411,7 @@ class CachedOp:
                                  input_nds=param_nds + in_nds,
                                  fwd_fn=flat_fwd)
         else:
-            run = jfn
-            from .. import aot_cache
-
-            if aot_cache.enabled():
-                import jax
-
-                # inference dispatch only: the vjp/recording path above
-                # needs the traceable fn, and in-trace calls (tracer
-                # inputs — e.g. the serving decode trace) must inline
-                if not any(isinstance(a, jax.core.Tracer)
-                           for a in (key,) + tuple(arrays)
-                           + tuple(in_arrays)):
-                    if shape_sig is None:
-                        shape_sig = tuple((tuple(x.shape),
-                                           str(x._data.dtype))
-                                          for x in in_nds)
-                    aot = self._resolve_aot(cache_key, shape_sig, jfn,
-                                            (arrays, key, *in_arrays),
-                                            ctx)
-                    if aot is not None:
-                        run = aot
-            outs = run(arrays, key, *in_arrays)
+            outs = jfn(arrays, key, *in_arrays)
 
         if traced:
             # one compile event per specialized executable of this block
@@ -505,27 +422,16 @@ class CachedOp:
             if shape_sig is None:  # detection off: built only on compile
                 shape_sig = tuple((tuple(x.shape), str(x._data.dtype))
                                   for x in in_nds)
-            aot_extra = {k: v for k, v in self._aot_info.items()
-                         if k != "meta"}
-            self._aot_info = {}
             memwatch.note_compile(
                 self._tele_name,
                 ("CachedOp", type(self.block).__name__, train,
                  str(in_treedef), shape_sig,
                  tuple((tuple(a.shape), str(a.dtype)) for a in arrays)),
                 wall_s=_time.perf_counter() - t0, site="cached_op",
-                # a deserialized executable never traced the forward —
-                # don't pay that trace just for cost analysis
-                jitted=(None if aot_extra.get("cache_hit") else jfn),
+                jitted=jfn,
                 args=(memwatch.shape_structs(arrays),
                       memwatch.shape_structs(key),
-                      *memwatch.shape_structs(tuple(in_arrays))),
-                **aot_extra)
-        else:
-            # an AOT resolution on a NON-traced call (retrace detection
-            # off, new shape under a warm cache_key) must not leak its
-            # cache facts into the next unrelated compile event
-            self._aot_info = {}
+                      *memwatch.shape_structs(tuple(in_arrays))))
 
         n_out = self._n_out[cache_key]
         out_nds = [NDArray(o, ctx=ctx) for o in outs[:n_out]]

@@ -711,20 +711,13 @@ class DevicePrefetchIter(_ThreadedIter):
     Only the FIRST label array is staged (the fused step consumes one
     label); extra label arrays pass through untouched.
 
-    ``depth=None`` (default) sizes the staging queue automatically: 1
-    normally, or the superstep group size when ``MX_SUPERSTEP`` is
-    active on this step's mesh — a K-step scan dispatch consumes K
-    staged batches at once, and a depth-1 queue would stall the group
-    fill behind each step's H2D.
+    ``depth`` is how many staged batches may wait ahead of the step
+    (``None``, the default, means 1).
     """
 
     def __init__(self, data_iter, step, depth=None):
-        if depth is None:
-            from ..parallel.data_parallel import superstep_k
-
-            depth = max(1, superstep_k(getattr(step, "mesh", None)))
         self._step = step
-        self._QUEUE_DEPTH = max(1, int(depth))
+        self._QUEUE_DEPTH = max(1, int(depth or 1))
         super().__init__(data_iter,
                          batch_size=getattr(data_iter, "batch_size", 0))
         # live-array census: batches staged on device ahead of the step
@@ -757,17 +750,11 @@ def stage_batches(iterable, step, depth=None):
     step computes.  Batches that are a single array stage as data only;
     sequences stage all-but-last as data and the last element as label.
     The step's in-flight window is drained when the iterable ends.
-    ``depth=None`` auto-sizes to the superstep group size like
-    :class:`DevicePrefetchIter`."""
+    ``depth=None`` means 1, as in :class:`DevicePrefetchIter`."""
     import queue as _q
     import threading
 
-    if depth is None:
-        from ..parallel.data_parallel import superstep_k
-
-        depth = max(1, superstep_k(getattr(step, "mesh", None)))
-
-    q: "_q.Queue" = _q.Queue(maxsize=max(1, int(depth)))
+    q: "_q.Queue" = _q.Queue(maxsize=max(1, int(depth or 1)))
     _END, _ERR = object(), object()
     retired = threading.Event()
 

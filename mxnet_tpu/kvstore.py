@@ -44,11 +44,6 @@ class KVStore:
         self._compression_params = None
         self._psum_cache: Dict[Any, Any] = {}
         self._psum_seen: set = set()
-        # per-(devices, shape, dtype) persistent AOT executables of the
-        # collective reduce (MX_EXECUTABLE_CACHE_DIR): a gang restart
-        # deserializes instead of re-tracing; False = resolution failed,
-        # stay on the plain jit path (docs/PERFORMANCE.md §AOT cache)
-        self._psum_aot: Dict[Any, Any] = {}
         if kv_type.startswith("dist"):
             # rendezvous with the coordination service when launched by
             # tools/launch.py (reference: ps::Postoffice::Start on first
@@ -374,35 +369,10 @@ class KVStore:
             (len(vals),) + shape, NamedSharding(mesh, P("kv")), parts)
         import time as _time
 
-        from . import aot_cache, telemetry
-
-        run = fn
-        aot_info = {}
-        if aot_cache.enabled():
-            # persistent AOT executable per (devices, shape, dtype) —
-            # the PR 9 recipe at the reduce site: a restarted gang
-            # deserializes the psum program instead of re-tracing it
-            aot_key = (key, shape, str(vals[0]._data.dtype))
-            aot = self._psum_aot.get(aot_key)
-            if aot is None:
-                from . import memwatch
-
-                exec_, aot_info = aot_cache.get_or_compile(
-                    fn, (stacked,),
-                    fingerprint=memwatch.fingerprint(
-                        ("reduce", len(devices), shape,
-                         str(vals[0]._data.dtype))),
-                    platform=devices[0].platform,
-                    mesh_shape=(("kv", len(devices)),),
-                    device_ids=tuple(int(d.id) for d in devices))
-                self._psum_aot[aot_key] = (exec_ if exec_ is not None
-                                           else False)
-                aot = exec_
-            if aot is not None and aot is not False:
-                run = aot
+        from . import telemetry
 
         t0 = _time.perf_counter()
-        reduced = run(stacked)  # replicated over the kv mesh
+        reduced = fn(stacked)  # replicated over the kv mesh
         if telemetry.enabled():
             # cold = this (devices, ndim) program was jit-built above;
             # jax also re-specializes per concrete shape — approximate
@@ -417,8 +387,7 @@ class KVStore:
                 wall_s=_time.perf_counter() - t0, ndev=len(vals),
                 traced=traced)
             if traced:
-                # one compile event per specialized psum executable — the
-                # cache-entry schema the AOT executable cache will key on
+                # one compile event per specialized psum executable
                 from . import memwatch
 
                 memwatch.note_compile(
@@ -426,12 +395,8 @@ class KVStore:
                     ("kvstore_psum", len(devices), shape,
                      str(vals[0]._data.dtype)),
                     wall_s=_time.perf_counter() - t0, site="kvstore",
-                    # a deserialized executable never traced the psum
-                    # fn — don't pay that trace just for cost analysis
-                    jitted=(None if aot_info.get("cache_hit") else fn),
-                    args=(memwatch.shape_structs(stacked),),
-                    ndev=len(devices),
-                    **{k: v for k, v in aot_info.items() if k != "meta"})
+                    jitted=fn, args=(memwatch.shape_structs(stacked),),
+                    ndev=len(devices))
         return NDArray(reduced, ctx=vals[0].context)
 
     def _global_sum(self, nd):

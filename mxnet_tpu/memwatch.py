@@ -2,11 +2,11 @@
 
 PRs 2 and 5 made *time* observable (step events, spans, Perfetto traces);
 this module makes *memory* and *compile cost* observable — the two inputs
-the serving path (memory headroom is its binding constraint) and the AOT
-executable cache / learned planner (per-executable cost records are their
-feature set; *A Learned Performance Model for TPUs*, arXiv:2008.01040)
-need.  Four pieces, all riding the PR 2/5 telemetry spine rather than
-growing a second pipeline:
+the serving path (memory headroom is its binding constraint) and the
+learned planner (per-executable cost records are its feature set; *A
+Learned Performance Model for TPUs*, arXiv:2008.01040) need.  Four
+pieces, all riding the PR 2/5 telemetry spine rather than growing a
+second pipeline:
 
   * **sampler** — ``on_step()`` / ``on_checkpoint()`` are called at step
     boundaries and checkpoint save/load (never inside hot dispatch: the
@@ -35,9 +35,9 @@ growing a second pipeline:
     ``compile`` event per cache entry (deduped in-process) carrying
     compile wall time, a **stable executable fingerprint** (sha256 of
     structural identity — shapes/dtypes/static hypers, never object ids,
-    so it survives a process restart: the key the AOT executable cache
-    will use), and — where this jax exposes them — ``cost_analysis()``
-    FLOPs/bytes-accessed from the (cached) retrace.  ``MX_MEMWATCH=full``
+    so it survives a process restart), and — where this jax exposes
+    them — ``cost_analysis()`` FLOPs/bytes-accessed from the (cached)
+    retrace.  ``MX_MEMWATCH=full``
     additionally captures ``memory_analysis()`` temp/argument/output
     bytes at the cost of ONE duplicate XLA compile per executable;
   * **OOM post-mortem** — dispatch/readback paths that catch a
@@ -135,7 +135,6 @@ class _State:
         self.compile_seen: set = set()
         self.compiles: List[dict] = []
         self.compile_ms = 0.0
-        self.compile_cache_hits = 0
         self.oom_reported = False
 
 
@@ -393,8 +392,10 @@ def fingerprint(parts: Any) -> str:
     """Stable executable fingerprint: sha256 over the repr of structural
     identity (optimizer/static hypers/shapes/dtypes) — deliberately no
     object ids or memory addresses, so the same program in a restarted
-    process maps to the same fingerprint (the AOT-cache key contract,
-    asserted by tests/test_memwatch.py)."""
+    process maps to the same fingerprint (asserted by
+    tests/test_memwatch.py).  A NAME for compile telemetry events and
+    checkpoint layouts, not a cache key: the traced code and a block's
+    non-shape configuration are not in it."""
     return hashlib.sha256(repr(parts).encode()).hexdigest()[:16]
 
 
@@ -489,12 +490,8 @@ def note_compile(executor: str, parts: Any, wall_s: float, site: str = "",
     event per (executor, fingerprint) — a steady-state step re-calling
     the cached executable never re-emits — carrying the compile wall
     (the traced first call's wall, per the record_step convention) and
-    whatever analysis this jax exposes.  AOT-cache facts ride in
-    ``extra``: ``cache_hit=True`` + ``deserialize_ms`` mark an
-    executable loaded from the persistent cache (mxnet_tpu.aot_cache)
-    instead of compiled — tools/mem_report.py's executable table shows
-    them so a post-mortem distinguishes "loaded in 0.2s" from "compiled
-    in 40s".  Returns the fingerprint (None when the watchdog is off —
+    whatever analysis this jax exposes; ``extra`` fields ride on the
+    event.  Returns the fingerprint (None when the watchdog is off —
     ``MX_MEMWATCH=0`` kills compile accounting, including the analysis
     retrace, along with sampling)."""
     if not enabled():
@@ -515,8 +512,6 @@ def note_compile(executor: str, parts: Any, wall_s: float, site: str = "",
             pass
     with _state.lock:
         _state.compile_ms += wall_s * 1e3
-        if ev.get("cache_hit"):
-            _state.compile_cache_hits += 1
         _state.compiles.append(dict(ev))
         if len(_state.compiles) > _COMPILE_RECORDS_MAX:
             del _state.compiles[:-_COMPILE_RECORDS_MAX]
@@ -619,7 +614,6 @@ def summary() -> dict:
                      "category": _state.leak_category,
                      "events": _state.leak_events},
             "compiles": {"count": len(_state.compile_seen),
-                         "wall_ms": round(_state.compile_ms, 3),
-                         "cache_hits": _state.compile_cache_hits},
+                         "wall_ms": round(_state.compile_ms, 3)},
             "oom_reported": _state.oom_reported,
         }

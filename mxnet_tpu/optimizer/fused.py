@@ -42,7 +42,6 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from .. import aot_cache
 from .. import engine
 from .. import memwatch
 from .. import telemetry
@@ -312,10 +311,6 @@ class FusedUpdater(Updater):
     def __init__(self, optimizer: Optimizer):
         super().__init__(optimizer)
         self._fn_cache: Dict[Any, Any] = {}
-        # persistent AOT executables (MX_EXECUTABLE_CACHE_DIR), keyed by
-        # the fn-cache key PLUS the group's weight shapes; False =
-        # resolution failed, stay on the plain jit path
-        self._aot_execs: Dict[Any, Any] = {}
         self.last_info: Optional[Dict[str, int]] = None
         # live-array census: the states dict is the "optimizer" category
         memwatch.register("optimizer", self, _flat_state_arrays)
@@ -415,37 +410,18 @@ class FusedUpdater(Updater):
                               for index, _g, _w, _s, _k in group],
                              dtype=np.float32)
         rescale = np.float32(opt.rescale_grad)
-        shapes = tuple((tuple(w.shape), str(w.dtype)) for w in ws)
-        parts = ("FusedUpdater", spec.opt_name, static, kinds, donate,
-                 shapes)
-        run, cache_info = fn, {}
         t0 = time.perf_counter() if cold else 0.0
-        aot_key = (spec.opt_name, static, kinds, donate, shapes)
-        if aot_cache.enabled():
-            # persistent AOT executable: a restarted process deserializes
-            # the fused-apply program instead of tracing + recompiling it
-            cached = self._aot_execs.get(aot_key)
-            if cached is None:
-                cached, cache_info = aot_cache.get_or_compile(
-                    fn, (ws, gs, ss, scalars, rescale),
-                    fingerprint=memwatch.fingerprint(parts),
-                    platform=ctx.jax_device.platform,
-                    device_ids=(int(ctx.jax_device.id),))
-                self._aot_execs[aot_key] = (cached if cached is not None
-                                            else False)
-            if cached is not None and cached is not False:
-                run = cached
-        new_ws, new_ss = run(ws, gs, ss, scalars, rescale)
+        new_ws, new_ss = fn(ws, gs, ss, scalars, rescale)
         if cold:
             memwatch.note_compile(
-                f"FusedUpdater:{spec.opt_name}", parts,
+                f"FusedUpdater:{spec.opt_name}",
+                ("FusedUpdater", spec.opt_name, static, kinds, donate,
+                 tuple((tuple(w.shape), str(w.dtype)) for w in ws)),
                 wall_s=time.perf_counter() - t0, site="fused",
-                # a deserialized executable never traced fused_fn — skip
-                # the analysis retrace, the cache facts carry the story
-                jitted=None if cache_info.get("cache_hit") else fn,
+                jitted=fn,
                 args=memwatch.shape_structs((ws, gs, ss, scalars,
                                              rescale)),
-                n_params=len(group), **cache_info)
+                n_params=len(group))
         if engine.is_naive():
             import jax
 

@@ -8,10 +8,9 @@ ICI/DCN (SURVEY §2.4, §5.8).
 """
 from .mesh import (make_mesh, local_mesh, device_mesh, host_barrier,
                    global_allreduce)
-from .async_loss import (AsyncLoss, InflightRing, StackedAsyncLoss,
-                         SuperstepLossView, drain_all, inflight_limit)
+from .async_loss import AsyncLoss, InflightRing, drain_all, inflight_limit
 from .data_parallel import (DataParallelStep, compile_step_with_plan,
-                            make_train_step, superstep_k)
+                            make_train_step)
 from .plan import (Plan, dp_plan, tensor_parallel_plan, pipeline_plan,
                    ring_plan, ulysses_plan)
 from .ring import ring_attention, ring_self_attention

@@ -38,7 +38,6 @@ from __future__ import annotations
 
 import contextlib
 import os
-import sys
 import threading
 import time
 import weakref
@@ -51,8 +50,7 @@ from .. import memwatch
 from .. import telemetry
 from ..base import MXNetError
 
-__all__ = ["AsyncLoss", "AsyncResult", "StackedAsyncLoss",
-           "SuperstepLossView", "StepFence", "InflightRing",
+__all__ = ["AsyncLoss", "AsyncResult", "StepFence", "InflightRing",
            "inflight_limit", "drain_all"]
 
 _DEFAULT_INFLIGHT = 2
@@ -86,10 +84,6 @@ class _PendingHandle:
         self._forced = False
         self._host = None
         self._exc: Optional[BaseException] = None
-        # superstep views delegate their wait to the group handle, which
-        # records the blocked wall itself — the view must not re-record
-        # the same interval into the rollup
-        self._record_wait = True
 
     @property
     def step(self) -> int:
@@ -149,9 +143,8 @@ class _PendingHandle:
                 # all host time spent blocked on the device funnels into
                 # one per-executor rollup
                 # (summary()['steps'][name]['block_wait_ms'])
-                if self._record_wait:
-                    telemetry.record_block_wait(self._executor,
-                                                time.perf_counter() - t0)
+                telemetry.record_block_wait(self._executor,
+                                            time.perf_counter() - t0)
 
     def __repr__(self):
         state = "forced" if self._forced else "pending"
@@ -205,59 +198,6 @@ class AsyncResult(AsyncLoss):
     (``mxnet_tpu.serving.engine``) admits one per compiled decode step
     (the (S,) per-slot token vector) through its bounded ring, so token
     readbacks happen at stream cadence, never per token."""
-
-
-class StackedAsyncLoss(AsyncLoss):
-    """Lazy (K,) vector of per-step losses from ONE superstep dispatch
-    (``DataParallelStep.superstep`` — K training steps inside a single
-    compiled ``lax.scan``).  One handle flows through the in-flight ring
-    per superstep, so the window bounds dispatched *supersteps*.
-
-    ``asnumpy()`` / ``np.asarray()`` force the readback and return the
-    full (K,) loss vector in step order; scalar conversions
-    (``float()`` / ``.asscalar()`` / ``.item()``) return the LAST step's
-    loss — exactly the value a sequential training loop would hold in
-    ``loss`` after the same K steps (what Speedometer-style display
-    callbacks want)."""
-
-    def __init__(self, value, steps, executor: str,
-                 ring: Optional["InflightRing"] = None, host_fn=None):
-        steps = tuple(int(s) for s in steps)
-        super().__init__(value, step=steps[-1], executor=executor,
-                         ring=ring, host_fn=host_fn)
-        self._steps = steps
-
-    @property
-    def steps(self):
-        """The step numbers this superstep covered, in dispatch order."""
-        return self._steps
-
-    def __len__(self):
-        return len(self._steps)
-
-    def asscalar(self):
-        return float(np.asarray(self.wait()).ravel()[-1])
-
-
-class SuperstepLossView(AsyncLoss):
-    """Per-step scalar view into a (possibly not-yet-dispatched)
-    superstep group — what ``DataParallelStep.step()`` returns in
-    transparent superstep mode so existing training loops keep their
-    one-loss-per-batch contract.  Forcing a view dispatches its group if
-    still buffered (a partial group runs as a shorter scan) and reads
-    this step's slot out of the stacked loss vector."""
-
-    def __init__(self, idx: int, step: int, executor: str, dispatch_fn):
-        super().__init__(None, step=step, executor=executor, ring=None)
-        self._idx = int(idx)
-        self._dispatch_fn = dispatch_fn
-        # the group's StackedAsyncLoss records the blocked wall once
-        self._record_wait = False
-
-    def _force(self):
-        stacked = self._dispatch_fn()
-        arr = np.asarray(stacked.wait(_span=False))
-        return arr.ravel()[self._idx]
 
 
 class StepFence(_PendingHandle):
@@ -371,20 +311,8 @@ def drain_all():
     """Drain every live ring in the process (preemption/checkpoint paths).
     Best-effort: deferred failures are collected and returned, not raised —
     the caller is usually about to snapshot-and-exit and must not die on a
-    step that was doomed anyway.
-
-    Buffered-but-undispatched superstep groups are flushed FIRST (via the
-    ``data_parallel`` step registry): they were never admitted to any
-    ring, so draining alone would silently drop up to K-1 enqueued steps
-    from a SIGTERM preemption's final checkpoint.  sys.modules lookup,
-    not import — this runs inside a signal handler."""
+    step that was doomed anyway."""
     errors = []
-    dp = sys.modules.get("mxnet_tpu.parallel.data_parallel")
-    if dp is not None:
-        try:
-            errors.extend(dp.flush_all_steps())
-        except Exception as exc:  # noqa: BLE001 — survey, don't die
-            errors.append(exc)
     with _rings_lock:
         rings = list(_live_rings)
     for ring in rings:

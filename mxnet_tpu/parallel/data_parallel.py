@@ -17,86 +17,20 @@ from __future__ import annotations
 import os
 import threading
 import time
-import weakref
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .. import aot_cache
 from .. import fault
 from .. import memwatch
 from .. import telemetry
 from ..base import MXNetError
-from .async_loss import (AsyncLoss, InflightRing, StackedAsyncLoss,
-                         SuperstepLossView, inflight_limit)
+from .async_loss import AsyncLoss, InflightRing, inflight_limit
 from .plan import Plan, dp_plan
 from .sharding import ShardingRules, replicated, shard_batch
 
 __all__ = ["DataParallelStep", "make_train_step", "compile_step_with_plan",
-           "superstep_k", "flush_all_steps", "dp_plan"]
-
-# every live step object in the process, so preemption paths can flush
-# buffered-but-undispatched superstep groups they never saw (weak: the
-# registry must not keep a dropped step alive)
-_live_steps: "weakref.WeakSet" = weakref.WeakSet()
-
-
-def flush_all_steps() -> List[BaseException]:
-    """Dispatch every live step's buffered partial superstep group
-    (best-effort, errors collected not raised).  The SIGTERM preemption
-    path runs this BEFORE ``async_loss.drain_all``: a buffered
-    ``_SuperstepGroup`` was never dispatched, so draining the in-flight
-    rings alone would silently drop up to K-1 enqueued steps from the
-    final sync checkpoint (the PR 9 known issue)."""
-    errors: List[BaseException] = []
-    for step in list(_live_steps):
-        try:
-            step.flush()
-        except BaseException as exc:  # noqa: BLE001 — survey, don't die
-            errors.append(exc)
-    return errors
-
-
-def superstep_k(mesh=None) -> int:
-    """Transparent superstep group size: how many ``step()`` calls are
-    batched into ONE compiled ``lax.scan`` dispatch (``MX_SUPERSTEP``,
-    re-read per call; 0/unset = off).  Defaults OFF on CPU meshes
-    regardless of the value — XLA:CPU runs scan bodies ~4.7x slower than
-    standalone steps (ROADMAP item 3 caveat) — unless
-    ``MX_SUPERSTEP_FORCE_CPU=1`` (the CPU parity-test override).  The
-    explicit :meth:`DataParallelStep.superstep` API is always available;
-    this gate only controls the transparent ``step()`` routing."""
-    try:
-        k = int(os.environ.get("MX_SUPERSTEP", "0") or "0")
-    except (TypeError, ValueError):
-        return 0
-    if k < 1:
-        return 0
-    if mesh is not None:
-        platform = next(iter(mesh.devices.flat)).platform
-        if platform == "cpu" and os.environ.get(
-                "MX_SUPERSTEP_FORCE_CPU", "0").lower() in (
-                    "", "0", "false", "off"):
-            return 0
-    return k
-
-
-class _SuperstepGroup:
-    """One buffered batch-group awaiting its scan dispatch (transparent
-    superstep mode).  ``sig`` is the (shapes, dtypes) signature of the
-    group's first batch — a later batch with a different signature (the
-    classic ragged final batch) closes the group instead of poisoning
-    its stack.  ``handle`` is set exactly once, at dispatch; ``entries``
-    are released then (loss views outlive the group and must not pin K
-    batches of device input buffers)."""
-
-    __slots__ = ("entries", "handle", "sig")
-
-    def __init__(self, sig=None):
-        self.entries: List[dict] = []
-        self.handle: Optional[StackedAsyncLoss] = None
-        self.sig = sig
-
+           "dp_plan"]
 
 def _global_put(arr, sharding):
     """device_put that also works on multi-process (multi-controller)
@@ -527,20 +461,6 @@ class DataParallelStep:
         self._shardings = None
         self._jitted = None
         self._step_count = 0
-        # superstep mode (docs/PERFORMANCE.md §Superstep): buffered
-        # batch-group awaiting one lax.scan dispatch, the per-length scan
-        # executables, their AOT-cache resolutions, and the per-shape
-        # device stackers that build the scanned (K, B, ...) inputs
-        self._open_group: Optional[_SuperstepGroup] = None
-        self._super_jits: Dict[int, Any] = {}
-        self._super_aot: Dict[Any, Any] = {}
-        self._stackers: Dict[Any, Any] = {}
-        # single-step AOT executables (MX_EXECUTABLE_CACHE_DIR): one per
-        # input signature (alternating shapes — bucketed lengths,
-        # train/eval interleave — must reuse in memory, not re-hit disk);
-        # False = resolution failed, stay on the plain jit path
-        self._aot_execs: Dict[Any, Any] = {}
-        self._last_cache_info: Dict[str, Any] = {}
         # bounded async dispatch window (MX_ASYNC_INFLIGHT handles pending
         # at once); the device prefetcher's staging thread and step() may
         # both trigger first-use state init, hence the lock
@@ -557,9 +477,6 @@ class DataParallelStep:
         # weak registration — the watchdog never keeps this step alive
         memwatch.register("params", self, _params_arrays)
         memwatch.register("optimizer", self, _opt_state_arrays)
-        # preemption paths flush buffered superstep groups via this
-        # process-wide registry (flush_all_steps)
-        _live_steps.add(self)
 
     def _ensure_state(self, example_inputs):
         """Gather params (resolving deferred init via one eager forward) and
@@ -908,28 +825,7 @@ class DataParallelStep:
         ``tools/trace_report.py`` aggregates into the gang-wide step
         breakdown, and ``mx:<name>`` events in the profiler's trace.
         Spans observe only; the computation is bitwise identical with
-        ``MX_TELEMETRY_SPANS=0``.
-
-        Superstep mode (``MX_SUPERSTEP=K``, docs/PERFORMANCE.md
-        §Superstep): ``step()`` transparently buffers the batch and
-        returns a lazy per-step view; every K-th call dispatches the
-        whole group as ONE compiled ``lax.scan`` over the same step
-        program — one device dispatch, one telemetry span, one compile
-        event per group size.  Per-step lr schedule values and RNG keys
-        are drawn at buffer time in step order, so schedules and losses
-        stay faithful to sequential dispatch.  Off by default on CPU
-        meshes (see :func:`superstep_k`)."""
-        k = superstep_k(self.mesh)
-        if k >= 1:
-            view, group = self._superstep_enqueue(data, label)
-            if len(group.entries) >= k:
-                self._dispatch_group(group)
-            memwatch.on_step(view.step)
-            return view
-        if self._open_group is not None and self._open_group.entries:
-            # MX_SUPERSTEP flipped off mid-run with steps still buffered:
-            # land them first so dispatch order matches call order
-            self.flush()
+        ``MX_TELEMETRY_SPANS=0``."""
         with telemetry.span("train_step", executor=self._tele_name,
                             step_num=self._step_count + 1):
             handle = self._step_impl(data, label)
@@ -941,17 +837,13 @@ class DataParallelStep:
         """Land the deferred compile record stamped by the hot dispatch
         body — HERE, outside it: note_compile may retrace for cost
         analysis, which is a once-per-executable fact, not a per-step
-        one.  AOT-cache facts (cache_hit, deserialize_ms) ride along; a
-        cache-hit executable skips the analysis retrace entirely (the
-        python step fn was never traced — that skip IS the win)."""
+        one."""
         pend, self._pending_compile = self._pending_compile, None
         if pend is None:
             return
         memwatch.note_compile(self._tele_name, pend["parts"],
-                              pend["wall_s"],
-                              site=pend.get("site", "data_parallel"),
-                              jitted=pend.get("jitted"), args=pend["args"],
-                              **pend.get("extra", {}))
+                              pend["wall_s"], site="data_parallel",
+                              jitted=self._jitted, args=pend["args"])
 
     def _step_impl(self, data, label):
         import jax
@@ -966,15 +858,11 @@ class DataParallelStep:
         # retrace detection: jit specializes on input shapes/dtypes, so a
         # new signature on an already-built step means XLA recompiles —
         # report it (telemetry warns after the limit) and tag this step's
-        # wall time as compile, not steady-state execute.  The AOT path
-        # needs the same signature to key its executable, so it pays the
-        # tuple build even with detection off.
+        # wall time as compile, not steady-state execute.
         name = self._tele_name
-        aot_on = aot_cache.enabled()
-        sig = (self._sig_of(datas, label)
-               if (telemetry.retrace_enabled() or aot_on) else None)
         if telemetry.retrace_enabled():
-            traced = telemetry.note_signature(name, sig)
+            traced = telemetry.note_signature(
+                name, self._sig_of(datas, label))
         else:  # detection off: still split the first-call compile out
             traced = self._jitted is None
         if self.plan.accum_steps > 1:
@@ -1028,12 +916,8 @@ class DataParallelStep:
                           key, lr_val, data_arrs, label_arr) if scaled
                          else (self.params, self.opt_state, key, lr_val,
                                data_arrs, label_arr))
-            resolve = ((lambda a, p: self._resolve_aot(sig, a, p))
-                       if aot_on else None)
-            outs = self._plan_dispatch(
-                self._jitted, call_args, (self._step_count + 1,),
-                sp_active, resolve,
-                f"FusedStep:{type(self.block).__name__}")
+            outs = self._plan_dispatch(call_args, self._step_count + 1,
+                                       sp_active)
             if scaled:
                 (self.params, self.opt_state, self.scaler_state,
                  loss) = outs
@@ -1043,22 +927,13 @@ class DataParallelStep:
             # what step() needs to book the compile once the hot body is
             # done: structural fingerprint parts + arg shape mirrors
             # (metadata only — the placed buffers are not kept alive)
-            cache_info = self._last_cache_info
-            self._last_cache_info = {}
             self._pending_compile = {
                 "parts": self._fingerprint_parts(
-                    (), sig if sig is not None
-                    else self._sig_of(data_arrs, label_arr)),
+                    self._sig_of(data_arrs, label_arr)),
                 "wall_s": time.perf_counter() - t0,
                 "args": memwatch.shape_structs(
                     (self.params, self.opt_state, key, lr_val,
                      data_arrs, label_arr)),
-                "site": "data_parallel",
-                # a deserialized executable never traced the python step
-                # fn — don't pay that trace just for cost analysis
-                "jitted": (None if cache_info.get("cache_hit")
-                           else self._jitted),
-                "extra": cache_info,
             }
         self._step_count += 1
         handle = AsyncLoss(loss, step=self._step_count, executor=name,
@@ -1085,13 +960,13 @@ class DataParallelStep:
         return handle
 
     # ------------------------------------------------------------------
-    # shared signature/fingerprint/scope helpers
+    # signature/fingerprint/scope helpers
     # ------------------------------------------------------------------
     @staticmethod
     def _sig_of(arrs, label):
         """Canonical (shapes, dtypes) signature of one batch — keys the
-        retrace detector, the AOT executable resolution, and the
-        restart-stable fingerprint.  Accepts NDArrays or raw arrays."""
+        retrace detector and the restart-stable fingerprint.  Accepts
+        NDArrays or raw arrays."""
         def one(a):
             data = getattr(a, "_data", a)
             return (tuple(np.shape(data)),
@@ -1099,16 +974,15 @@ class DataParallelStep:
 
         return (tuple(one(a) for a in arrs), one(label))
 
-    def _fingerprint_parts(self, variant: Tuple, shape_sig) -> Tuple:
-        """Structural identity of one step executable (shapes/dtypes/
-        static hypers/mesh axes — no object ids, restart-stable: the
-        memwatch.fingerprint + AOT-cache key contract).  ``variant``
-        distinguishes executable families over the same step program,
-        e.g. ``("superstep", K)``."""
-        # hypers baked into the trace as CONSTANTS are executable
-        # identity too: two steps differing only in momentum (or remat,
-        # or the loss class) compile different programs and must not
-        # collide on the restart-stable fingerprint
+    def _fingerprint_parts(self, shape_sig) -> Tuple:
+        """Structural name of one step executable (shapes/dtypes/static
+        hypers/mesh axes — no object ids, restart-stable) for
+        ``memwatch.fingerprint``: compile telemetry events carry it.  A
+        name, not a key: the block's own configuration and the code are
+        not in it."""
+        # hypers baked into the trace as CONSTANTS split the name too:
+        # two steps differing only in momentum (or remat, or the loss
+        # class) compile different programs
         hyper_sig = (self._momentum, self._wd, self._rescale,
                      self._beta1, self._beta2, self._eps,
                      self._clip_gradient, self._clip_global,
@@ -1117,36 +991,26 @@ class DataParallelStep:
                      self.plan.batch_axes, self.plan.seq_axis,
                      type(self.loss_fn).__name__,
                      tuple(sorted(self._mults.items())),
-                     # the AMP policy + loss-scale config are executable
-                     # identity: a restart under a different MX_AMP /
-                     # MX_LOSS_SCALE must MISS the AOT cache, not load
-                     # the other precision's program
+                     # the AMP policy + loss-scale config
                      self._precision.signature()
                      if self._precision is not None else None,
                      # the ONE pass-pipeline signature: any config or
                      # order change (pass toggled, fused set grown, AMP
                      # policy swapped) changes the fingerprint
                      self._pipeline.signature())
-        return (("DataParallelStep",) + tuple(variant)
-                + (type(self.block).__name__,
-                   self._optimizer, self.plan.accum_steps, hyper_sig,
-                   tuple(self.mesh.shape.items()), shape_sig))
+        return ("DataParallelStep", type(self.block).__name__,
+                self._optimizer, self.plan.accum_steps, hyper_sig,
+                tuple(self.mesh.shape.items()), shape_sig)
 
-    def _plan_dispatch(self, fn, call_args, step_nos, sp_active,
-                       resolve_aot, profile_label):
-        """THE unified dispatch body: every compiled-step execution —
-        single step or superstep scan, whatever strategy the Plan
-        encodes (dp/tp/pp/ring/ulysses and their compositions) — runs
-        through here.  Per covered step the chaos/fault hook fires
+    def _plan_dispatch(self, call_args, step_no, sp_active):
+        """THE dispatch body: every compiled-step execution, whatever
+        strategy the Plan encodes (dp/tp/pp/ring/ulysses and their
+        compositions), runs through here.  The chaos/fault hook fires
         (`oom:step=N` raises a synthetic RESOURCE_EXHAUSTED exactly
         where a real HBM exhaustion would); the plan's trace-time
         scopes activate (pallas platform override, ring/ulysses SP
-        routing, pipeline microbatch schedule); ``resolve_aot`` swaps
-        in the persistent AOT executable when warm (INSIDE the scopes —
-        a cache MISS lowers the program here, and the scope flags are
-        trace-time facts); the profiler wrap and the OOM post-mortem
-        close the loop.  ``step_nos`` are the logical step numbers the
-        dispatch covers (one for a single step, K for a superstep)."""
+        routing, pipeline microbatch schedule); the profiler wrap and
+        the OOM post-mortem close the loop."""
         from ..ops import pallas as _pk
 
         from .. import profiler
@@ -1159,33 +1023,25 @@ class DataParallelStep:
         ring_cm, pp_cm = self._dispatch_scopes(sp_active)
         mesh_platform = next(iter(self.mesh.devices.flat)).platform
         try:
-            for s in step_nos:
-                fault.on_dispatch(s)
+            fault.on_dispatch(step_no)
             with _pk.compute_on(mesh_platform, self.mesh.size > 1), \
                     ring_cm, pp_cm:
-                run = fn
-                if resolve_aot is not None:
-                    aot = resolve_aot(call_args, mesh_platform)
-                    if aot is not None:
-                        run = aot
                 if profiler.is_recording():
-                    base_run = run
-                    run = (lambda *a: profiler.timed_call(
-                        profile_label, base_run, *a))
-                return run(*call_args)
+                    return profiler.timed_call(
+                        f"FusedStep:{type(self.block).__name__}",
+                        self._jitted, *call_args)
+                return self._jitted(*call_args)
         except Exception as e:
             if memwatch.is_resource_exhausted(e):
                 # land the post-mortem (census, largest category, top
                 # executables, window depth) on disk before dying
                 memwatch.emit_oom_report(
-                    executor=self._tele_name, step=step_nos[-1],
+                    executor=self._tele_name, step=step_no,
                     inflight_depth=self._inflight.depth)
             raise
 
     def _dispatch_scopes(self, sp_active):
-        """(ring_cm, pp_cm) trace-time scopes for one dispatch — shared
-        by the single-step and superstep paths so both lower the model
-        identically."""
+        """(ring_cm, pp_cm) trace-time scopes for one dispatch."""
         import contextlib
 
         from .scope import ring_attention_scope
@@ -1219,352 +1075,9 @@ class DataParallelStep:
             pp_cm = contextlib.nullcontext()
         return ring_cm, pp_cm
 
-    def _resolve_aot(self, sig, call_args, mesh_platform):
-        """Single-step AOT executable for this input signature, or None
-        (cache disabled / AOT unavailable -> plain jit dispatch).  Keyed
-        per signature so alternating shapes reuse their executables in
-        memory; a failed resolution is negative-cached (False) so the
-        plain jit path isn't re-lowered per step; ``_last_cache_info``
-        carries the cache facts to the compile booking."""
-        cached = self._aot_execs.get(sig)
-        if cached is not None:
-            return cached if cached is not False else None
-        parts = self._fingerprint_parts((), sig)
-        exec_, info = aot_cache.get_or_compile(
-            self._jitted, call_args,
-            fingerprint=memwatch.fingerprint(parts),
-            platform=mesh_platform,
-            mesh_shape=tuple(self.mesh.shape.items()),
-            device_ids=tuple(int(d.id) for d in self.mesh.devices.flat))
-        self._last_cache_info = info
-        self._aot_execs[sig] = exec_ if exec_ is not None else False
-        return exec_
-
-    # ------------------------------------------------------------------
-    # superstep mode: K steps per compiled lax.scan dispatch
-    # ------------------------------------------------------------------
-    def superstep(self, batches) -> StackedAsyncLoss:
-        """Run ``len(batches)`` training steps inside ONE compiled
-        ``lax.scan`` dispatch (docs/PERFORMANCE.md §Superstep).
-
-        ``batches`` is a sequence of ``(data, label)`` pairs (``data``
-        may be a tuple for multi-input blocks).  Per-step scalars — the
-        scheduled learning rate, the RNG key — become scanned arrays, so
-        lr schedules step exactly as they would under sequential
-        dispatch.  Returns ONE lazy :class:`StackedAsyncLoss` carrying
-        the (K,) per-step loss vector, flowing through the same bounded
-        in-flight window as single steps.
-
-        This explicit API is always available (the ``MX_SUPERSTEP``
-        platform gate only covers the transparent ``step()`` routing);
-        any transparently-buffered steps are flushed first so dispatch
-        order always matches call order."""
-        batches = list(batches)
-        if not batches:
-            raise MXNetError("superstep() needs at least one "
-                             "(data, label) batch")
-        self.flush()
-        group = None
-        for data, label in batches:
-            _view, group = self._superstep_enqueue(data, label)
-            memwatch.on_step(self._step_count)
-        return self._dispatch_group(group)
-
-    def flush(self) -> None:
-        """Dispatch any partially-filled transparent superstep group now
-        (epoch end, pre-checkpoint, mode flip).  A partial group runs as
-        a shorter scan — still one dispatch."""
-        if self._open_group is not None and self._open_group.entries:
-            self._dispatch_group(self._open_group)
-
-    def _superstep_enqueue(self, data, label):
-        """Buffer one logical step for the open superstep group: inputs
-        are placed on device NOW (the prefetcher handshake holds —
-        pre-staged batches skip the H2D), and the RNG key + scheduled lr
-        are drawn NOW in step order, keeping losses/weights faithful to
-        sequential dispatch.  Returns (per-step view handle, group)."""
-        from .. import random as _random
-        from ..ndarray import NDArray
-
-        if label is None:
-            raise MXNetError("superstep mode requires a label per batch")
-        datas = tuple(data) if isinstance(data, (tuple, list)) else (data,)
-        datas = tuple(d if isinstance(d, NDArray)
-                      else NDArray(d, ctx=self._ctx) for d in datas)
-        self._ensure_state(datas)
-        if self._jitted is None:
-            self._build()
-        data_arrs = tuple(d._data for d in datas)
-        label_arr = label._data if isinstance(label, NDArray) else label
-        sig = self._sig_of(data_arrs, label_arr)
-        if (self._open_group is not None and self._open_group.entries
-                and self._open_group.sig != sig):
-            # shape change mid-group (ragged final batch, bucketed
-            # lengths): close the open group as a shorter scan — one
-            # stacked group must be shape-uniform
-            self._dispatch_group(self._open_group)
-        if self.plan.accum_steps > 1:
-            for dim0 in [np.shape(a)[0] for a in data_arrs] + \
-                    [np.shape(label_arr)[0]]:
-                if dim0 % self.plan.accum_steps:
-                    raise MXNetError(
-                        f"batch {dim0} not divisible by "
-                        f"accum_steps={self.plan.accum_steps}")
-        data_sh, label_sh, _sp = self._input_shardings(data_arrs, label_arr)
-        overlapped = 0
-        placed = []
-        for a, s in zip(data_arrs, data_sh):
-            arr, pre = _maybe_put(a, s)
-            placed.append(arr)
-            if pre:
-                overlapped += int(getattr(arr, "nbytes", 0))
-        label_arr, pre = _maybe_put(label_arr, label_sh)
-        if pre:
-            overlapped += int(getattr(label_arr, "nbytes", 0))
-        key = _random.next_key()
-        self._step_count += 1
-        step_no = self._step_count
-        entry = {
-            "data": tuple(placed), "label": label_arr, "key": key,
-            "lr": np.float32(self._current_lr(step_no)),
-            "step": step_no, "overlapped": overlapped,
-            "nbytes": sum(int(getattr(a, "nbytes", 0))
-                          for a in tuple(placed) + (label_arr,)),
-        }
-        if self._open_group is None:
-            self._open_group = _SuperstepGroup(sig=sig)
-        group = self._open_group
-        idx = len(group.entries)
-        group.entries.append(entry)
-        view = SuperstepLossView(
-            idx=idx, step=step_no, executor=self._tele_name,
-            dispatch_fn=lambda g=group: self._dispatch_group(g))
-        return view, group
-
-    def _dispatch_group(self, group) -> StackedAsyncLoss:
-        """Dispatch one buffered group as a single scan executable.
-        Idempotent: a view forcing an already-dispatched group gets the
-        cached handle.  Partial groups (flush/drain/early force) run as
-        a shorter scan — every superstep dispatch stays in the scan
-        executable family, which is bitwise self-consistent across
-        lengths (asserted by tests/test_superstep.py)."""
-        if group.handle is not None:
-            return group.handle
-        if group is self._open_group:
-            self._open_group = None
-        with telemetry.span("train_step", executor=self._tele_name,
-                            superstep=len(group.entries),
-                            step_num=group.entries[-1]["step"]):
-            handle = self._superstep_impl(group)
-            # release the K placed input buffers NOW: loss views (and
-            # their dispatch closures) outlive the group, and retaining an
-            # epoch's worth of staged batches would grow device memory
-            # without bound
-            group.entries = []
-            self._book_pending_compile()
-        return handle
-
-    def _superstep_impl(self, group) -> StackedAsyncLoss:
-        import jax.numpy as jnp
-
-        t0 = time.perf_counter()
-        entries = group.entries
-        k = len(entries)
-        name = self._tele_name
-        first = entries[0]
-        last_step = entries[-1]["step"]
-        aot_on = aot_cache.enabled()
-        sig = (k,) + self._sig_of(first["data"], first["label"])
-        if telemetry.retrace_enabled():
-            traced = telemetry.note_signature(name, ("superstep",) + sig)
-        else:
-            traced = k not in self._super_jits
-        limit = inflight_limit()
-        block_wait_s = 0.0
-        if limit > 0:
-            with telemetry.span("block_wait"):
-                block_wait_s = self._inflight.make_room(limit,
-                                                        wait_span=False)
-        with telemetry.span("input_stage"):
-            datas, label_arr, sp_active = self._stack_group(entries)
-        with telemetry.span("step_prep"):
-            keys = jnp.stack([e["key"] for e in entries])
-            # per-step scalars become SCANNED arrays: an lr schedule
-            # steps inside the compiled program exactly as it would
-            # under sequential dispatch
-            lrs = np.array([e["lr"] for e in entries], np.float32)
-        mesh_platform = next(iter(self.mesh.devices.flat)).platform
-        with telemetry.span("dispatch", step=last_step, traced=traced,
-                            superstep=k):
-            fn = self._super_fn(k, mesh_platform)
-            scaled = self.scaler_state is not None
-            call_args = ((self.params, self.opt_state, self.scaler_state,
-                          keys, lrs, datas, label_arr) if scaled
-                         else (self.params, self.opt_state, keys, lrs,
-                               datas, label_arr))
-            resolve = ((lambda a, p: self._resolve_super_aot(sig, fn, a, p))
-                       if aot_on else None)
-            outs = self._plan_dispatch(
-                fn, call_args, tuple(e["step"] for e in entries),
-                sp_active, resolve,
-                f"Superstep:{type(self.block).__name__}")
-            if scaled:
-                (self.params, self.opt_state, self.scaler_state,
-                 losses) = outs
-            else:
-                self.params, self.opt_state, losses = outs
-        if traced and telemetry.enabled():
-            cache_info = self._last_cache_info
-            self._last_cache_info = {}
-            self._pending_compile = {
-                "parts": self._fingerprint_parts(("superstep", k),
-                                                 sig[1:]),
-                "wall_s": time.perf_counter() - t0,
-                "args": memwatch.shape_structs(
-                    (self.params, self.opt_state, keys, lrs, datas,
-                     label_arr)),
-                "site": "superstep",
-                "jitted": (None if cache_info.get("cache_hit")
-                           else self._super_jits.get(k)),
-                "extra": cache_info,
-            }
-        handle = StackedAsyncLoss(
-            losses, steps=[e["step"] for e in entries], executor=name,
-            ring=self._inflight, host_fn=_host_scalar)
-        group.handle = handle
-        depth = self._inflight.admit(handle) if limit > 0 else 0
-        if telemetry.enabled():
-            samples = sum(
-                (int(np.shape(e["label"])[0]) if np.ndim(e["label"]) else 1)
-                for e in entries)
-            telemetry.record_step(
-                name, step=last_step, wall_s=time.perf_counter() - t0,
-                samples=samples,
-                transfer_bytes=sum(e["nbytes"] for e in entries),
-                traced=traced,
-                h2d_overlapped=sum(e["overlapped"] for e in entries),
-                inflight_depth=depth,
-                block_wait_ms=round(block_wait_s * 1e3, 3),
-                superstep=k)
-            telemetry.heartbeat(last_step)
-        if limit == 0:
-            handle.wait()  # synchronous mode: errors surface right here
-        return handle
-
-    def _super_fn(self, k: int, mesh_platform: str):
-        """The K-step scan executable: ``lax.scan`` over the SAME
-        single-step program ``_build`` produced, carrying (params,
-        opt_state) and scanning (keys, lrs, data, label).  Cached per K;
-        partial-group lengths get their own entry."""
-        fn = self._super_jits.get(k)
-        if fn is not None:
-            return fn
-        import jax
-        from jax import lax
-
-        if self._jitted is None:
-            self._build()
-        inner = self._jitted
-        repl = replicated(self.mesh)
-        donate = (0, 1) if (self._donate and mesh_platform != "cpu") else ()
-
-        def superstep_body(params, opt_state, keys, lrs, datas, label):
-            def body(carry, xs):
-                p, o = carry
-                key, lr, data, lab = xs
-                p2, o2, loss = inner(p, o, key, lr, data, lab)
-                return (p2, o2), loss
-
-            (p, o), losses = lax.scan(body, (params, opt_state),
-                                      (keys, lrs, datas, label))
-            return p, o, losses
-
-        def superstep_body_scaled(params, opt_state, scaler, keys, lrs,
-                                  datas, label):
-            # loss-scaled twin: the scaler state joins the scan carry,
-            # so skip/backoff/regrow transitions happen per scanned step
-            # exactly as under sequential dispatch
-            def body(carry, xs):
-                p, o, s = carry
-                key, lr, data, lab = xs
-                p2, o2, s2, loss = inner(p, o, s, key, lr, data, lab)
-                return (p2, o2, s2), loss
-
-            (p, o, s), losses = lax.scan(
-                body, (params, opt_state, scaler),
-                (keys, lrs, datas, label))
-            return p, o, s, losses
-
-        # built once per scan length K, cached in _super_jits; the
-        # loss-scale config is construction-time state
-        if self._loss_scale_cfg is None:
-            # mxlint: disable=retrace-hazard — built once per K, cached
-            fn = jax.jit(superstep_body,
-                         out_shardings=(self._shardings, None, repl),
-                         donate_argnums=donate)
-        else:
-            # mxlint: disable=retrace-hazard — built once per K, cached
-            fn = jax.jit(superstep_body_scaled,
-                         out_shardings=(self._shardings, None, None, repl),
-                         donate_argnums=donate)
-        self._super_jits[k] = fn
-        return fn
-
-    def _resolve_super_aot(self, sig, fn, call_args, mesh_platform):
-        """Superstep AOT executable for (scan length, input signature),
-        or None.  Failed resolutions are negative-cached so the plain
-        jit path isn't re-probed per dispatch."""
-        cached = self._super_aot.get(sig)
-        if cached is not None:
-            return cached if cached is not False else None
-        parts = self._fingerprint_parts(("superstep", sig[0]), sig[1:])
-        exec_, info = aot_cache.get_or_compile(
-            fn, call_args, fingerprint=memwatch.fingerprint(parts),
-            platform=mesh_platform,
-            mesh_shape=tuple(self.mesh.shape.items()),
-            device_ids=tuple(int(d.id) for d in self.mesh.devices.flat))
-        self._last_cache_info = info
-        self._super_aot[sig] = exec_ if exec_ is not None else False
-        return exec_
-
-    def _stack_group(self, entries):
-        """Stack K staged per-step batches into the scanned (K, B, ...)
-        inputs ON DEVICE, preserving each batch's placement sharding
-        under a leading unsharded scan axis — the prefetcher's staged
-        arrays are stacked in place, never read back to host."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        first = entries[0]
-        data_sh, label_sh, sp_active = self._input_shardings(
-            first["data"], first["label"])
-
-        def stack(arrs, sh):
-            out_sh = NamedSharding(self.mesh, PartitionSpec(None, *sh.spec))
-            key = (len(arrs), tuple(np.shape(arrs[0])),
-                   str(arrs[0].dtype), out_sh)
-            fn = self._stackers.get(key)
-            if fn is None:
-                import jax.numpy as jnp
-
-                # mxlint: disable=retrace-hazard — cached per
-                # (K, shape, dtype, sharding) in _stackers
-                fn = jax.jit(lambda *xs: jnp.stack(xs),
-                             out_shardings=out_sh)
-                self._stackers[key] = fn
-            return fn(*arrs)
-
-        datas = tuple(
-            stack([e["data"][j] for e in entries], data_sh[j])
-            for j in range(len(first["data"])))
-        label = stack([e["label"] for e in entries], label_sh)
-        return datas, label, sp_active
-
     def drain(self) -> None:
         """Force every in-flight step (epoch end, pre-checkpoint, exit);
-        dispatches any buffered partial superstep group first; raises
-        the first deferred failure."""
-        self.flush()
+        raises the first deferred failure."""
         self._inflight.drain()
         self._record_moe_load()
 
@@ -1720,15 +1233,13 @@ class DataParallelStep:
     def state_dict(self, allow_collective: bool = True) -> dict:
         """Host snapshot of the sharded training state, keyed by
         structural parameter names: ``{"params": {name: ndarray},
-        "opt_state": {slot.name: ndarray}, "optimizer": ...}``.  Flushes
-        any buffered superstep group first (a buffered step's update is
-        not in ``self.params`` yet) but does NOT force the in-flight
-        window — jax arrays are futures, and the host reads below block
-        on exactly the values the dispatched steps produce."""
+        "opt_state": {slot.name: ndarray}, "optimizer": ...}``.  Does NOT
+        force the in-flight window — jax arrays are futures, and the
+        host reads below block on exactly the values the dispatched
+        steps produce."""
         if self.params is None:
             raise MXNetError(
                 "state_dict: step holds no state yet (no step/stage ran)")
-        self.flush()
         smap = self._struct_names()
 
         def host(a):
@@ -1777,7 +1288,6 @@ class DataParallelStep:
             raise MXNetError(
                 "shard_state_dict: step holds no state yet "
                 "(no step/stage ran)")
-        self.flush()
         import jax
 
         rank = int(jax.process_index())
@@ -1992,9 +1502,8 @@ class DataParallelStep:
 def _layouts_equal(a: dict, b: dict) -> bool:
     """Whether two :meth:`DataParallelStep.layout` descriptions denote the
     SAME placement: world size, mesh axes, per-param specs AND the device
-    assignment — serialized executables and shard ownership both key on
-    device ids (the AOT-cache lesson), so a same-shape mesh over reordered
-    devices is a different layout."""
+    assignment — shard ownership keys on device ids, so a same-shape
+    mesh over reordered devices is a different layout."""
     keys = ("world_size", "mesh_axes", "device_ids", "specs")
     return all(a.get(k) == b.get(k) for k in keys)
 
@@ -2008,11 +1517,10 @@ def compile_step_with_plan(block, loss_fn, plan: Plan, mesh=None,
     """THE single compile path of the parallelism zoo: consume ANY
     :class:`~mxnet_tpu.parallel.plan.Plan` — dp, tp, pipeline, ring or
     Ulysses SP, or any composition the planner enumerated — and return
-    the compiled :class:`DataParallelStep` for it.  Superstep scan mode,
-    the persistent AOT executable cache, the async in-flight window,
-    telemetry spans and elastic resharding all ride along: they are
-    features of the one dispatch body (``_plan_dispatch``), not of any
-    single strategy.
+    the compiled :class:`DataParallelStep` for it.  The async in-flight
+    window, telemetry spans and elastic resharding all ride along: they
+    are features of the one dispatch body (``_plan_dispatch``), not of
+    any single strategy.
 
     ``mesh`` defaults to ``plan.build_mesh()`` over all devices; pass an
     explicit mesh (it must match the plan's axes) to pin devices.
@@ -2030,10 +1538,9 @@ def compile_step_with_plan(block, loss_fn, plan: Plan, mesh=None,
         telemetry.record(
             "plan", executor=step._tele_name, strategy=plan.strategy,
             plan=plan.to_json(),
-            # the pass pipeline this step compiles under: names + the
-            # shared fingerprint that keys its AOT executables — a trace
-            # reader can tie a slow/fast step stream to the exact
-            # rewrite config that produced it
+            # the pass pipeline this step compiles under: names + its
+            # fingerprint — a trace reader can tie a slow/fast step
+            # stream to the exact rewrite config that produced it
             passes=step._pipeline.names(),
             pass_fingerprint=step._pipeline.fingerprint(),
             predicted=plan.predicted)

@@ -13,8 +13,8 @@ captures everything those knobs expressed as one serializable value:
     +  pipeline microbatching  +  gradient-accumulation microbatching
 
 ``compile_step_with_plan`` (data_parallel.py) consumes ANY Plan through
-the one dispatch body, so superstep scan, AOT caching, async in-flight,
-telemetry spans and elastic resharding are written once, not five times.
+the one dispatch body, so the async in-flight window, telemetry spans
+and elastic resharding are written once, not five times.
 The legacy strategy entry points remain as thin shims that BUILD the
 equivalent Plan (``dp_plan``/``tensor_parallel_plan``/``pipeline_plan``/
 ``ring_plan``/``ulysses_plan`` here, re-exported by their home modules),

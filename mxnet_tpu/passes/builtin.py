@@ -269,7 +269,7 @@ def pipeline_for_serving(adapter, environ=None) -> PassPipeline:
     """The serving engine's pipeline: adapter-contributed passes (a
     quantized adapter exposes its quant pass via ``.passes``) then
     fused-kernel substitution.  The engine enters this scope around its
-    traced decode/prefill bodies and feeds ``signature()`` into its AOT
+    traced decode/prefill bodies and feeds ``signature()`` into its
     fingerprint."""
     passes = list(getattr(adapter, "passes", ()) or ())
     fused = fused_kernels_from_env(environ)

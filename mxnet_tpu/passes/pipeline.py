@@ -12,10 +12,9 @@ trace-time reality:
     whose effect is a trace-time scope (``scope()``) plus a structural
     ``signature()``;
   * a :class:`PassPipeline` is an ORDERED list of passes with ONE shared
-    ``signature()`` that joins ``_fingerprint_parts``/the AOT executable
-    cache — any pass config, toggle, or ORDER change produces a
-    different fingerprint, so a restart under a different pass config
-    misses instead of deserializing the wrong program;
+    ``signature()`` that joins ``_fingerprint_parts`` — any pass
+    config, toggle, or ORDER change produces a different fingerprint on
+    the compile events and checkpoint layouts that carry it;
   * a disabled pass is bitwise absent: it contributes nothing to the
     signature and nothing to the trace (``wrap_apply``/``scope`` skip
     it), so pipeline-with-pass-disabled traces a byte-identical program

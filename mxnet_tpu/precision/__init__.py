@@ -16,7 +16,7 @@ Three pillars over the compiled train/serve paths:
     per-layer scales (reusing ``contrib/quantization``'s calibrators)
     rewrite Dense/Conv in the adapter's traced prefill/decode graphs
     onto the ``ops/quantization.py`` int8 primitives — ONE quantized
-    decode executable, AOT-fingerprinted by the quant config.
+    decode executable, fingerprinted by the quant config.
 
 Env surface (env_vars.py): MX_AMP, MX_AMP_POLICY, MX_LOSS_SCALE,
 MX_QUANTIZE, MX_QUANT_CALIB, MX_SERVE_INT4, MX_QUANT_GROUP (all the

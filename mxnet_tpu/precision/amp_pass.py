@@ -17,11 +17,10 @@ runs, every op dispatch consults the active
 
 Because the policy is applied at trace time inside ``_build``, the whole
 mixed-precision program lands in ONE compiled executable — it composes
-with superstep ``lax.scan`` (the scan body is the same traced step), the
-AOT executable cache (the policy signature joins ``_fingerprint_parts``)
-and the ``Plan`` (``Plan.precision`` serializes it into checkpoint
-layouts).  With no policy the wrapped apply is returned UNCHANGED — the
-AMP-off program is byte-for-byte the pre-pass program.
+with the ``Plan`` (``Plan.precision`` serializes it into checkpoint
+layouts; the policy signature joins ``_fingerprint_parts``).  With no
+policy the wrapped apply is returned UNCHANGED — the AMP-off program is
+byte-for-byte the pre-pass program.
 """
 from __future__ import annotations
 

@@ -3,8 +3,8 @@ machine runs INSIDE the compiled train step as device values.
 
 The eager reference (``contrib/amp/amp.py`` ``DynamicLossScaler``) reads
 every gradient back to host per step to decide overflow — a per-step
-device->host sync that would stall the PR 4 async pipeline and the PR 9
-superstep scan.  Here the whole protocol is traced:
+device->host sync that would stall the async step pipeline.  Here the
+whole protocol is traced:
 
   * the loss is multiplied by the scale before ``value_and_grad`` (small
     fp16 grads then survive the 5-bit exponent);
@@ -19,10 +19,9 @@ superstep scan.  Here the whole protocol is traced:
 
 The scaler state — ``scale`` (f32), ``growth`` (i32 consecutive-finite
 counter), ``skipped`` (i32 cumulative skip count, observability) — is
-part of the step's train state: it threads through the jitted step and
-the superstep ``lax.scan`` carry, is checkpointed alongside the
-optimizer slots (``amp.*`` keys in ``opt_state``), and survives elastic
-reshard (replicated scalars place trivially on any mesh).
+part of the step's train state: it threads through the jitted step, is
+checkpointed alongside the optimizer slots (``amp.*`` keys in
+``opt_state``), and survives elastic reshard (replicated scalars place trivially on any mesh).
 
 ``overflow_flag`` is the eager-path export: ONE fused reduce over a
 gradient list returning a DEVICE scalar, used by the

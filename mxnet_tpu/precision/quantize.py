@@ -26,9 +26,9 @@ eager forward-pre hooks exactly as ``contrib.quantization.quantize_net``
 does.
 
 The quantization signature (calib mode + per-layer thresholds) joins the
-adapter ``signature()`` and therefore the engine's AOT-cache
-fingerprint: a restart under different ``MX_QUANTIZE``/``MX_QUANT_CALIB``
-settings *misses* instead of deserializing the wrong program.  Int8
+adapter ``signature()`` and therefore the engine's executable
+fingerprint: compile events under different ``MX_QUANTIZE``/
+``MX_QUANT_CALIB`` settings carry different names.  Int8
 buffers register under the ``quantized`` memwatch census category.
 
 The int4 path (:class:`Int4WeightAdapter`) lives next to int8: weight-
@@ -41,7 +41,7 @@ weight-bandwidth bound, and ~0.14x weight bytes is the win.
 Both adapters express their rewrite as a registered graph pass
 (``passes/builtin``: ``quant_int8`` / ``quant_int4``) exposed via
 ``.passes`` — the serving engine builds its pipeline from that, and the
-pass signature is what joins the AOT-cache fingerprint.
+pass signature is what joins the engine's fingerprint.
 
 Env surface: ``MX_QUANTIZE`` (``int8`` to enable, ``0``/unset off) with
 ``MX_QUANT_CALIB`` (``naive``/``entropy``, default naive) drives
@@ -259,10 +259,9 @@ class _RewriteAdapterBase:
 
     def quant_signature(self) -> Tuple:
         """Structural identity of the quantization config — the pass's
-        signature.  A restart under different MX_QUANTIZE/MX_SERVE_INT4/
-        MX_QUANT_* settings (or requantized weights) produces a
-        different signature — the AOT cache then misses instead of
-        loading the wrong program."""
+        signature.  Different MX_QUANTIZE/MX_SERVE_INT4/MX_QUANT_*
+        settings (or requantized weights) produce a different
+        signature."""
         return self._pass.signature()
 
     def signature(self):

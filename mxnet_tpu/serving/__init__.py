@@ -6,9 +6,8 @@ request": a fixed-slot engine whose hot loop is ONE compiled decode step
 shared by ragged in-flight requests (paged KV cache + page tables, per
 *Ragged Paged Attention*), a request queue with in-flight admission/
 eviction between decode steps, lazy token readback at stream cadence
-through the PR 4 ``InflightRing``, AOT-cached executables for
-millisecond restarts, and ``serve_request`` SLO telemetry on the PR 2
-recorder.
+through the PR 4 ``InflightRing``, and ``serve_request`` SLO telemetry
+on the PR 2 recorder.
 
 The front door on top (PR 17): a multi-replica HTTP ``Router`` +
 per-engine ``ReplicaServer`` (session affinity, least-outstanding

@@ -24,11 +24,6 @@ Any model servable here implements :class:`ServingAdapter` — the
 and :class:`FullPrefixAdapter` (any fixed-shape logits function — e.g.
 an ONNX-imported decoder-only SymbolBlock — served O(L^2) but still
 one-executable).
-
-Both executables AOT-cache through mxnet_tpu.aot_cache (fingerprint
-variants ``("decode", page_size, slots)`` / ``("prefill", src_max)``):
-with ``MX_EXECUTABLE_CACHE_DIR`` set a serving-process restart
-deserializes in milliseconds instead of recompiling.
 """
 from __future__ import annotations
 
@@ -40,7 +35,6 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from .. import aot_cache
 from .. import memwatch
 from .. import telemetry
 from ..base import MXNetError, env_int
@@ -148,8 +142,7 @@ class ServingAdapter:
         return OrderedDict()
 
     #: extra-state keys the prefill executable produces, in output
-    #: order (static — an AOT-cache-hit prefill never traces, so the
-    #: names cannot be discovered from the trace)
+    #: order
     prefill_names = ()
 
     def prefill_src(self, request: Request):
@@ -180,10 +173,10 @@ class ServingAdapter:
         return None
 
     def signature(self):
-        """Extra structural identity for the AOT-cache fingerprint:
-        anything that changes the traced decode program without changing
-        shapes (e.g. the fused-attention decision) MUST appear here, or
-        a restart could deserialize the wrong executable."""
+        """Extra structural identity for the executable's fingerprint
+        (the name compile telemetry events carry): what changes the
+        traced decode program without changing shapes, e.g. the
+        fused-attention decision."""
         return ()
 
     def warmup(self, ctx) -> None:
@@ -252,7 +245,7 @@ class TransformerAdapter(ServingAdapter):
 
     def _resolved_fused(self) -> bool:
         """The fused decision, resolved ONCE and pinned — the traced
-        program and the AOT-cache fingerprint must agree on it."""
+        program and the fingerprint must agree on it."""
         if self._fused is None:
             self._fused = _serve_fused()
         return self._fused
@@ -452,8 +445,7 @@ class ServingEngine:
         # the serving pass pipeline (passes/builtin.pipeline_for_serving):
         # adapter-contributed quant passes + fused-kernel substitution.
         # Every traced body runs under its scope (_traced), and its ONE
-        # signature joins _fingerprint_parts — config/order changes miss
-        # the AOT cache instead of loading the wrong program.
+        # signature joins _fingerprint_parts.
         from ..passes.builtin import pipeline_for_serving
 
         self._pipeline = pipeline_for_serving(adapter)
@@ -506,7 +498,7 @@ class ServingEngine:
                            dtype="int32"))
         # per-slot sampling state rides the compiled step ONLY when
         # sampling is on: a greedy engine's state (and therefore its
-        # traced program and AOT fingerprint) is unchanged — the
+        # traced program and fingerprint) is unchanged — the
         # parity-pinned default
         self._samp_names: List[str] = []
         if self._sampling:
@@ -613,11 +605,11 @@ class ServingEngine:
         requests finish against a consistent weight set, the paged KV
         pool and page tables are untouched, and because ``_params()`` is
         re-read live each dispatch the compiled decode executable is
-        reused as-is (same AOT fingerprint = zero recompile).
+        reused as-is (same fingerprint = zero recompile).
 
         Verification before anything is published: the checkpoint's
         SHA-256 digests (``load_checkpoint_state`` rejects torn/corrupt
-        steps), full param coverage, and the decode AOT fingerprint
+        steps), full param coverage, and the decode fingerprint
         recomputed over the staged arrays — a mismatched fingerprint
         (different shapes/dtypes, i.e. a different/quantized model) is a
         LOUD rejection and the engine keeps serving the old weights.
@@ -658,8 +650,8 @@ class ServingEngine:
                                  else np.asarray(v))
             # the fingerprint gate: the decode executable's structural
             # identity recomputed over the STAGED arrays must equal the
-            # serving one — same structure means the compiled step (and
-            # any AOT cache entry) keeps working unchanged
+            # serving one — same structure means the compiled step
+            # keeps working unchanged
             variant = ("decode", self._ps, self._S)
             sarrs = [a._data for a in self._state.values()]
             cur = memwatch.fingerprint(self._fingerprint_parts(
@@ -901,7 +893,7 @@ class ServingEngine:
         new_state = dict(state)
         if not self._sampling:
             # the original greedy body, op-for-op (the parity-pinned
-            # default: same trace, same AOT fingerprint)
+            # default: same trace, same fingerprint)
             nxt, new_extra, new_pools = self._adapter.decode(
                 F, tok, pos, table, keep, pages, rows, lengths, extra,
                 pools)
@@ -946,8 +938,9 @@ class ServingEngine:
                      for a in arrays)
 
     def _fingerprint_parts(self, variant, arg_arrays):
-        """Restart-stable structural identity (the memwatch.fingerprint /
-        aot_cache key contract — shapes/dtypes/config, no object ids)."""
+        """Restart-stable structural name of one executable for
+        ``memwatch.fingerprint`` (shapes/dtypes/config, no object ids):
+        compile telemetry events and the ``swap_weights`` gate read it."""
         model = getattr(self._adapter, "model", None)
         return (("ServingEngine",) + tuple(variant)
                 + (type(self._adapter).__name__,
@@ -957,28 +950,14 @@ class ServingEngine:
                    self._S, self._ps, self._P, self._max_len,
                    self._shape_sig(arg_arrays)))
 
-    def _resolve(self, jfn, args, variant, site):
-        """AOT-resolve one executable through the persistent cache;
-        falls back to plain jit dispatch (compile booked at first call
-        via ``_pending_compile``)."""
+    def _pend_compile(self, jfn, args, variant, site):
+        """Note ``jfn``'s compile for booking after its first call
+        (``_book_pending_compile``) and hand it back."""
         # fingerprint over params + operands
         flat = list(args[0]) + list(args[1:])
-        parts = self._fingerprint_parts(variant, flat)
-        dev = self._ctx.jax_device
-        t0 = time.perf_counter()
-        compiled, info = aot_cache.get_or_compile(
-            jfn, args, fingerprint=memwatch.fingerprint(parts),
-            platform=dev.platform, mesh_shape=(),
-            device_ids=(int(dev.id),))
-        if compiled is not None:
-            memwatch.note_compile(
-                "ServingEngine", parts,
-                wall_s=time.perf_counter() - t0, site=site,
-                jitted=None if info.get("cache_hit") else jfn,
-                args=memwatch.shape_structs(args), **info)
-            return compiled
-        self._pending_compile[site] = {"parts": parts, "jitted": jfn,
-                                       "args": memwatch.shape_structs(args)}
+        self._pending_compile[site] = {
+            "parts": self._fingerprint_parts(variant, flat), "jitted": jfn,
+            "args": memwatch.shape_structs(args)}
         return jfn
 
     def _ensure_compiled(self):
@@ -991,9 +970,9 @@ class ServingEngine:
         jfn = jax.jit(self._traced(self._decode_body))
         args = (self._params(),) + tuple(a._data
                                          for a in self._state.values())
-        self._run = self._resolve(jfn, args,
-                                  ("decode", self._ps, self._S),
-                                  "serving_decode")
+        self._run = self._pend_compile(jfn, args,
+                                       ("decode", self._ps, self._S),
+                                       "serving_decode")
 
     def _ensure_prefill(self, src_row):
         if self._prefill_run is not None:
@@ -1013,7 +992,7 @@ class ServingEngine:
         import jax.numpy as jnp
 
         args = (self._params(), jnp.asarray(src_row))
-        self._prefill_run = self._resolve(
+        self._prefill_run = self._pend_compile(
             jfn, args, ("prefill", src_row.shape[1]), "serving_prefill")
 
     # ------------------------------------------------------------------
@@ -1184,7 +1163,7 @@ class ServingEngine:
             + tuple(a._data for a in self._state.values()) \
             + (jnp.zeros((self._S, self._spec_k), jnp.int32),
                jnp.zeros((self._S,), jnp.int32))
-        self._vrun = self._resolve(
+        self._vrun = self._pend_compile(
             jfn, args, ("verify", self._spec_k, self._ps, self._S),
             "serving_verify")
 
@@ -1200,7 +1179,7 @@ class ServingEngine:
             + tuple(a._data for a in self._state.values()) \
             + (jnp.zeros((self._S, self._prefix_chunk), jnp.int32),
                jnp.zeros((self._S,), jnp.int32))
-        self._irun = self._resolve(
+        self._irun = self._pend_compile(
             jfn, args, ("ingest", self._prefix_chunk, self._ps, self._S),
             "serving_ingest")
 

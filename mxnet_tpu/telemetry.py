@@ -1666,8 +1666,6 @@ def render_prometheus(mode: str = "live") -> str:
                   kind="counter")
             gauge("mx_mem_compile_ms_total", ms["compiles"]["wall_ms"],
                   kind="counter")
-            gauge("mx_mem_compile_cache_hits_total",
-                  ms["compiles"].get("cache_hits", 0), kind="counter")
     except Exception:  # the exposition must land even if memwatch breaks
         pass
     lines.append("# EOF")

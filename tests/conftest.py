@@ -52,12 +52,9 @@ _CACHE_THRESHOLDS = ("jax_persistent_cache_min_compile_time_secs",
 def _cache_thresholds():
     """What a test changes of jax's persistent-cache thresholds ends with
     the test.  ``benchmark/run.py``'s ``configure_jax()`` stores EVERY
-    program; a test that calls its ``main()`` in this process left that on
-    for the worker's life, so every later test's tiny programs landed in
-    the shared directory.  One of them, loaded back by the next run and then
-    serialized by ``aot_cache.store``, is an executable the CPU cannot run
-    (NOT_FOUND: ... fusion not found; ``test_aot_cache_through_plan_path``
-    failed on every run after the one that stored its step)."""
+    program, and tests/benchmark call its ``main()`` in this process:
+    without the restore, every later test of that worker would write its
+    tiny programs into the shared cache directory."""
     before = [getattr(jax.config, k) for k in _CACHE_THRESHOLDS]
     yield
     for key, value in zip(_CACHE_THRESHOLDS, before):
@@ -129,12 +126,4 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.slow)
         if (name.startswith("test_op_sweep.py::test_gradient")
                 or name.startswith("test_op_sweep.py::test_bf16_backward")):
-            item.add_marker(pytest.mark.slow)
-        # the int4 AOT restart story spawns three subprocesses that each
-        # cold-compile a Transformer engine (~33s total); its constituent
-        # paths keep default-tier coverage (in-process engine-fingerprint
-        # splits + restart-stable digests in test_passes.py, the
-        # cross-process AOT hit/miss machinery in the int8 and cold-start
-        # tests)
-        if base == "test_passes.py::test_int4_aot_cache_roundtrip":
             item.add_marker(pytest.mark.slow)

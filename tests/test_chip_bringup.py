@@ -3,7 +3,8 @@
 * a TPU context never computes on the host: ``mx.tpu()`` raises without an
   accelerator;
 * one compile-cache rule: a set JAX_COMPILATION_CACHE_DIR is left alone,
-  unset the cache is one fixed directory inside the checkout;
+  unset the cache is one fixed directory inside the checkout; and a
+  restarted process loads every program of the five jit sites from it;
 * every exported Pallas kernel, and the serving engine's decode step under
   the decision ``_serve_fused()`` takes, LOWERS for the TPU.  Pallas-to-Mosaic
   lowering runs in jaxlib, so ``jax.export`` for platform "tpu" exercises it
@@ -73,6 +74,148 @@ def test_compile_cache_rule():
     assert was_set == "/some/dir"
     with open(os.path.join(_REPO, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+# ---------------------------------------------------------------------------
+# a restart goes through that one cache: a second process under the same
+# JAX_COMPILATION_CACHE_DIR, with the thresholds benchmark/run.py sets, loads
+# every program of the five jit sites and compiles none (what the benchmark's
+# first_setup_s -> setup_s factor rests on)
+# ---------------------------------------------------------------------------
+_RESTART_CHILD = r'''
+import hashlib
+import json
+import jax, jax.monitoring
+import numpy as np
+
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+seen = {"hits": 0, "misses": 0, "compiles": 0, "loads": 0}
+NAMES = {"/jax/compilation_cache/cache_hits": "hits",
+         "/jax/compilation_cache/cache_misses": "misses",
+         "/jax/core/compile/backend_compile_duration": "compiles",
+         "/jax/compilation_cache/cache_retrieval_time_sec": "loads"}
+
+
+def on(event, *_a, **_kw):
+    if event in NAMES:
+        seen[NAMES[event]] += 1
+
+
+jax.monitoring.register_event_listener(on)
+jax.monitoring.register_event_duration_secs_listener(on)
+
+import mxnet_tpu as mx
+from mxnet_tpu import gluon, nd
+from mxnet_tpu.gluon import nn
+from mxnet_tpu.models.transformer import Transformer
+from mxnet_tpu.optimizer.fused import FusedUpdater
+from mxnet_tpu.parallel import DataParallelStep, local_mesh
+from mxnet_tpu.serving import Request, ServingEngine, TransformerAdapter
+
+
+def dense_net():
+    net = nn.HybridSequential()
+    net.add(nn.Dense(8, activation="relu", in_units=4),
+            nn.Dense(2, in_units=8))
+    net.initialize(mx.init.Xavier())
+    return net
+
+
+X = nd.array(np.linspace(-1, 1, 32).reshape(8, 4).astype(np.float32))
+
+
+def step_site():
+    step = DataParallelStep(
+        dense_net(), gluon.loss.L2Loss(),
+        mesh=local_mesh(devices=[jax.devices("cpu")[0]]), optimizer="adam",
+        optimizer_params={"learning_rate": 0.01})
+    loss = step.step(X, nd.zeros((8, 2))).asnumpy()
+    step.drain()
+    return [loss] + [np.asarray(v) for _, v in sorted(step.params.items())]
+
+
+def forward_site():
+    net = dense_net()
+    net.hybridize()
+    return [net(X).asnumpy()]
+
+
+def fused_site():
+    upd = FusedUpdater(mx.optimizer.create("sgd", learning_rate=0.1,
+                                           momentum=0.9))
+    ws = [nd.ones((4, 3)), nd.ones((5,)) * 2]
+    upd.apply([(i, w * 0.5, w) for i, w in enumerate(ws)])
+    assert upd.last_info["n_fused"] == 2, upd.last_info
+    return [w.asnumpy() for w in ws]
+
+
+def reduce_site():
+    kv = mx.kvstore.create("device")
+    ctxs = [mx.cpu(i) for i in range(4)]
+    kv.init("w", nd.zeros((3, 4), ctx=ctxs[0]))
+    kv.push("w", [nd.ones((3, 4), ctx=c) * (i + 1)
+                  for i, c in enumerate(ctxs)])
+    out = nd.zeros((3, 4), ctx=ctxs[0])
+    kv.pull("w", out)
+    return [out.asnumpy()]
+
+
+def decode_site():
+    net = Transformer(16, units=32, hidden_size=64, num_heads=4,
+                      num_layers=2, max_length=48, dropout=0.0)
+    net.initialize(mx.init.Xavier())
+    eng = ServingEngine(TransformerAdapter(net, src_max_len=6), slots=2,
+                        page_size=4, max_len=8, stream_every=2)
+    out = eng.serve([Request(np.array([3, 7, 11, 5]), max_new_tokens=5,
+                             bos_id=1, eos_id=2)])
+    return [np.asarray(list(out.values())[0])]
+
+
+report = {}
+for name, site in [("step", step_site), ("forward", forward_site),
+                   ("fused_update", fused_site), ("reduce", reduce_site),
+                   ("decode", decode_site)]:
+    mx.random.seed(0)
+    before = dict(seen)
+    digest = hashlib.sha256()
+    for a in site():
+        digest.update(np.ascontiguousarray(a).tobytes())
+    report[name] = dict({k: seen[k] - before[k] for k in seen},
+                        result=digest.hexdigest())
+print("RESTART " + json.dumps(report))
+'''
+
+_RESTART_SITES = ("step", "forward", "fused_update", "reduce", "decode")
+
+
+@pytest.fixture(scope="module")
+def restart_pair(tmp_path_factory):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_REPO,
+               XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               JAX_COMPILATION_CACHE_DIR=str(
+                   tmp_path_factory.mktemp("restart_cache")))
+    reports = []
+    for _ in range(2):
+        out = subprocess.run([sys.executable, "-c", _RESTART_CHILD], env=env,
+                             capture_output=True, text=True, timeout=400)
+        assert out.returncode == 0, out.stderr[-3000:]
+        (line,) = [ln for ln in out.stdout.splitlines()
+                   if ln.startswith("RESTART ")]
+        reports.append(json.loads(line[len("RESTART "):]))
+    return reports
+
+
+@pytest.mark.parametrize("site", _RESTART_SITES)
+def test_a_restarted_process_loads_every_program_from_the_one_cache(
+        restart_pair, site):
+    cold, warm = (r[site] for r in restart_pair)
+    assert cold["misses"] > 0, cold
+    assert warm["misses"] == 0, warm
+    assert warm["hits"] == cold["hits"] + cold["misses"], (cold, warm)
+    # the events compiles_in_window counts: each "compile" was a load
+    assert warm["loads"] == warm["compiles"] == warm["hits"], warm
+    assert warm["result"] == cold["result"]
 
 
 # ---------------------------------------------------------------------------

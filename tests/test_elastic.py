@@ -248,8 +248,8 @@ def test_restore_reshards_onto_smaller_and_larger_mesh(tmp_path):
 
 def test_restore_same_size_different_device_order(tmp_path):
     """ACCEPTANCE satellite: a mesh of the SAME size but a different
-    device order is a different layout (device assignment is load-bearing
-    — the AOT-cache lesson); restore must detect the mismatch, reshard,
+    device order is a different layout (device assignment is
+    load-bearing); restore must detect the mismatch, reshard,
     and produce identical values."""
     import jax
 

@@ -69,3 +69,13 @@ def test_registry_covers_telemetry_knobs():
                  "MX_HEARTBEAT_SEC", "MX_TELEMETRY_RETRACE_LIMIT"):
         assert name in env_vars.ENV_VARS, name
         assert env_vars.ENV_VARS[name][0] == "honored", name
+
+
+def test_the_scan_dispatch_and_executable_cache_names_are_gone():
+    """PR 9's four names left with their mechanisms (PR 29): none is
+    registered, and no use-site in the tree reads one."""
+    # spelled in pieces: a grep of the tree for the names stays empty
+    scan, cache = "MX_" + "SUPER" + "STEP", "MX_" + "EXECUTABLE" + "_CACHE"
+    gone = {scan, scan + "_FORCE_CPU", cache, cache + "_DIR"}
+    assert not gone & set(env_vars.ENV_VARS)
+    assert not gone & set(_scan(registry=set()))

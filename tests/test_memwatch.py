@@ -8,6 +8,7 @@ CLI contract, and the observe-don't-perturb parity guarantee."""
 import importlib.util
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -305,9 +306,9 @@ print([e["fingerprint"] for e in evs if e["kind"] == "compile"][0])
 
 def test_fingerprint_stable_across_process_restart():
     """Acceptance: the same program in two separate processes maps to the
-    SAME fingerprint (the AOT-cache key contract) — structural identity
-    only, no object ids.  The two restarts run concurrently: the test
-    pays one jax-import wall, not two (tier-1 budget)."""
+    SAME fingerprint — structural identity only, no object ids.  The two
+    restarts run concurrently: the test pays one jax-import wall, not two
+    (tier-1 budget)."""
     env = dict(os.environ)
     env.pop("MX_TELEMETRY_DIR", None)
     procs = [subprocess.Popen([sys.executable, "-c", _FP_SCRIPT],
@@ -607,6 +608,22 @@ def test_prometheus_gains_mem_gauges(tele, tmp_path, monkeypatch):
     assert 'mx_mem_category_bytes{rank="0",category="params"}' in text
     assert "mx_mem_compile_total" in text
     assert text.rstrip().endswith("# EOF")
+
+
+def test_compile_accounting_counts_compiles_and_nothing_else(tele, tmp_path):
+    """No second cache stands behind the jit sites since PR 29: the
+    compile rollup and its gauges carry a count and a wall, and a compile
+    event says nothing of where its executable came from."""
+    tele.enable(str(tmp_path))
+    _run_steps(_toy_step(), 2)
+    assert set(memwatch.summary()["compiles"]) == {"count", "wall_ms"}
+    text = open(telemetry.export_prometheus(str(tmp_path / "m.prom"))).read()
+    assert sorted(set(re.findall(r"mx_mem_compile_\w+", text))) == [
+        "mx_mem_compile_ms_total", "mx_mem_compile_total"]
+    compiles = [e for e in telemetry.flight_tail(256)
+                if e["kind"] == "compile"]
+    assert compiles and not any(
+        k.startswith(("cache_", "deserialize")) for e in compiles for k in e)
 
 
 def test_chrome_trace_gains_memory_counter_track(tele, tmp_path,

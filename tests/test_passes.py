@@ -10,8 +10,8 @@ JSON round-trips through the checkpoint-layout shape, MX_PASSES /
 MX_PALLAS_FUSED env semantics, AMP's backward-graph cast metadata
 seam, fused-kernel substitution at the traced dispatch branch, the
 weight-only int4 serving path (pack/dequant math, ≤0.16x weight bytes,
-top-1 agreement vs the fp32 engine, fingerprint splits, env gate, AOT
-restart round-trip in a second process), and training-side wiring
+top-1 agreement vs the fp32 engine, fingerprint splits, env gate), and
+training-side wiring
 (``DataParallelStep`` fingerprints, ``layout()`` round-trip, the
 ``plan`` telemetry event's pass fingerprint).
 
@@ -326,16 +326,16 @@ def _make_step(precision=None):
 
 
 def test_training_pipeline_splits_step_fingerprint(monkeypatch):
-    """The pipeline signature joins the step's AOT fingerprint: amp
+    """The pipeline signature joins the step's fingerprint: amp
     on/off × fused on/off are four distinct executables."""
     sig = ((((16, 8), "float32"),), ((16,), "float32"))
     monkeypatch.delenv("MX_PALLAS_FUSED", raising=False)
     monkeypatch.delenv("MX_PASSES", raising=False)
-    parts = [_make_step(None)._fingerprint_parts((), sig),
-             _make_step(PREC)._fingerprint_parts((), sig)]
+    parts = [_make_step(None)._fingerprint_parts(sig),
+             _make_step(PREC)._fingerprint_parts(sig)]
     monkeypatch.setenv("MX_PALLAS_FUSED", "1")
-    parts += [_make_step(None)._fingerprint_parts((), sig),
-              _make_step(PREC)._fingerprint_parts((), sig)]
+    parts += [_make_step(None)._fingerprint_parts(sig),
+              _make_step(PREC)._fingerprint_parts(sig)]
     fps = [memwatch.fingerprint(p) for p in parts]
     assert len(set(fps)) == 4, fps
 
@@ -355,7 +355,7 @@ def test_step_layout_roundtrips_pipeline(monkeypatch):
 
 def test_plan_event_carries_pass_fingerprint(tele, tmp_path):
     """Satellite: the ``plan`` telemetry event names the pass set and
-    the shared fingerprint keying the step's AOT executables."""
+    the shared fingerprint that names the step's executables."""
     mx.random.seed(0)
     net = nn.HybridSequential()
     net.add(nn.Dense(4, in_units=8))
@@ -540,9 +540,9 @@ def test_int4_engine_weight_bytes_and_top1_agreement(trained):
 
 def test_int4_config_splits_engine_fingerprint(trained):
     """ACCEPTANCE: fp32 vs int4 vs a different MX_QUANT_GROUP are three
-    distinct AOT fingerprints, while re-packing the same weights at the
+    distinct fingerprints, while re-packing the same weights at the
     same group reproduces the SAME fingerprint (the restart-stability
-    half of the contract — a same-config restart must hit)."""
+    half of the contract)."""
     net, src = trained
     mk = lambda ad: ServingEngine(ad, slots=2, page_size=4, max_len=8,
                                   stream_every=2)
@@ -592,7 +592,7 @@ def test_maybe_int4_env_gate(monkeypatch, trained):
 # ---------------------------------------------------------------------------
 def test_fused_pass_in_serving_engine(monkeypatch, trained):
     """MX_PALLAS_FUSED=1 swaps the registered kernels into the engine's
-    compiled decode/prefill (interpret mode here), splits the AOT
+    compiled decode/prefill (interpret mode here), splits the
     fingerprint, agrees top-1 with the stock engine, and MX_PASSES can
     veto the pass back out of the signature."""
     net, src = trained
@@ -619,77 +619,3 @@ def test_fused_pass_in_serving_engine(monkeypatch, trained):
                            page_size=4, max_len=12, stream_every=4)
     assert vetoed._pipeline.get("fused_kernels").enabled is False
     assert fp(vetoed) == fp(base)
-
-
-# ---------------------------------------------------------------------------
-# ACCEPTANCE: int4 AOT round-trip in a second process (the restart story)
-# ---------------------------------------------------------------------------
-_AOT4_CHILD = r"""
-import json, sys
-import numpy as np
-import mxnet_tpu as mx
-from mxnet_tpu import nd, telemetry
-from mxnet_tpu.models.transformer import Transformer
-from mxnet_tpu.precision import Int4WeightAdapter, maybe_int4_adapter
-from mxnet_tpu.serving import Request, ServingEngine, TransformerAdapter
-
-mx.random.seed(0)
-net = Transformer(16, units=32, hidden_size=64, num_heads=4, num_layers=2,
-                  max_length=48, dropout=0.0)
-net.initialize(mx.init.Xavier())
-rng = np.random.RandomState(4)
-prompts = [rng.randint(3, 16, 4) for _ in range(3)]
-
-# int4 packing reads the weights directly (no calibration forward), so
-# materialize the deferred-init parameters first
-net.translate(nd.array(prompts[0].reshape(1, -1), dtype="int32"), bos_id=1,
-              eos_id=2, max_len=3, beam_size=1)
-
-qad = maybe_int4_adapter(TransformerAdapter(net, src_max_len=6))
-assert isinstance(qad, Int4WeightAdapter)
-eng = ServingEngine(qad, slots=2, page_size=4, max_len=8, stream_every=2)
-out = eng.serve([Request(prompts[0], max_new_tokens=5, bos_id=1, eos_id=2)])
-evs = [e for e in telemetry.flight_tail(256) if e["kind"] == "compile"
-       and e.get("executor") == "ServingEngine"]
-print("I4AOT " + json.dumps({"compiles": evs,
-                             "tokens": [int(t) for t in
-                                        list(out.values())[0]]}))
-"""
-
-
-def test_int4_aot_cache_roundtrip(tmp_path):
-    """ACCEPTANCE: a second process under the SAME int4 config hits the
-    AOT cache on both compile events and decodes identical tokens; a
-    different MX_QUANT_GROUP misses (the fingerprint carries the int4
-    config).  Fresh private jax compile cache per phase (the
-    test_serving recipe)."""
-    import subprocess
-    import sys
-
-    def run_phase(tele_dir, group):
-        env = dict(os.environ,
-                   MX_SERVE_INT4="1", MX_QUANT_GROUP=group,
-                   MX_EXECUTABLE_CACHE_DIR=str(tmp_path / "aot"),
-                   MX_TELEMETRY_DIR=str(tmp_path / tele_dir),
-                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jaxcache"),
-                   JAX_PLATFORMS="cpu")
-        out = subprocess.run([sys.executable, "-c", _AOT4_CHILD], env=env,
-                             capture_output=True, text=True, timeout=300)
-        assert out.returncode == 0, out.stderr[-2000:]
-        line = [ln for ln in out.stdout.splitlines()
-                if ln.startswith("I4AOT ")][-1]
-        return json.loads(line[len("I4AOT "):])
-
-    first = run_phase("tele1", "32")
-    assert len(first["compiles"]) == 2
-    assert all(not e.get("cache_hit") for e in first["compiles"])
-
-    second = run_phase("tele2", "32")
-    assert len(second["compiles"]) == 2, second
-    for e in second["compiles"]:
-        assert e.get("cache_hit") is True, e
-        assert e.get("deserialize_ms", 0) > 0
-    assert second["tokens"] == first["tokens"]
-
-    other = run_phase("tele3", "16")
-    assert all(not e.get("cache_hit") for e in other["compiles"]), other

@@ -14,16 +14,11 @@ Four invariants:
   3. every enumerated Plan is LEGAL (axes exist, specs divide shapes,
      stages divide layers, batch divides over dp) and serializes
      losslessly;
-  4. the platform features — superstep scan, AOT executable cache,
-     elastic reshard — work THROUGH the Plan path, plus the PR-satellite
-     AOT coverage of kvstore._reduce_collective and CachedOp.__call__.
+  4. elastic reshard works THROUGH the Plan path.
 """
 import glob
 import json
 import os
-import subprocess
-import sys
-import tempfile
 
 import numpy as np
 import pytest
@@ -39,8 +34,6 @@ from mxnet_tpu.parallel import (DataParallelStep, Plan,
 from mxnet_tpu.parallel import planner
 from mxnet_tpu.parallel.planner import Hardware, ModelSignature
 from mxnet_tpu.parallel.sharding import ShardingRules
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
@@ -516,56 +509,6 @@ def test_plan_telemetry_event(tele):
 # ---------------------------------------------------------------------------
 # platform features THROUGH the Plan path
 # ---------------------------------------------------------------------------
-def test_superstep_through_plan_path(monkeypatch):
-    """MX_SUPERSTEP=2 over a plan-built step: bitwise identical to the
-    K=0 plan-built run on a single-device mesh."""
-    import jax
-
-    def run(k):
-        monkeypatch.setenv("MX_SUPERSTEP", str(k))
-        monkeypatch.setenv("MX_SUPERSTEP_FORCE_CPU", "1")
-        step = compile_step_with_plan(
-            _dense_net(), gluon.loss.L2Loss(), dp_plan(n_devices=1),
-            mesh=local_mesh(devices=[jax.devices("cpu")[0]]),
-            optimizer="sgd", optimizer_params={"learning_rate": 0.1})
-        losses = _run_steps(step, n=4)
-        step.drain()
-        return losses, _weights(step)
-
-    l0, w0 = run(0)
-    l2, w2 = run(2)
-    assert l0 == l2
-    for kk in w0:
-        np.testing.assert_array_equal(w0[kk], w2[kk])
-
-
-def test_aot_cache_through_plan_path(tele, tmp_path, monkeypatch):
-    """A second plan-built step over the same program deserializes the
-    persistent AOT executable (cache_hit compile event) instead of
-    recompiling — the restart SLO, through the Plan path."""
-    import jax
-
-    monkeypatch.setenv("MX_EXECUTABLE_CACHE_DIR", str(tmp_path / "aot"))
-
-    def build():
-        mx.random.seed(0)
-        net = nn.Dense(4, prefix="planaot_")   # fixed prefix: param
-        net.initialize(mx.init.Xavier())       # names are identity
-        return compile_step_with_plan(
-            net, gluon.loss.L2Loss(), dp_plan(n_devices=1),
-            mesh=local_mesh(devices=[jax.devices("cpu")[0]]),
-            optimizer="sgd", optimizer_params={"learning_rate": 0.1})
-
-    _run_steps(build(), n=1)
-    _run_steps(build(), n=1)
-    compiles = [e for e in _events(tele) if e.get("kind") == "compile"
-                and e.get("site") == "data_parallel"]
-    assert len(compiles) == 2
-    assert not compiles[0].get("cache_hit")
-    assert compiles[1].get("cache_hit") and \
-        compiles[1].get("deserialize_ms") is not None
-
-
 def test_elastic_reshard_through_plan_path(tele):
     """state_dict from a dp2 plan-built step restores onto a dp4
     plan-built step (reshard), the layout round-trips the Plan, and the
@@ -593,90 +536,3 @@ def test_elastic_reshard_through_plan_path(tele):
         np.testing.assert_array_equal(v, _weights(dst)[k])
     # and training continues through the plan path on the new mesh
     assert np.isfinite(_run_steps(dst, n=1)[0])
-
-
-# ---------------------------------------------------------------------------
-# PR satellites: AOT coverage of the two remaining jit sites
-# ---------------------------------------------------------------------------
-_SAT_CODE = """
-import os, numpy as np
-import mxnet_tpu as mx
-from mxnet_tpu import nd, telemetry, memwatch
-telemetry.enable(os.environ["SAT_TELE"])
-from mxnet_tpu.gluon import nn
-
-# CachedOp site (BatchNorm included: aux rebinding must survive the
-# no-trace warm load)
-net = nn.HybridSequential(prefix="sat_")
-with net.name_scope():
-    net.add(nn.Dense(8, activation="relu"), nn.BatchNorm(), nn.Dense(4))
-net.initialize(mx.init.Constant(0.05))
-net.hybridize()
-x = nd.array(np.linspace(0, 1, 24).reshape(4, 6).astype(np.float32))
-out = net(x)
-print("OUT", repr(float(np.asarray(out._data).sum())))
-
-# kvstore collective-reduce site
-kv = mx.kvstore.create("device")
-ctxs = [mx.cpu(i) for i in range(4)]
-kv.init("w", nd.zeros((3, 4), ctx=ctxs[0]))
-kv.push("w", [nd.ones((3, 4), ctx=c) * (i + 1) for i, c in enumerate(ctxs)])
-outp = nd.zeros((3, 4), ctx=ctxs[0])
-kv.pull("w", outp)
-print("KV", repr(float(outp.asnumpy().sum())))
-comp = memwatch.summary()["compiles"]
-print("HITS", comp.get("cache_hits", 0))
-"""
-
-
-def _run_sat(aot_dir, tele_dir):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               MX_EXECUTABLE_CACHE_DIR=aot_dir, SAT_TELE=tele_dir,
-               PYTHONPATH=_REPO)
-    r = subprocess.run([sys.executable, "-c", _SAT_CODE], env=env,
-                       capture_output=True, text=True, cwd=_REPO,
-                       timeout=240)
-    assert r.returncode == 0, r.stderr[-4000:]
-    out = {l.split()[0]: l.split(None, 1)[1]
-           for l in r.stdout.splitlines()
-           if l.startswith(("OUT", "KV", "HITS"))}
-    return out
-
-
-def test_kvstore_and_cachedop_aot_restart_roundtrip(tmp_path):
-    """The PR 9 'Known' closure: a restarted process deserializes the
-    kvstore._reduce_collective psum AND the CachedOp forward from the
-    persistent cache (cache hits booked, zero fresh value drift) —
-    including the CachedOp structural meta (n_out/treedef/aux names)
-    that a no-trace warm load cannot learn from tracing."""
-    aot = str(tmp_path / "aot")
-    os.makedirs(aot)
-    first = _run_sat(aot, str(tmp_path / "t1"))
-    assert first["HITS"] == "0"
-    n_entries = len(os.listdir(aot))
-    assert n_entries >= 2   # >=1 cachedop + 1 reduce executable
-    second = _run_sat(aot, str(tmp_path / "t2"))
-    assert int(second["HITS"]) >= 2, second
-    assert second["OUT"] == first["OUT"]
-    assert second["KV"] == first["KV"]
-    assert len(os.listdir(aot)) == n_entries  # hits, not re-stores
-
-
-def test_cachedop_aot_disabled_is_inert(tmp_path, monkeypatch):
-    """Kill switch: MX_EXECUTABLE_CACHE=0 writes nothing at either new
-    site and the values are byte-for-byte the plain-jit ones."""
-    monkeypatch.setenv("MX_EXECUTABLE_CACHE_DIR", str(tmp_path / "aot"))
-    monkeypatch.setenv("MX_EXECUTABLE_CACHE", "0")
-    net = nn.HybridSequential()
-    with net.name_scope():
-        net.add(nn.Dense(4))
-    net.initialize(mx.init.Constant(0.1))
-    net.hybridize()
-    out = net(nd.array(np.ones((2, 3), np.float32)))
-    assert np.isfinite(np.asarray(out._data)).all()
-    kv = mx.kvstore.create("device")
-    kv.init("w", nd.zeros((2, 2), ctx=mx.cpu(0)))
-    kv.push("w", [nd.ones((2, 2), ctx=mx.cpu(i)) for i in range(2)])
-    assert not os.path.exists(str(tmp_path / "aot")) or \
-        not os.listdir(str(tmp_path / "aot"))

@@ -4,8 +4,8 @@ level AMP pass, traced dynamic loss scaling, Plan/checkpoint round-trips.
 Covers: cast-policy semantics at the op-dispatch point, bf16-policy
 compiled steps tracking the fp32 oracle within tolerance, loss-scale
 skip-step semantics (injected non-finite grads leave weights / optimizer
-state / Adam's t untouched, scale halves, then regrows), superstep scan
-parity of the scaler state machine, AMP-off runs staying bitwise f32,
+state / Adam's t untouched, scale halves, then regrows), AMP-off runs
+staying bitwise f32,
 executable-fingerprint splits on precision config, env parsing, and
 ``Plan.precision`` + scaler state surviving checkpoint save -> elastic
 reshard -> restore.
@@ -262,36 +262,6 @@ def test_loss_scale_composes_with_clip_global_norm():
                                    rtol=2e-5, atol=1e-7)
 
 
-def test_superstep_scan_carries_scaler_faithfully(monkeypatch):
-    """MX_SUPERSTEP: the scaler joins the scan carry — final weights,
-    scale, and the per-step losses match sequential dispatch, including
-    a skip step in the middle of a group."""
-    monkeypatch.setenv("MX_SUPERSTEP_FORCE_CPU", "1")
-    x, y = _data(n=8)
-    bad = x.copy()
-    bad[0, 0] = np.inf
-    batches = [x, x, bad, x, x, x]
-
-    def run(superstep):
-        monkeypatch.setenv("MX_SUPERSTEP", "3" if superstep else "0")
-        step = _make_step(PREC_BF16, optimizer="adam", lr=0.01)
-        views = [step.step(nd.array(b), nd.array(y)) for b in batches]
-        step.drain()
-        losses = [float(v) for v in views]
-        return step, losses
-
-    seq, seq_losses = run(False)
-    sup, sup_losses = run(True)
-    finite = [i for i, b in enumerate(batches) if np.isfinite(b).all()]
-    for i in finite:
-        assert seq_losses[i] == sup_losses[i], (i, seq_losses, sup_losses)
-    for k in ("scale", "growth", "skipped"):
-        assert _host(seq.scaler_state[k]) == _host(sup.scaler_state[k]), k
-    for (_, pa), (_, pb) in zip(_in_order(seq.params),
-                                _in_order(sup.params)):
-        np.testing.assert_array_equal(_host(pa), _host(pb))
-
-
 # ---------------------------------------------------------------------------
 # executable identity: precision splits the fingerprint
 # ---------------------------------------------------------------------------
@@ -299,16 +269,15 @@ def test_precision_splits_executable_fingerprint():
     from mxnet_tpu import memwatch
 
     sig = ((( (16, 8), "float32"),), ((16,), "float32"))
-    base = _make_step(None)._fingerprint_parts((), sig)
-    amp = _make_step(PREC_BF16)._fingerprint_parts((), sig)
+    base = _make_step(None)._fingerprint_parts(sig)
+    amp = _make_step(PREC_BF16)._fingerprint_parts(sig)
     fp16 = _make_step(PrecisionConfig(
         amp=AmpPolicy(dtype="float16"),
-        loss_scale=LS))._fingerprint_parts((), sig)
+        loss_scale=LS))._fingerprint_parts(sig)
     static = _make_step(PrecisionConfig(
         amp=AmpPolicy(),
         loss_scale=LossScaleConfig(init_scale=16.0, growth_interval=4,
-                                   dynamic=False)))._fingerprint_parts(
-        (), sig)
+                                   dynamic=False)))._fingerprint_parts(sig)
     fps = [memwatch.fingerprint(p) for p in (base, amp, fp16, static)]
     assert len(set(fps)) == 4, fps
 

@@ -4,10 +4,9 @@ acceptance).
 Covers: quantize->dequantize round-trip vs the ops/quantization.py
 oracle, the calibrated int8 engine's top-1 agreement with the fp32
 engine on the reverse-task model, the ONE-int8-decode-executable
-property (telemetry compile events), AOT fingerprint miss on changed
-quant config + round-trip in a second process with cache_hit, the
-MX_QUANTIZE env gate, precision telemetry labels, and the `quantized`
-memwatch census category.
+property (telemetry compile events), the fingerprint split on a changed
+quant config, the MX_QUANTIZE env gate, precision telemetry labels, and
+the `quantized` memwatch census category.
 """
 import json
 import os
@@ -193,11 +192,10 @@ def test_one_int8_decode_executable(tele, tmp_path, trained):
     assert sites == ["serving_decode", "serving_prefill"], sites
 
 
-def test_quant_config_splits_aot_fingerprint(trained):
+def test_quant_config_splits_fingerprint(trained):
     """ACCEPTANCE: a different quant config (calib mode, excluded
-    layers, or fp32 vs int8) produces a different AOT-cache fingerprint
-    — a restart under different MX_QUANTIZE settings misses instead of
-    deserializing the wrong program."""
+    layers, or fp32 vs int8) produces a different executable
+    fingerprint on its compile events."""
     net, src = trained
     naive = _quantize(net, src, calib_mode="naive")
     entropy = _quantize(net, src, calib_mode="entropy")
@@ -325,73 +323,3 @@ def test_calibrate_observes_through_hybridized_blocks():
     assert set(thresholds) == {p for p, _ in layers}
     assert all(t > 0 for t in thresholds.values())
     assert net._active  # hybridization restored after the pass
-
-
-# ---------------------------------------------------------------------------
-# AOT round-trip in a second process (the restart story)
-# ---------------------------------------------------------------------------
-_AOT_CHILD = r"""
-import json, sys
-import numpy as np
-import mxnet_tpu as mx
-from mxnet_tpu import nd, telemetry
-from mxnet_tpu.models.transformer import Transformer
-from mxnet_tpu.precision import quantize_adapter
-from mxnet_tpu.serving import Request, ServingEngine, TransformerAdapter
-
-mx.random.seed(0)
-net = Transformer(16, units=32, hidden_size=64, num_heads=4, num_layers=2,
-                  max_length=48, dropout=0.0)
-net.initialize(mx.init.Xavier())
-rng = np.random.RandomState(4)
-prompts = [rng.randint(3, 16, 4) for _ in range(3)]
-
-def calib_fn(batch):
-    net.translate(nd.array(batch.reshape(1, -1), dtype="int32"), bos_id=1,
-                  eos_id=2, max_len=6, beam_size=1)
-
-qad = quantize_adapter(TransformerAdapter(net, src_max_len=6), prompts,
-                       calib_fn, calib_mode="naive")
-eng = ServingEngine(qad, slots=2, page_size=4, max_len=8, stream_every=2)
-out = eng.serve([Request(prompts[0], max_new_tokens=5, bos_id=1, eos_id=2)])
-evs = [e for e in telemetry.flight_tail(256) if e["kind"] == "compile"
-       and e.get("executor") == "ServingEngine"]
-print("QAOT " + json.dumps({"compiles": evs,
-                            "tokens": [int(t) for t in
-                                       list(out.values())[0]]}))
-"""
-
-
-def test_quantized_aot_cache_roundtrip(tmp_path):
-    """ACCEPTANCE: the int8 decode + prefill executables persist through
-    the AOT cache — a restarted quantized serving process asserts
-    cache_hit on both compile events and decodes identical tokens.
-    Fresh private jax compile cache per phase (the test_serving
-    recipe: serializing a jax-compile-cache-loaded executable is
-    unloadable on this XLA:CPU)."""
-    import subprocess
-    import sys
-
-    def run_phase(tele_dir):
-        env = dict(os.environ,
-                   MX_EXECUTABLE_CACHE_DIR=str(tmp_path / "aot"),
-                   MX_TELEMETRY_DIR=str(tmp_path / tele_dir),
-                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jaxcache"),
-                   JAX_PLATFORMS="cpu")
-        out = subprocess.run([sys.executable, "-c", _AOT_CHILD], env=env,
-                             capture_output=True, text=True, timeout=300)
-        assert out.returncode == 0, out.stderr[-2000:]
-        line = [ln for ln in out.stdout.splitlines()
-                if ln.startswith("QAOT ")][-1]
-        return json.loads(line[len("QAOT "):])
-
-    first = run_phase("tele1")
-    assert len(first["compiles"]) == 2
-    assert all(not e.get("cache_hit") for e in first["compiles"])
-
-    second = run_phase("tele2")
-    assert len(second["compiles"]) == 2, second
-    for e in second["compiles"]:
-        assert e.get("cache_hit") is True, e
-        assert e.get("deserialize_ms", 0) > 0
-    assert second["tokens"] == first["tokens"]

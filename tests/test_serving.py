@@ -5,7 +5,7 @@ Covers: bitwise paged-vs-dense attend parity, engine-greedy ==
 standalone translate(beam_size=1) token-for-token, the one-executable
 property on a mixed-length mid-flight trace (exactly one decode + one
 prefill compile event), continuous-batching slot/page reuse, scheduler
-backpressure, pool exhaustion, AOT executable round-trip, serve
+backpressure, pool exhaustion, serve
 telemetry + prometheus gauges, the Pallas ragged paged kernel, and the
 FullPrefixAdapter decoder-only path.
 """
@@ -309,11 +309,10 @@ def test_positional_capacity_fails_loudly():
                       bos_id=BOS, eos_id=EOS, max_len=32, beam_size=1)
 
 
-def test_fused_decision_in_aot_fingerprint():
+def test_fused_decision_in_fingerprint():
     """The fused-attention decision changes the traced program without
-    changing shapes — it must split the AOT-cache fingerprint, or a
-    restart under a different MX_SERVE_FLASH would deserialize the
-    wrong executable."""
+    changing shapes — it must split the fingerprint that names the
+    decode executable's compile events."""
     net = _tiny_model()
     parts = []
     for fused in (False, True):
@@ -326,7 +325,7 @@ def test_fused_decision_in_aot_fingerprint():
 
 
 # ---------------------------------------------------------------------------
-# satellites: telemetry, AOT cache, fused kernel, generic adapter
+# satellites: telemetry, fused kernel, generic adapter
 # ---------------------------------------------------------------------------
 def test_serve_telemetry_rollup_and_prometheus(tele, tmp_path):
     net = _tiny_model()
@@ -358,74 +357,6 @@ def test_serve_telemetry_rollup_and_prometheus(tele, tmp_path):
         assert e["tokens"] == 6 and e["reason"] == "length"
         assert "queue_wait_ms" in e and "prefill_ms" in e \
             and "decode_ms" in e
-
-
-_AOT_CHILD = r"""
-import json, sys
-import numpy as np
-import mxnet_tpu as mx
-from mxnet_tpu import telemetry
-from mxnet_tpu.models.transformer import Transformer
-from mxnet_tpu.serving import Request, ServingEngine, TransformerAdapter
-
-mx.random.seed(0)
-net = Transformer(16, units=32, hidden_size=64, num_heads=4, num_layers=2,
-                  max_length=48, dropout=0.0)
-net.initialize(mx.init.Xavier())
-eng = ServingEngine(TransformerAdapter(net, src_max_len=6), slots=2,
-                    page_size=4, max_len=8, stream_every=2)
-rng = np.random.RandomState(4)
-out = eng.serve([Request(rng.randint(3, 16, 4), max_new_tokens=5, bos_id=1,
-                         eos_id=2)])
-evs = [e for e in telemetry.flight_tail(256) if e["kind"] == "compile"
-       and e.get("executor") == "ServingEngine"]
-print("AOTEVS " + json.dumps({"compiles": evs,
-                              "tokens": [int(t) for t in
-                                         list(out.values())[0]]}))
-"""
-
-
-def test_aot_cache_roundtrip_deserializes(tmp_path):
-    """Satellite: decode + prefill executables persist through the PR 9
-    AOT cache — a restarted serving process deserializes instead of
-    recompiling (cache_hit + deserialize_ms on its compile events, the
-    python fn never retraced), and decodes the same tokens.
-
-    Both phases run as subprocesses with a PRIVATE fresh
-    JAX_COMPILATION_CACHE_DIR: on this jax/XLA:CPU, serializing an
-    executable that jax itself loaded from its persistent compile cache
-    produces an unloadable blob ('Symbols not found') — in production
-    that degrades gracefully (cache_corrupt -> fresh compile +
-    overwrite, asserted by test_superstep's corrupt-entry test), but
-    here it would mask the round-trip under a warm test-suite cache."""
-    import subprocess
-    import sys
-
-    def run_phase(tele_dir):
-        env = dict(os.environ,
-                   MX_EXECUTABLE_CACHE_DIR=str(tmp_path / "aot"),
-                   MX_TELEMETRY_DIR=str(tmp_path / tele_dir),
-                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jaxcache"),
-                   JAX_PLATFORMS="cpu")
-        out = subprocess.run([sys.executable, "-c", _AOT_CHILD], env=env,
-                             capture_output=True, text=True, timeout=300)
-        assert out.returncode == 0, out.stderr[-2000:]
-        line = [ln for ln in out.stdout.splitlines()
-                if ln.startswith("AOTEVS ")][-1]
-        return json.loads(line[len("AOTEVS "):])
-
-    first = run_phase("tele1")
-    assert len(first["compiles"]) == 2
-    assert all(not e.get("cache_hit") for e in first["compiles"])
-    assert len([f for f in os.listdir(tmp_path / "aot")
-                if f.endswith(".jexec")]) == 2
-
-    second = run_phase("tele2")
-    assert len(second["compiles"]) == 2, second
-    for e in second["compiles"]:
-        assert e.get("cache_hit") is True, e
-        assert e.get("deserialize_ms", 0) > 0
-    assert second["tokens"] == first["tokens"]
 
 
 def test_paged_flash_kernel_matches_dense_softmax():

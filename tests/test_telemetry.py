@@ -70,7 +70,7 @@ def test_jsonl_sink_ring_and_summary(tele, tmp_path):
     tail = tele.flight_tail(3)
     assert [e["kind"] for e in tail] == ["step", "collective",
                                         "checkpoint_save"]
-    json.dumps(s)  # summary must stay JSON-serializable (bench.py embeds it)
+    json.dumps(s)  # summary must stay JSON-serializable
 
 
 def test_heartbeat_atomic_and_rate_limited(tele, tmp_path, monkeypatch):

@@ -123,26 +123,6 @@ def test_a_profiler_trace_holds_the_steps_spans_nested_on_one_host_line(
     assert tele.summary()["events"] == {}
 
 
-def test_the_superstep_path_carries_the_same_names(tele, tmp_path,
-                                                   monkeypatch):
-    monkeypatch.setenv("MX_SUPERSTEP", "2")
-    monkeypatch.setenv("MX_SUPERSTEP_FORCE_CPU", "1")
-    tele.enable(str(tmp_path))
-    step, x, y = _tiny_step()
-    for _ in range(2):
-        step.step(x, y)
-    step.drain()
-    kept = tele.spans_between(*EVER)
-    (group,) = [s for s in kept if s.name == "train_step"]
-    assert [s.name for s in kept if s.parent == group.span] == STEP_SPANS[1:]
-    tele.flush()
-    (event,) = [e for e in map(json.loads,
-                               open(tele.event_path(str(tmp_path), 0)))
-                if e["kind"] == "span" and e["name"] == "train_step"]
-    assert (event["span"], event["superstep"], event["step_num"]) == (
-        group.span, 2, 2)
-
-
 def test_the_store_is_bounded(tele, tmp_path):
     tele.enable(str(tmp_path))
     n = tele.SPAN_STORE_SIZE + 10

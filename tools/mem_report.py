@@ -17,12 +17,8 @@ the after-the-run questions:
     floor), plus any ``mem_leak`` events the run recorded live.  Verdict
     per rank: ``leak`` / ``clean`` / ``no-data``;
   * **executable cost table** — one row per ``compile`` event: executor,
-    stable fingerprint (the AOT-cache key), compile wall, FLOPs,
-    argument/output/temp bytes where the run captured them, and the
-    AOT-cache disposition — entries the run DESERIALIZED from the
-    persistent executable cache (``MX_EXECUTABLE_CACHE_DIR``) are marked
-    ``hit`` with their deserialize wall, so a post-mortem distinguishes
-    "loaded in 0.2s" from "compiled in 40s";
+    stable fingerprint, compile wall, FLOPs, argument/output/temp bytes
+    where the run captured them;
   * **OOM post-mortems** — any ``oom_report`` echoed verbatim (largest
     category, watermark, in-flight depth, top executables).
 
@@ -192,8 +188,6 @@ def _executables(ranks: Dict[int, List[dict]]) -> List[dict]:
                 "arg_bytes": e.get("arg_bytes"),
                 "out_bytes": e.get("out_bytes"),
                 "temp_bytes": e.get("temp_bytes"),
-                "cache_hit": bool(e.get("cache_hit", False)),
-                "deserialize_ms": e.get("deserialize_ms"),
             })
     rows.sort(key=lambda r: (-(r["temp_bytes"] or 0),
                              -(r["bytes_accessed"] or 0), -r["wall_ms"]))
@@ -281,23 +275,15 @@ def format_text(rep: dict) -> str:
         w("executable cost table (compile events)")
         w(f"  {'rank':>4} {'executor':<34} {'fingerprint':<17} "
           f"{'wall ms':>9} {'flops':>12} {'args':>9} {'out':>9} "
-          f"{'temp':>9} {'aot':>12}")
+          f"{'temp':>9}")
         for row in rep["executables"]:
             flops = (f"{row['flops']:.3g}" if row["flops"] is not None
                      else "-")
-            # "hit(0.2s)" = deserialized from the persistent AOT cache,
-            # never compiled in this process; "-" = compiled fresh
-            if row["cache_hit"]:
-                des = row.get("deserialize_ms")
-                aot = (f"hit({des / 1e3:.1f}s)" if des is not None
-                       else "hit")
-            else:
-                aot = "-"
             w(f"  {row['rank']:>4} {row['executor']:<34.34} "
               f"{row['fingerprint']:<17} {row['wall_ms']:>9.1f} "
               f"{flops:>12} {_fmt_bytes(row['arg_bytes']):>9} "
               f"{_fmt_bytes(row['out_bytes']):>9} "
-              f"{_fmt_bytes(row['temp_bytes']):>9} {aot:>12}")
+              f"{_fmt_bytes(row['temp_bytes']):>9}")
         w("")
     for e in rep["ooms"]:
         w(f"OOM post-mortem: rank {e['rank']} step {e.get('step')}: "

@@ -86,13 +86,9 @@ RULES = {
 HOT_PATH_ENTRIES = {
     "mxnet_tpu/parallel/data_parallel.py": (
         "DataParallelStep._step_impl", "DataParallelStep.stage",
-        # superstep mode: the group dispatch body and the scan-body
-        # builder (its nested lax.scan body is the hottest path in the
-        # tree — K steps per dispatch ride through it)
-        "DataParallelStep._superstep_impl", "DataParallelStep._super_fn",
-        # the unified Plan dispatch body: EVERY compiled-step execution
-        # (single step or superstep, any strategy Plan) funnels through
-        # it — a host sync here would stall every strategy at once
+        # the Plan dispatch body: EVERY compiled-step execution (any
+        # strategy Plan) funnels through it — a host sync here would
+        # stall every strategy at once
         "DataParallelStep._plan_dispatch"),
     "mxnet_tpu/optimizer/fused.py": ("FusedUpdater._apply_impl",),
     # precision subsystem (docs/PRECISION.md): the fused overflow reduce
@@ -919,7 +915,7 @@ class FileLint:
         non-pass plumbing).  Any other ``<module>._underscore`` load in
         the body is a pass smuggled around the pipeline — invisible to
         the pipeline fingerprint, so two different traced programs
-        would collide on one AOT cache key."""
+        would carry one name."""
         cfg = self.pass_entries.get(self.path)
         if not cfg:
             return
