@@ -386,6 +386,36 @@ def kernels_phase(ctx, on_path, fused_paged_attention, rows, units, heads,
              f"relative delta to the einsum form {d_fwd:.1e} forward, "
              f"{d_bwd:.1e} over its seven gradients")
 
+        # the held experts' chunk loop, forward and backward, at the
+        # nemotron_h widths: megablox's grouped products and the combine
+        # against ragged_dot and a scatter-add (the form a partitioned step
+        # takes)
+        from mxnet_tpu.ops import moe_ops
+
+        eu, ew = rand(2048, 2688), rand(2048, 2688)
+        up, down = (rand(8, 2688, 1856) * 0.02).astype(jnp.bfloat16), \
+            (rand(8, 1856, 2688) * 0.02).astype(jnp.bfloat16)
+        experts, gates = jax.jit(lambda u, r: moe_ops.moe_route(
+            u, r, jnp.zeros((32,)), top_k=6, scaling=2.5))(eu, rand(32, 2688))
+
+        def experts_loss(u, gates, up, down):
+            out = moe_ops.moe_experts(u, experts, gates, up, down)[0]
+            return (out.astype(jnp.float32) * ew.astype(jnp.float32)).sum()
+
+        got = jax.jit(jax.value_and_grad(
+            experts_loss, argnums=(0, 1, 2, 3)))(eu, gates, up, down)
+        with pallas.compute_on(dev.platform, partitioned=True):
+            want = jax.jit(jax.value_and_grad(
+                experts_loss, argnums=(0, 1, 2, 3)))(eu, gates, up, down)
+        d_fwd = delta(got[0], want[0])
+        d_bwd = max(delta(a, b) for a, b in zip(got[1], want[1]))
+        check(d_fwd < 3e-2 and d_bwd < 3e-2,
+              f"moe_experts differs: forward {d_fwd}, backward {d_bwd}")
+        line("moe_combine", ("mx_moe_combine",),
+             f"alone at {tuple(eu.shape)} bf16, 8 of 32 experts of 1856 held, "
+             f"6 choices: relative delta to the scatter-add form "
+             f"{d_fwd:.1e} for the sum, {d_bwd:.1e} over its four gradients")
+
         x, r = rand(rows, units), rand(rows, units)
         g = jnp.ones((units,), jnp.bfloat16)
         b = jnp.zeros((units,), jnp.bfloat16)

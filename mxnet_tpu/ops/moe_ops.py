@@ -17,9 +17,10 @@ load at 8 of 128 experts and 6 choices; the worst case, ``tokens *
 min(top_k, count)`` rows, only sizes the index arrays).  A chunk gathers its
 tokens' rows, runs them through one grouped matrix product per projection
 (megablox ``gmm`` on a TPU, ``jax.lax.ragged_dot`` elsewhere) and adds its
-weighted results to its tokens' rows.  The work follows the landed pairs a
-chunk at a time; inside a chunk every row runs, the rows past the landed
-pairs as zeros.
+weighted results to its tokens' rows of the loop's f32 carry (one Mosaic
+call on a TPU, ``ops/pallas/moe_rows.py``; a scatter-add elsewhere).  The
+work follows the landed pairs a chunk at a time; inside a chunk every row
+runs, the rows past the landed pairs with no weight.
 """
 from __future__ import annotations
 
@@ -28,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import pallas as _pk
+from .pallas import moe_rows
 from .registry import register
 
 #: m, k and n tile of the grouped products on the chip (VMEM: about 10 MB)
@@ -57,42 +60,99 @@ def moe_route(data, weight, correction_bias, top_k=1, scaling=1.0,
         return experts.astype(jnp.int32), w * scaling
 
 
-def _grouped_dot(lhs, rhs, group_sizes):
+def _kernels(rows):
+    """True where a chunk of ``rows`` sorted rows runs as Mosaic calls: on a
+    TPU, in per-device code, at whole row tiles.  Elsewhere (the CPU, a step
+    that GSPMD partitions, other row counts) the same chunk runs as XLA ops."""
+    return (_pk.enabled() and _pk.use_compiled()
+            and rows % GMM_TILING[0] == 0)
+
+
+def _grouped_dot(lhs, rhs, group_sizes, transpose_rhs=False):
     """Rows of ``lhs`` (m, k), sorted by group, times their group's
-    ``rhs[g]`` (k, n) -> (m, n) in lhs's type.  ``group_sizes`` sum to m."""
-    from . import pallas as _pk
+    ``rhs[g]`` (k, n), or its transpose (rhs (groups, n, k)) -> (m, n) in
+    lhs's type.  ``group_sizes`` sum to m."""
+    if _kernels(lhs.shape[0]):
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
-    if _pk.enabled() and _pk.use_compiled() \
-            and lhs.shape[0] % GMM_TILING[0] == 0:
-        from jax.experimental.pallas.ops.tpu.megablox import ops as _mb
-
-        return _mb.gmm(lhs, rhs, group_sizes, lhs.dtype, GMM_TILING)
+        return gmm(lhs, rhs, group_sizes, lhs.dtype, GMM_TILING,
+                   transpose_rhs=transpose_rhs, interpret=_pk.interpret())
+    if transpose_rhs:
+        rhs = rhs.swapaxes(1, 2)
     return jax.lax.ragged_dot(
         lhs, rhs, group_sizes,
         preferred_element_type=jnp.float32).astype(lhs.dtype)
 
 
-def _chunk_part(data, flat_w, up, down, lo, order, starts, ends, k, chunk):
-    """What sorted rows ``lo .. lo + chunk - 1`` add to every token's result
-    (a (tokens, d) f32 array, zero but for those rows' tokens)."""
-    landed = ends[-1]
+_ROWS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _grouped_dot_weights_grad(acc, lhs, d_out, group_sizes):
+    """``acc`` (groups, k, n) f32 plus every group's ``lhs_g^T d_out_g``:
+    what ``_grouped_dot(lhs, rhs, group_sizes)``'s gradient gives ``rhs``,
+    summed in f32 into what is there (megablox ``tgmm`` adds to
+    ``existing_out`` and aliases it)."""
+    if _kernels(lhs.shape[0]):
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+        # an f32 block that is read and written takes four times a bf16
+        # result's VMEM: half the n tile
+        tm, tk, tn = GMM_TILING
+        return tgmm(lhs.swapaxes(0, 1), d_out, group_sizes, jnp.float32,
+                    (tm, tk, tn // 2), existing_out=acc,
+                    interpret=_pk.interpret())
+    return acc + jax.lax.ragged_dot_general(
+        lhs, d_out, group_sizes, _ROWS_CONTRACTED,
+        preferred_element_type=jnp.float32)
+
+
+def _add_rows(acc, rows, token, scale, here, n_live, fresh):
+    """``acc`` (tokens, d) f32 plus ``rows[i] * scale[i]`` at ``token[i]``
+    for the landed rows ``i < n_live``, multiplied and summed in f32.
+    ``fresh`` says that ``acc`` is still all zero (the loop's first chunk).
+    On the chip one Mosaic call that writes every row of ``acc`` once and
+    does not read a fresh one (``ops/pallas/moe_rows.py``); elsewhere a
+    scatter-add into ``acc``."""
+    if _kernels(rows.shape[0]) and moe_rows.fits(
+            acc.shape[0], *rows.shape, here.shape[0]):
+        return moe_rows.combine(acc, rows, token, scale, here, n_live, fresh,
+                                interpret=_pk.interpret())
+    live = jnp.arange(rows.shape[0]) < n_live
+    return acc.at[token].add(
+        rows.astype(jnp.float32) * jnp.where(live, scale, 0)[:, None])
+
+
+def _chunk_index(i, order, flat_w, starts, ends, k, chunk):
+    """Sorted rows ``i * chunk .. (i + 1) * chunk - 1``: their (token,
+    choice) pairs and tokens, how many of them are landed pairs (they come
+    first), their weights (0 past the landed pairs) and how many fall to each
+    held expert."""
+    lo = i * chunk
     pairs = jax.lax.dynamic_slice(order, (lo,), (chunk,))
-    token = pairs // k
-    live = lo + jnp.arange(chunk) < landed
+    n_live = jnp.clip(ends[-1] - lo, 0, chunk)
     here = jnp.clip(jnp.minimum(ends, lo + chunk) - jnp.maximum(starts, lo),
                     0, None)
-    # a chunk runs whole: the rows past the landed pairs are zero rows, given
-    # to the last held expert, so that a step's time does not follow the
-    # router's draw from chunk to chunk (docs/NEMOTRON_H.md has the numbers)
+    # a chunk runs whole, so that a step's time does not follow the router's
+    # draw from chunk to chunk (docs/NEMOTRON_H.md has the numbers): the rows
+    # past the landed pairs go to the last held expert.  They are the rows of
+    # whatever tokens their pairs name and weigh nothing: what they make is
+    # added nowhere, and their gradient is 0 times a finite number
     here = here.at[-1].add(chunk - jnp.sum(here))
-    rows = jnp.where(live[:, None], data[token], 0)
+    w = jnp.where(jnp.arange(chunk) < n_live, flat_w[pairs], 0)
+    return pairs, pairs // k, n_live, w, here
+
+
+def _chunk_products(data, up, down, token, here):
+    """A chunk's rows of ``data`` and what the held experts make of them."""
+    rows = data[token]
     with jax.named_scope("mx_moe_experts"):
         h = _grouped_dot(rows, up, here)
         r = jnp.maximum(h, 0)
-        y = _grouped_dot(r * r, down, here)
-    w = jnp.where(live, flat_w[pairs], 0)
-    return jnp.zeros(data.shape, jnp.float32).at[token].add(
-        y.astype(jnp.float32) * w[:, None])
+        a = r * r
+        y = _grouped_dot(a, down, here)
+    return rows, h, r, a, y
 
 
 def _chunks_to_run(ends, chunk):
@@ -100,17 +160,25 @@ def _chunks_to_run(ends, chunk):
 
 
 # The loop over chunks runs as many times as landed pairs need, so it cannot
-# be differentiated through; forward and backward are written out.  The
-# backward keeps nothing of the forward but its inputs: a chunk's rows are
-# gathered and multiplied again where its gradient is taken.
+# be differentiated through; forward and backward are written out, once, for
+# both back ends.  Every accumulator (the tokens' result; the gradients of
+# the tokens, the weights and both projections) is the loop's f32 carry and a
+# chunk adds to it in place.  The backward keeps nothing of the forward but
+# its inputs: a chunk's rows are gathered and multiplied again where its
+# gradient is taken.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
 def _all_chunks(data, flat_w, up, down, order, starts, ends, k, chunk):
     def body(i, out):
-        return out + _chunk_part(data, flat_w, up, down, i * chunk, order,
-                                 starts, ends, k, chunk)
+        _pairs, token, n_live, w, here = _chunk_index(
+            i, order, flat_w, starts, ends, k, chunk)
+        y = _chunk_products(data, up, down, token, here)[-1]
+        return _add_rows(out, y, token, w, here, n_live, fresh=i == 0)
 
-    return jax.lax.fori_loop(0, _chunks_to_run(ends, chunk), body,
-                             jnp.zeros(data.shape, jnp.float32))
+    out = jax.lax.fori_loop(0, _chunks_to_run(ends, chunk), body,
+                            jnp.zeros(data.shape, jnp.float32))
+    # rounded here, so that the gradient comes back in data's type and its
+    # rows are gathered at that width (as exact: the chunks widen them)
+    return out.astype(data.dtype)
 
 
 def _all_chunks_fwd(data, flat_w, up, down, order, starts, ends, k, chunk):
@@ -123,10 +191,27 @@ def _all_chunks_bwd(k, chunk, res, d_out):
     f32 = jnp.float32
 
     def body(i, acc):
-        _out, pull = jax.vjp(
-            lambda *a: _chunk_part(*a, i * chunk, order, starts, ends, k,
-                                   chunk), data, flat_w, up, down)
-        return tuple(a + g.astype(f32) for a, g in zip(acc, pull(d_out)))
+        d_data, d_flat_w, d_up, d_down = acc
+        pairs, token, n_live, w, here = _chunk_index(
+            i, order, flat_w, starts, ends, k, chunk)
+        live = jnp.arange(chunk) < n_live
+        rows, h, r, a, y = _chunk_products(data, up, down, token, here)
+        # out[token] += w y: d_out's rows in f32, rounded once after the
+        # multiplication by w; a weight gets its row's product with y
+        taken = d_out[token].astype(f32)
+        d_y = (taken * w[:, None]).astype(y.dtype)
+        d_w = jnp.sum(taken * y.astype(f32), -1)
+        d_flat_w = d_flat_w.at[pairs].add(jnp.where(live, d_w, 0))
+        with jax.named_scope("mx_moe_experts"):
+            d_a = _grouped_dot(d_y, down, here, transpose_rhs=True)
+            d_down = _grouped_dot_weights_grad(d_down, a, d_y, here)
+            d_r = d_a * r
+            d_h = jnp.where(h > 0, d_r + d_r, 0)
+            d_rows = _grouped_dot(d_h, up, here, transpose_rhs=True)
+            d_up = _grouped_dot_weights_grad(d_up, rows, d_h, here)
+        d_data = _add_rows(d_data, d_rows, token, live.astype(f32), here,
+                           n_live, fresh=i == 0)
+        return d_data, d_flat_w, d_up, d_down
 
     acc = jax.lax.fori_loop(
         0, _chunks_to_run(ends, chunk), body,
@@ -164,4 +249,4 @@ def moe_experts(data, experts, weights, up_weight, down_weight, first=0):
     out = _all_chunks(data, weights.astype(jnp.float32).reshape(-1),
                       up_weight, down_weight, order, ends - sizes, ends, k,
                       chunk)
-    return out.astype(data.dtype), sizes
+    return out, sizes

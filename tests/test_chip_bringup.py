@@ -316,7 +316,99 @@ def test_expert_products_lower_as_grouped_kernels_at_the_published_widths():
         jax.grad(loss, argnums=(0, 3, 4)), _s((1024, 2688)),
         _s((1024, 6), jnp.int32), _s((1024, 6), jnp.float32),
         _s((8, 2688, 1856)), _s((8, 1856, 2688)))
-    assert len(calls) >= 5 and set(calls) == {"kernel"}   # megablox gmm, tgmm
+    # megablox gmm and tgmm, and the combine of ops/pallas/moe_rows.py
+    assert len(calls) >= 5 and set(calls) == {"kernel", "mx_moe_combine"}
+
+
+def _hlo_computations(exported):
+    """{name: lines} of the exported module as HLO text, and for a
+    computation the lines of everything it reaches through calls."""
+    import re
+
+    from jax._src.lib import xla_client as xc
+
+    hlo = xc._xla.mlir.mlir_module_to_xla_computation(
+        exported.mlir_module(), use_tuple_args=False,
+        return_tuple=False).as_hlo_text()
+    comps, name = {}, None
+    for ln in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?([\w.\-]+) (?:\(.*\) -> .* )?\{$", ln)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif ln.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(ln)
+
+    def reach(start):
+        seen, todo = [], [start]
+        while todo:
+            at = todo.pop()
+            if at in seen:
+                continue
+            seen.append(at)
+            for ln in comps[at]:
+                for group in re.findall(
+                        r"(?:to_apply|body|condition|calls)=([\w.\-]+)|"
+                        r"branch_computations=\{([^}]*)\}", ln):
+                    todo += [n.strip() for g in group if g
+                             for n in g.split(",")]
+        return {n: comps[n] for n in seen}
+
+    return comps, reach
+
+
+def test_the_experts_loops_keep_their_work_inside_at_the_cells_shapes():
+    """Forward and backward of the held experts at the Nemotron cell's
+    shapes (16,384 tokens of 2,688, 8 held of 128, 6 choices), lowered for
+    the TPU: both loops are the ``while`` instructions the cell's
+    ``moe_experts`` pattern finds, the grouped products and the combine are
+    inside their bodies, and no body scatters rows or fills an array as
+    large as an accumulator: a chunk adds into the loop's carry."""
+    import re
+
+    from mxnet_tpu.ops import moe_ops
+
+    def both(ct, x, idx, w, up, down):
+        out, vjp = jax.vjp(lambda x, w, up, down: moe_ops.moe_experts(
+            x, idx, w, up, down, first=0)[0], x, w, up, down)
+        return (out,) + vjp(ct)
+
+    tokens = 16384
+    with pallas.compute_on("tpu"):
+        exp = jax.export.export(jax.jit(both), platforms=["tpu"])(
+            _s((tokens, 2688)), _s((tokens, 2688)),
+            _s((tokens, 6), jnp.int32), _s((tokens, 6), jnp.float32),
+            _s((8, 2688, 1856)), _s((8, 1856, 2688)))
+    assert set(re.findall(r'kernel_name = "(\w+)"', exp.mlir_module())) == {
+        "kernel", "mx_moe_combine"}
+    with open(os.path.join(_REPO, "benchmark", "checks",
+                           "nemotron_twotower_30b_a3b.train_2x8k.json")) as f:
+        pattern = json.load(f)["kernels"]["moe_experts"]
+    comps, reach = _hlo_computations(exp)
+    loops = [ln for lines in comps.values() for ln in lines
+             if " while(" in ln and re.search(pattern, "%" + ln.strip())]
+    assert len(loops) == 2                       # forward, backward
+    wide = r"(16384,2688|16384,1856|8,2688,1856|8,1856,2688)"
+    seen = []
+    for loop in loops:
+        body = reach(re.search(r"body=([\w.\-]+)", loop).group(1))
+        lines = [ln for ls in body.values() for ln in ls]
+        kernels = [re.sub(r"[_.]\d.*$", "", n) for n, ls in body.items()
+                   if any('"tpu_custom_call"' in ln for ln in ls)]
+        seen.append(sorted(kernels))
+        # rows are gathered (XLA's gather runs at the memory's pace), never
+        # scattered, and nothing of an accumulator's size is filled
+        assert not [ln for ln in lines
+                    if re.search(r"\[" + wide + r"\]\S* scatter\(", ln)]
+        assert not [ln for ln in lines if re.search(
+            r"\[(16384,2688|8,2688,1856|8,1856,2688)\]\S* broadcast\("
+            r".*dimensions=\{\}", ln)]
+    # by jitted function: up and down; those, their transposes, the two
+    # weight gradients
+    assert sorted(seen) == [["combine"] + ["gmm"] * 2,
+                            ["combine"] + ["gmm"] * 4 + ["tgmm"] * 2]
 
 
 def test_layer_norm_kernels_lower_for_tpu():
@@ -416,6 +508,17 @@ def test_kernels_are_not_selected_where_gspmd_partitions():
         assert _mosaic_calls(lambda x, g, b: nd.LayerNorm(
             nd.NDArray(x), nd.NDArray(g), nd.NDArray(b))._data,
             x, g, g, partitioned=True) == []
+        # the experts' chunk loop: ragged_dot and a scatter-add there
+        from mxnet_tpu.ops import moe_ops
+
+        assert not moe_ops._kernels(16384)
+        assert _mosaic_calls(
+            jax.grad(lambda x, idx, w, up, down: moe_ops.moe_experts(
+                x, idx, w, up, down)[0].astype(jnp.float32).sum(),
+                argnums=(0, 3, 4)),
+            _s((1024, 256)), _s((1024, 6), jnp.int32),
+            _s((1024, 6), jnp.float32), _s((8, 256, 128)),
+            _s((8, 128, 256)), partitioned=True) == []
         # a shard_map body is per-device code again
         seen = []
         mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
