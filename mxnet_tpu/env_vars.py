@@ -141,8 +141,10 @@ ENV_VARS: Dict[str, Tuple[str, str]] = {
     "MX_ASYNC_INFLIGHT": (
         "honored", "bounded in-flight dispatch window: how many "
         "dispatched-but-unforced steps may be pending before dispatch "
-        "blocks on the oldest (default 2; 0 = synchronous, every step "
-        "forced at dispatch).  Read per step call by "
+        "blocks on the oldest (default 2; unset, DataParallelStep.step, "
+        "whose handle pins one scalar, grows its window to at most 8 while "
+        "a step ends under 1.25 s after its dispatch; 0 = synchronous, "
+        "every step forced at dispatch).  Read per step call by "
         "parallel/async_loss.py; honored by DataParallelStep.step (lazy "
         "AsyncLoss), gluon Trainer.step and module.Module.update (step "
         "fences)"),

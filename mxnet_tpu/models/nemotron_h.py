@@ -37,40 +37,18 @@ import functools
 import math
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from .. import initializer as init
 from ..gluon import nn
 from ..gluon.block import HybridBlock
-from ..gluon.parameter import record_aux_update
-from ..ndarray import NDArray
-from ..ops import registry as _reg
+from .common import HeldExperts, checkpointed, vocab_logits
 
 #: the published ``hybrid_override_pattern``: 23 M, 23 E, 6 *
 PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
 __all__ = ["Mamba2Mixer", "MoELayer", "GQAttention", "NemotronHLayer",
            "NemotronHModel", "nemotron_h"]
-
-
-def _checkpointed(block, x):
-    """``block(x)`` with its activations recomputed in the backward pass
-    while a jitted step is being traced; a plain call otherwise."""
-    if not isinstance(x._data, jax.core.Tracer):
-        return block(x)
-    ctx = x.context
-
-    def pure(a):
-        out = block(NDArray(a, ctx=ctx))
-        if isinstance(out, (list, tuple)):
-            return tuple(o._data for o in out)
-        return out._data
-
-    out = jax.checkpoint(pure)(x._data)
-    if isinstance(out, tuple):
-        return [NDArray(o, ctx=ctx) for o in out]
-    return NDArray(out, ctx=ctx)
 
 
 class Mamba2Mixer(HybridBlock):
@@ -133,7 +111,7 @@ class Mamba2Mixer(HybridBlock):
         return self.out_proj(y)
 
 
-class MoELayer(HybridBlock):
+class MoELayer(HeldExperts):
     """Sigmoid top-k routing over ``n_routed_experts``, the held experts'
     ``relu2`` feed-forwards as one grouped product, and one shared expert.
     Returns ``(out, load)``: see the module docstring."""
@@ -142,14 +120,9 @@ class MoELayer(HybridBlock):
                  top_k=6, expert_width=1856, shared_width=3712,
                  routed_scaling_factor=2.5, norm_topk_prob=True,
                  router_lr_mult=1.0, prefix=None, params=None):
-        super().__init__(prefix=prefix, params=params)
-        first, count = experts_held or (0, n_routed_experts)
-        if first < 0 or count < 1 or first + count > n_routed_experts:
-            raise ValueError(f"experts_held {experts_held} of "
-                             f"{n_routed_experts}")
-        self._e, self._first, self._count = n_routed_experts, first, count
-        self._k, self._scale = top_k, routed_scaling_factor
-        self._norm = norm_topk_prob
+        super().__init__(units, n_routed_experts, experts_held, top_k,
+                         expert_width, "relu2", prefix=prefix, params=params)
+        self._scale, self._norm = routed_scaling_factor, norm_topk_prob
         with self.name_scope():
             self.router_weight = self.params.get(
                 "router_weight", shape=(n_routed_experts, units),
@@ -157,22 +130,12 @@ class MoELayer(HybridBlock):
             self.correction_bias = self.params.get(
                 "e_score_correction_bias", shape=(n_routed_experts,),
                 init="zeros", grad_req="null")
-            self.up_weight = self.params.get(
-                "experts_up_weight", shape=(count, units, expert_width))
-            self.down_weight = self.params.get(
-                "experts_down_weight", shape=(count, expert_width, units))
             self.shared_up = nn.Dense(shared_width, flatten=False,
                                       use_bias=False, in_units=units,
                                       prefix="shared_up_")
             self.shared_down = nn.Dense(units, flatten=False, use_bias=False,
                                         in_units=shared_width,
                                         prefix="shared_down_")
-            self.load = self.params.get("load", shape=(count,), init="zeros",
-                                        grad_req="null")
-            self.load_max = self.params.get("load_max", shape=(count,),
-                                            init="zeros", grad_req="null")
-        # DataParallelStep.drain reads what carries this mark
-        self.load.telemetry = self.load_max.telemetry = "moe_load"
 
     def hybrid_forward(self, F, u, router_weight, correction_bias, up_weight,
                        down_weight, load, load_max):
@@ -180,21 +143,11 @@ class MoELayer(HybridBlock):
         experts, weights = F._contrib_moe_route(
             flat, router_weight, correction_bias, top_k=self._k,
             scaling=self._scale, norm_topk_prob=self._norm)
-        routed, landed = F._contrib_moe_experts(
-            flat, experts, weights, up_weight, down_weight,
-            first=self._first)
+        routed, load = self.routed(F, flat, experts, weights, up_weight,
+                                   down_weight, load)
         with jax.named_scope("mx_moe_shared"):
             shared = self.shared_down(F._contrib_relu2(self.shared_up(flat)))
-        out = (routed + shared).reshape(u.shape)
-        even = flat.shape[0] * self._k / self._e     # pairs an expert gets
-        return out, (landed.astype("float32") / even).astype(load.dtype)
-
-    def record_load(self, load):
-        """The aux write of this step's ``load`` (outside any recomputed
-        region: an aux value must belong to the step's own trace)."""
-        record_aux_update(self.load, load)
-        record_aux_update(self.load_max, _reg.invoke_fn(
-            jnp.maximum, [self.load_max.data(load.context), load]))
+        return (routed + shared).reshape(u.shape), load
 
 
 class GQAttention(HybridBlock):
@@ -293,17 +246,14 @@ class NemotronHModel(HybridBlock):
     def hybrid_forward(self, F, tokens, head_weight):
         x = self.embed(tokens)
         for layer in self.layers:
-            out = _checkpointed(layer, x)
+            out = checkpointed(layer, x)
             if isinstance(out, (list, tuple)):
                 x, load = out
                 layer.mixer.record_load(load)
             else:
                 x = out
         h = self.norm_f(x)
-        return _reg.invoke_fn(
-            lambda a, w: jnp.einsum("bld,vd->blv", a, w,
-                                    preferred_element_type=jnp.float32),
-            [h, head_weight])
+        return vocab_logits(h, head_weight)
 
 
 def nemotron_h(**kwargs) -> NemotronHModel:

@@ -26,6 +26,33 @@ def _dense_attention(q, k, v, causal, sm_scale):
     return jnp.einsum("nqk,nkd->nqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+@register("_contrib_rotary")
+def rotary(data, theta=10000.0, fraction=1.0, axis=1):
+    """Rotary position on the first ``fraction`` of the last axis of
+    ``data``; positions 0, 1, ... run along ``axis``.  With ``R = fraction *
+    D`` rotated channels (even), channel ``i < R / 2`` pairs with ``i + R /
+    2`` (rotate-half) and the pair turns by ``position * theta^(-2 i / R)``;
+    channels from R on pass unchanged.  Angles and the rotation in f32."""
+    with jax.named_scope("mx_rotary"):
+        d = data.shape[-1]
+        rot = int(round(d * float(fraction)))
+        if rot % 2 or not 0 < rot <= d:
+            raise ValueError(f"rotary: {fraction} of {d} channels")
+        axis = axis % data.ndim
+        half = rot // 2
+        freq = float(theta) ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        pos = jnp.arange(data.shape[axis], dtype=jnp.float32)
+        shape = [1] * data.ndim
+        shape[axis], shape[-1] = data.shape[axis], half
+        angle = (pos[:, None] * freq[None, :]).reshape(shape)
+        cos, sin = jnp.cos(angle), jnp.sin(angle)
+        x = data.astype(jnp.float32)
+        x1, x2, rest = x[..., :half], x[..., half:rot], x[..., rot:]
+        out = jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], -1)
+        return out.astype(data.dtype)
+
+
 @register("_contrib_flash_attention")
 def flash_attention_op(q, k, v, causal=False, sm_scale=None):
     """Fused softmax(q k^T) v.  q/k/v: (N, L, D) or (B, H, L, D); with 4-d

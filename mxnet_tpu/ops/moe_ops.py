@@ -4,9 +4,11 @@ and the part of the result that the experts HELD HERE give.
 No reference counterpart.  A chip of an expert-parallel job holds
 ``experts_held = (first, count)`` of the ``n_routed`` experts of a layer.
 The router keeps its published width: every token chooses its ``top_k``
-among all experts and its weights are normalised over all its choices.  The
-chip computes only the terms of its own experts; what the absent experts
-would add is left out (on one chip the layer runs without its exchange).
+among all experts (by sigmoid scores of a linear router, or by the softmax
+of logits a router block has made).  The chip computes only the terms of its
+own experts; what the absent experts would add is left out (on one chip the
+layer runs without its exchange).  An expert is ``relu2(u W_up) W_down`` or
+the gated ``(silu(u W_gate) * (u W_up)) W_down``.
 
 No (token, choice) pair that lands on a held expert is ever dropped, at any
 skew: the pairs are sorted by expert (the absent experts' last) and worked
@@ -14,13 +16,14 @@ through in chunks of as many sorted rows as there are tokens, as many chunks
 as the landed pairs fill (a loop with a run-time trip count: one chunk unless
 the held experts draw more pairs than there are tokens, 2.7 times the even
 load at 8 of 128 experts and 6 choices; the worst case, ``tokens *
-min(top_k, count)`` rows, only sizes the index arrays).  A chunk gathers its
-tokens' rows, runs them through one grouped matrix product per projection
-(megablox ``gmm`` on a TPU, ``jax.lax.ragged_dot`` elsewhere) and adds its
-weighted results to its tokens' rows of the loop's f32 carry (one Mosaic
-call on a TPU, ``ops/pallas/moe_rows.py``; a scatter-add elsewhere).  The
-work follows the landed pairs a chunk at a time; inside a chunk every row
-runs, the rows past the landed pairs with no weight.
+min(top_k, count)`` rows, only sizes the index arrays; where that is one
+chunk, as with one choice a token, the chunk runs once with no loop).  A
+chunk gathers its tokens' rows, runs them through one grouped matrix product
+per projection (megablox ``gmm`` on a TPU, ``jax.lax.ragged_dot`` elsewhere)
+and adds its weighted results to its tokens' rows of the loop's f32 carry
+(one Mosaic call on a TPU, ``ops/pallas/moe_rows.py``; a scatter-add
+elsewhere).  The work follows the landed pairs a chunk at a time; inside a
+chunk every row runs, the rows past the landed pairs with no weight.
 """
 from __future__ import annotations
 
@@ -37,6 +40,18 @@ from .registry import register
 GMM_TILING = (512, 1024, 1024)
 
 
+def _choose(scores, bias, top_k, norm, scaling):
+    """The ``top_k`` of ``scores + bias`` (the bias takes no gradient) and
+    each choice's own score, over the choices' sum where ``norm``, times
+    ``scaling`` -> (experts (T, k) int32, weights (T, k) f32)."""
+    biased = scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    _top, experts = jax.lax.top_k(biased, int(top_k))
+    w = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), w * scaling
+
+
 @register("_contrib_moe_route")
 def moe_route(data, weight, correction_bias, top_k=1, scaling=1.0,
               norm_topk_prob=True):
@@ -50,14 +65,19 @@ def moe_route(data, weight, correction_bias, top_k=1, scaling=1.0,
     with jax.named_scope("mx_moe_route"):
         logits = jnp.einsum("td,ed->te", data, weight,
                             preferred_element_type=jnp.float32)
-        s = jax.nn.sigmoid(logits)
-        biased = s + jax.lax.stop_gradient(
-            correction_bias.astype(jnp.float32))
-        _top, experts = jax.lax.top_k(biased, int(top_k))
-        w = jnp.take_along_axis(s, experts, axis=-1)
-        if norm_topk_prob:
-            w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
-        return experts.astype(jnp.int32), w * scaling
+        return _choose(jax.nn.sigmoid(logits), correction_bias, top_k,
+                       norm_topk_prob, scaling)
+
+
+@register("_contrib_moe_route_softmax")
+def moe_route_softmax(logits, balance_bias, top_k=1):
+    """Routing from a router's own logits (T, E): ``p = softmax(logits)`` in
+    f32; the choices are the ``top_k`` of ``p + balance_bias``; a choice's
+    weight is its ``p``, not renormalised.  Returns (experts (T, k) int32,
+    weights (T, k) f32)."""
+    with jax.named_scope("mx_moe_route"):
+        p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        return _choose(p, balance_bias, top_k, False, 1.0)
 
 
 def _kernels(rows):
@@ -93,19 +113,24 @@ def _grouped_dot_weights_grad(acc, lhs, d_out, group_sizes):
     """``acc`` (groups, k, n) f32 plus every group's ``lhs_g^T d_out_g``:
     what ``_grouped_dot(lhs, rhs, group_sizes)``'s gradient gives ``rhs``,
     summed in f32 into what is there (megablox ``tgmm`` adds to
-    ``existing_out`` and aliases it)."""
+    ``existing_out`` and aliases it).  With ``acc`` None (the only chunk of
+    a loop that needs none) the sum alone, rounded once to ``lhs``'s type."""
     if _kernels(lhs.shape[0]):
         from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
 
+        if acc is None:
+            return tgmm(lhs.swapaxes(0, 1), d_out, group_sizes, lhs.dtype,
+                        GMM_TILING, interpret=_pk.interpret())
         # an f32 block that is read and written takes four times a bf16
         # result's VMEM: half the n tile
         tm, tk, tn = GMM_TILING
         return tgmm(lhs.swapaxes(0, 1), d_out, group_sizes, jnp.float32,
                     (tm, tk, tn // 2), existing_out=acc,
                     interpret=_pk.interpret())
-    return acc + jax.lax.ragged_dot_general(
+    grad = jax.lax.ragged_dot_general(
         lhs, d_out, group_sizes, _ROWS_CONTRACTED,
         preferred_element_type=jnp.float32)
+    return grad.astype(lhs.dtype) if acc is None else acc + grad
 
 
 def _add_rows(acc, rows, token, scale, here, n_live, fresh):
@@ -144,58 +169,99 @@ def _chunk_index(i, order, flat_w, starts, ends, k, chunk):
     return pairs, pairs // k, n_live, w, here
 
 
-def _chunk_products(data, up, down, token, here):
-    """A chunk's rows of ``data`` and what the held experts make of them."""
+ACTIVATIONS = {"relu2": 2, "swiglu": 3}     # matrices an expert has
+
+
+def _activate(pre, activation):
+    """An expert's hidden row from its row's products with the first
+    matrices: ``relu2``: ``max(h, 0)^2`` of one; ``swiglu``: ``silu(g) * u``
+    of two (gate, up), taken in f32 and rounded once."""
+    if activation == "relu2":
+        r = jnp.maximum(pre[0], 0)
+        return r * r
+    g, u = (t.astype(jnp.float32) for t in pre)
+    return (jax.nn.silu(g) * u).astype(pre[0].dtype)
+
+
+def _activate_bwd(pre, d_a, activation):
+    """The products' cotangents from the hidden row's."""
+    if activation == "relu2":
+        d_r = d_a * jnp.maximum(pre[0], 0)
+        return (jnp.where(pre[0] > 0, d_r + d_r, 0),)
+    g, u, d = (t.astype(jnp.float32) for t in (*pre, d_a))
+    sig = jax.nn.sigmoid(g)
+    d_g = d * u * sig * (1 + g * (1 - sig))
+    return d_g.astype(d_a.dtype), (d * g * sig).astype(d_a.dtype)
+
+
+def _chunk_products(data, mats, token, here, activation):
+    """A chunk's rows of ``data`` and what the held experts make of them:
+    the rows' products with every matrix but the last, the hidden rows, and
+    their product with the last."""
     rows = data[token]
     with jax.named_scope("mx_moe_experts"):
-        h = _grouped_dot(rows, up, here)
-        r = jnp.maximum(h, 0)
-        a = r * r
-        y = _grouped_dot(a, down, here)
-    return rows, h, r, a, y
+        pre = tuple(_grouped_dot(rows, m, here) for m in mats[:-1])
+        a = _activate(pre, activation)
+        y = _grouped_dot(a, mats[-1], here)
+    return rows, pre, a, y
 
 
-def _chunks_to_run(ends, chunk):
-    return (ends[-1] + chunk - 1) // chunk
+def _one_chunk(order, chunk):
+    """The worst case is one chunk (``top_k`` 1): no loop, no carry."""
+    return order.shape[0] == chunk
+
+
+def _over_chunks(order, ends, chunk, body, init):
+    """``body(i, carry)`` over the chunks that hold landed pairs: a loop
+    with a run-time trip count; where the worst case is one chunk, that
+    chunk once and no loop."""
+    if _one_chunk(order, chunk):
+        return body(0, init)
+    return jax.lax.fori_loop(0, (ends[-1] + chunk - 1) // chunk, body, init)
 
 
 # The loop over chunks runs as many times as landed pairs need, so it cannot
 # be differentiated through; forward and backward are written out, once, for
-# both back ends.  Every accumulator (the tokens' result; the gradients of
-# the tokens, the weights and both projections) is the loop's f32 carry and a
-# chunk adds to it in place.  The backward keeps nothing of the forward but
-# its inputs: a chunk's rows are gathered and multiplied again where its
-# gradient is taken.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _all_chunks(data, flat_w, up, down, order, starts, ends, k, chunk):
+# both back ends and both forms of expert (``mats`` is (up, down) or (gate,
+# up, down)).  Every accumulator (the tokens' result; the gradients of the
+# tokens, the weights and every matrix) is the loop's f32 carry and a chunk
+# adds to it in place.  The backward keeps nothing of the forward but its
+# inputs: a chunk's rows are gathered and multiplied again where its gradient
+# is taken.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _all_chunks(data, flat_w, mats, order, starts, ends, k, chunk,
+                activation):
     def body(i, out):
         _pairs, token, n_live, w, here = _chunk_index(
             i, order, flat_w, starts, ends, k, chunk)
-        y = _chunk_products(data, up, down, token, here)[-1]
+        y = _chunk_products(data, mats, token, here, activation)[-1]
         return _add_rows(out, y, token, w, here, n_live, fresh=i == 0)
 
-    out = jax.lax.fori_loop(0, _chunks_to_run(ends, chunk), body,
-                            jnp.zeros(data.shape, jnp.float32))
+    out = _over_chunks(order, ends, chunk, body,
+                       jnp.zeros(data.shape, jnp.float32))
     # rounded here, so that the gradient comes back in data's type and its
     # rows are gathered at that width (as exact: the chunks widen them)
     return out.astype(data.dtype)
 
 
-def _all_chunks_fwd(data, flat_w, up, down, order, starts, ends, k, chunk):
-    out = _all_chunks(data, flat_w, up, down, order, starts, ends, k, chunk)
-    return out, (data, flat_w, up, down, order, starts, ends)
+def _all_chunks_fwd(data, flat_w, mats, order, starts, ends, k, chunk,
+                    activation):
+    out = _all_chunks(data, flat_w, mats, order, starts, ends, k, chunk,
+                      activation)
+    return out, (data, flat_w, mats, order, starts, ends)
 
 
-def _all_chunks_bwd(k, chunk, res, d_out):
-    data, flat_w, up, down, order, starts, ends = res
+def _all_chunks_bwd(k, chunk, activation, res, d_out):
+    data, flat_w, mats, order, starts, ends = res
     f32 = jnp.float32
 
     def body(i, acc):
-        d_data, d_flat_w, d_up, d_down = acc
+        d_data, d_flat_w, d_mats = acc
         pairs, token, n_live, w, here = _chunk_index(
             i, order, flat_w, starts, ends, k, chunk)
         live = jnp.arange(chunk) < n_live
-        rows, h, r, a, y = _chunk_products(data, up, down, token, here)
+        rows, pre, a, y = _chunk_products(data, mats, token, here,
+                                          activation)
         # out[token] += w y: d_out's rows in f32, rounded once after the
         # multiplication by w; a weight gets its row's product with y
         taken = d_out[token].astype(f32)
@@ -203,36 +269,53 @@ def _all_chunks_bwd(k, chunk, res, d_out):
         d_w = jnp.sum(taken * y.astype(f32), -1)
         d_flat_w = d_flat_w.at[pairs].add(jnp.where(live, d_w, 0))
         with jax.named_scope("mx_moe_experts"):
-            d_a = _grouped_dot(d_y, down, here, transpose_rhs=True)
-            d_down = _grouped_dot_weights_grad(d_down, a, d_y, here)
-            d_r = d_a * r
-            d_h = jnp.where(h > 0, d_r + d_r, 0)
-            d_rows = _grouped_dot(d_h, up, here, transpose_rhs=True)
-            d_up = _grouped_dot_weights_grad(d_up, rows, d_h, here)
-        d_data = _add_rows(d_data, d_rows, token, live.astype(f32), here,
+            d_a = _grouped_dot(d_y, mats[-1], here, transpose_rhs=True)
+            d_last = _grouped_dot_weights_grad(d_mats[-1], a, d_y, here)
+            d_pre = _activate_bwd(pre, d_a, activation)
+            d_rows = [_grouped_dot(d, m, here, transpose_rhs=True)
+                      for d, m in zip(d_pre, mats)]
+            d_first = tuple(_grouped_dot_weights_grad(dm, rows, d, here)
+                            for dm, d in zip(d_mats, d_pre))
+        if len(d_rows) > 1:     # two products' rows, summed before rounding
+            d_rows = [sum(d.astype(f32) for d in d_rows).astype(rows.dtype)]
+        d_data = _add_rows(d_data, d_rows[0], token, live.astype(f32), here,
                            n_live, fresh=i == 0)
-        return d_data, d_flat_w, d_up, d_down
+        return d_data, d_flat_w, d_first + (d_last,)
 
-    acc = jax.lax.fori_loop(
-        0, _chunks_to_run(ends, chunk), body,
-        tuple(jnp.zeros(a.shape, f32) for a in (data, flat_w, up, down)))
-    grads = tuple(g.astype(a.dtype)
-                  for g, a in zip(acc, (data, flat_w, up, down)))
-    return grads + (None, None, None)
+    def zeros(t):
+        return jnp.zeros(t.shape, f32)
+
+    # one chunk adds to nothing: its weight gradients need no f32 carry
+    d_mats = tuple(None if _one_chunk(order, chunk) else zeros(m)
+                   for m in mats)
+    d_data, d_flat_w, d_mats = _over_chunks(
+        order, ends, chunk, body, (zeros(data), zeros(flat_w), d_mats))
+    return (d_data.astype(data.dtype), d_flat_w.astype(flat_w.dtype),
+            tuple(g.astype(m.dtype) for g, m in zip(d_mats, mats)),
+            None, None, None)
 
 
 _all_chunks.defvjp(_all_chunks_fwd, _all_chunks_bwd)
 
 
 @register("_contrib_moe_experts")
-def moe_experts(data, experts, weights, up_weight, down_weight, first=0):
-    """What the held experts add: ``sum_{e held} w_e relu2(u W_up,e)
-    W_down,e`` for every token whose choices name them.
+def moe_experts(data, experts, weights, up_weight, down_weight,
+                gate_weight=None, first=0, activation="relu2"):
+    """What the held experts add: ``sum_{e held} w_e f_e(u) W_down,e`` for
+    every token whose choices name them, with ``f_e(u) = relu2(u W_up,e)``
+    (``activation="relu2"``) or ``silu(u W_gate,e) * (u W_up,e)``
+    (``"swiglu"``, which takes ``gate_weight``).
 
-    data (T, d); experts, weights (T, k) from ``_contrib_moe_route``;
-    up_weight (count, d, f) and down_weight (count, f, d) are experts
+    data (T, d); experts, weights (T, k) from a routing op; up_weight,
+    gate_weight (count, d, f) and down_weight (count, f, d) are experts
     ``first .. first + count - 1``.  Returns (out (T, d), pairs landed on
     each held expert (count,) int32)."""
+    if activation not in ACTIVATIONS or (
+            (gate_weight is None) != (ACTIVATIONS[activation] == 2)):
+        raise ValueError(f"moe_experts: activation {activation!r} with "
+                         f"{2 + (gate_weight is not None)} matrices")
+    mats = (up_weight, down_weight) if gate_weight is None else (
+        gate_weight, up_weight, down_weight)
     tokens, k = experts.shape
     count = up_weight.shape[0]
     tm = GMM_TILING[0]
@@ -246,7 +329,6 @@ def moe_experts(data, experts, weights, up_weight, down_weight, first=0):
     order = jnp.pad(order, (0, max(0, n_chunks * chunk - order.shape[0])))
     sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), 0)
     ends = jnp.cumsum(sizes)
-    out = _all_chunks(data, weights.astype(jnp.float32).reshape(-1),
-                      up_weight, down_weight, order, ends - sizes, ends, k,
-                      chunk)
+    out = _all_chunks(data, weights.astype(jnp.float32).reshape(-1), mats,
+                      order, ends - sizes, ends, k, chunk, activation)
     return out, sizes

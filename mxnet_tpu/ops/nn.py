@@ -303,6 +303,8 @@ def leaky_relu(data, *gamma, act_type="leaky", slope=0.25, lower_bound=0.125,
         return scale * jnp.where(data >= 0, data, alpha * jnp.expm1(data))
     if act_type == "gelu":
         return jax.nn.gelu(data, approximate=False)
+    if act_type == "gelu_tanh":
+        return jax.nn.gelu(data, approximate=True)
     if act_type == "prelu":
         g = gamma[0]
         shape = [1] * data.ndim

@@ -210,7 +210,7 @@ declare("Convolution",
 declare("LeakyReLU",
         ParamField("act_type", "str", "leaky",
                    enum=("leaky", "prelu", "rrelu", "elu", "selu",
-                         "gelu")))
+                         "gelu", "gelu_tanh")))
 declare("softmax", ParamField("axis", "int", -1))
 declare("RNN",
         ParamField("mode", "str", "lstm",

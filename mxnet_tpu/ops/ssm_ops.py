@@ -71,6 +71,28 @@ def causal_conv1d(data, weight, bias=None, activation=None):
         return out.astype(data.dtype)
 
 
+@register("_contrib_causal_conv1d_heads")
+def causal_conv1d_heads(data, weight, bias=None):
+    """Causal convolution along axis 1 of ``data`` (B, L, H * D) that mixes
+    the D channels inside each of its H heads: ``weight`` (H, K, D, D) holds
+    a (D in, D out) matrix a head a tap, ``y_t[h] = sum_k x_{t-K+1+k}[h]
+    weight[h, k] (+ bias)``, zeros before position 0.  The taps are
+    contracted as one product K * D deep, summed in f32."""
+    with jax.named_scope("mx_conv1d_heads"):
+        heads, k, d, _ = weight.shape
+        batch, length, _ = data.shape
+        x = jnp.pad(data.reshape(batch, length, heads, d),
+                    ((0, 0), (k - 1, 0), (0, 0), (0, 0)))
+        taps = jnp.concatenate([x[:, i:i + length] for i in range(k)], -1)
+        out = jnp.einsum("blhc,hce->blhe", taps,
+                         weight.reshape(heads, k * d, d),
+                         preferred_element_type=jnp.float32)
+        out = out.reshape(batch, length, heads * d)
+        if bias is not None:
+            out = out + bias.astype(jnp.float32)
+        return out.astype(data.dtype)
+
+
 def _chunk_states(decay, s_local):
     """State at the START of every chunk: ``h_0 = 0``,
     ``h_{c+1} = decay_c h_c + s_local_c``; (b, c, ...) with c the scanned

@@ -18,11 +18,15 @@ This module supplies the missing pieces:
     buffers in place and have no scalar to hand back (``gluon.Trainer``,
     ``module.Module``): waiting on the fence syncs that step's updates.
   * :class:`InflightRing` — the bounded window.  ``MX_ASYNC_INFLIGHT``
-    (default 2) caps how many dispatched-but-unforced steps may be
-    pending; admitting a new step past the cap blocks on the *oldest*
-    pending handle first, so the dispatch queue can never run away from
-    the device.  ``MX_ASYNC_INFLIGHT=0`` restores fully synchronous
-    behavior (every step forced at dispatch).
+    caps how many dispatched-but-unforced steps may be pending; admitting
+    a new step past the cap blocks on the *oldest* pending handle first,
+    so the dispatch queue can never run away from the device.  Unset,
+    the window is 2, and the compiled step's (its handle pins one scalar,
+    where a fence pins a generation of buffers) grows to as many as 8
+    while a step goes from dispatch to its end in under 1.25 s: about a
+    second of work is then queued on the device, and a host that is held
+    for less costs the device nothing.  ``MX_ASYNC_INFLIGHT=0`` restores
+    fully synchronous behavior (every step forced at dispatch).
   * :func:`drain_all` — force every pending handle in the process; the
     SIGTERM preemption path (``fault.install_preemption_handler``) calls
     it so a final sync checkpoint never snapshots ahead of an in-flight
@@ -54,6 +58,14 @@ __all__ = ["AsyncLoss", "AsyncResult", "StepFence", "InflightRing",
            "inflight_limit", "drain_all"]
 
 _DEFAULT_INFLIGHT = 2
+# DataParallelStep's window where MX_ASYNC_INFLIGHT is unset, as (most
+# handles, seconds from a step's dispatch to its end).  An AsyncLoss pins
+# one scalar, so depth costs no memory; what it costs is how far the host
+# is ahead (a drain, a deferred error), hence the bound in seconds.  On a
+# host that shares its cores the process is held for 0.1 to 1.2 s now and
+# then (PERF.md, PR 31): with two steps in flight every hold past one
+# step's time idles the device
+_COMPILED_STEP_DEEP = (8, 1.25)
 
 # every ring in the process, so preemption/checkpoint paths can drain
 # pending work they never saw dispatched (weak: a dropped step object
@@ -73,6 +85,14 @@ def inflight_limit() -> int:
         return _DEFAULT_INFLIGHT
 
 
+def compiled_step_window():
+    """``(limit, deep)`` for :meth:`InflightRing.make_room` as
+    ``DataParallelStep.step`` calls it: the variable's count alone where
+    it is set, else the default with ``_COMPILED_STEP_DEEP``."""
+    deep = None if "MX_ASYNC_INFLIGHT" in os.environ else _COMPILED_STEP_DEEP
+    return inflight_limit(), deep
+
+
 class _PendingHandle:
     """One dispatched-but-unforced step.  Subclasses define `_force()`."""
 
@@ -81,6 +101,8 @@ class _PendingHandle:
         self._step = int(step)
         self._executor = executor
         self._ring = ring
+        self._dispatched = time.perf_counter()
+        self._admitted = 0  # the ring's depth with this handle in it
         self._forced = False
         self._host = None
         self._exc: Optional[BaseException] = None
@@ -242,6 +264,7 @@ class InflightRing:
     def __init__(self, executor: str):
         self._executor = executor
         self._pending: deque = deque()
+        self._deep = 0  # the window make_room(deep=...) has grown to
         self._lock = threading.Lock()
         with _rings_lock:
             _live_rings.add(self)
@@ -265,12 +288,22 @@ class InflightRing:
                 return None
             return self._pending[0]
 
-    def make_room(self, limit: int, wait_span: bool = True) -> float:
+    def make_room(self, limit: int, wait_span: bool = True,
+                  deep=None) -> float:
         """Ensure the window has a free slot; returns seconds spent
         blocked (0.0 when the ring wasn't full).  ``wait_span=False``
         suppresses the inner waits' ``loss_wait`` spans for a caller that
         records the returned duration as its own ``block_wait`` span —
-        the same blocked wall must not land in the trace twice."""
+        the same blocked wall must not land in the trace twice.
+        ``deep=(most, seconds)`` lets the window find its own size between
+        ``limit`` and ``most``: a handle that was waited for ended as the
+        wait did, so dispatch to end is the work queued with it, and the
+        next call's window is one larger where that was under ``seconds``
+        (one smaller past half as much again).  Only a handle that filled
+        the window as it is now is read: another tells of a window of
+        another size, and the size would swing."""
+        if deep is not None:
+            limit = self._deep = min(max(self._deep, limit), deep[0])
         waited = 0.0
         while True:
             oldest = self._oldest_over(limit)
@@ -278,12 +311,21 @@ class InflightRing:
                 return waited
             t0 = time.perf_counter()
             oldest.wait(_span=wait_span)  # discards itself from the ring
-            waited += time.perf_counter() - t0
+            now = time.perf_counter()
+            waited += now - t0
+            if (deep is not None and now - t0 > 1e-3
+                    and oldest._admitted == limit):
+                queued_s = now - oldest._dispatched
+                if queued_s < deep[1]:
+                    self._deep = limit + 1
+                elif queued_s > 1.5 * deep[1]:
+                    self._deep = limit - 1
 
     def admit(self, handle) -> int:
         with self._lock:
             self._pending.append(handle)
-            return len(self._pending)
+            handle._admitted = len(self._pending)
+            return handle._admitted
 
     @property
     def depth(self) -> int:

@@ -25,7 +25,7 @@ from .. import fault
 from .. import memwatch
 from .. import telemetry
 from ..base import MXNetError
-from .async_loss import AsyncLoss, InflightRing, inflight_limit
+from .async_loss import AsyncLoss, InflightRing, compiled_step_window
 from .plan import Plan, dp_plan
 from .sharding import ShardingRules, replicated, shard_batch
 
@@ -810,7 +810,8 @@ class DataParallelStep:
         Dispatch is non-blocking (jax queues the execution): the handle's
         ``float()`` / ``.asnumpy()`` / ``.wait()`` force the host readback,
         so compute for step N overlaps host prep for step N+1.  At most
-        ``MX_ASYNC_INFLIGHT`` (default 2) steps may be pending — admitting
+        ``MX_ASYNC_INFLIGHT`` steps may be pending (unset: 2, growing to at
+        most 8 while a step ends under 1.25 s after its dispatch) — admitting
         one more blocks on the oldest first; ``MX_ASYNC_INFLIGHT=0``
         forces every step at dispatch (the old synchronous behavior, same
         numbers: asynchrony never changes what is computed).
@@ -879,15 +880,15 @@ class DataParallelStep:
         # bounded window: block on the OLDEST pending step only when the
         # ring is full, BEFORE paying this batch's placement — the
         # remaining in-flight steps keep the device busy meanwhile
-        limit = inflight_limit()
+        limit, deep = compiled_step_window()
         block_wait_s = 0.0
         if limit > 0:
             # a live span, open while the host waits.  wait_span=False:
             # this IS the step's wait; the inner wait emitting loss_wait
             # over the same wall would double-count the phase breakdown
             with telemetry.span("block_wait"):
-                block_wait_s = self._inflight.make_room(limit,
-                                                        wait_span=False)
+                block_wait_s = self._inflight.make_room(
+                    limit, wait_span=False, deep=deep)
         with telemetry.span("input_stage"):
             data_arrs = tuple(d._data for d in datas)
             label_arr = label._data if isinstance(label, NDArray) else label

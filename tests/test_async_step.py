@@ -118,6 +118,59 @@ def test_window_never_exceeds_limit(tele, monkeypatch):
     assert row["block_wait_ms"] >= 0.0
 
 
+def _slow_handle(i, ring):
+    """A step whose end the host has to wait for."""
+    import time
+
+    return AsyncLoss(np.float32(i), step=i, executor="t", ring=ring,
+                     host_fn=lambda v: (time.sleep(0.004), v)[1])
+
+
+@pytest.mark.parametrize("seconds,most", [(60.0, 5), (0.0, 2)])
+def test_deep_window_grows_by_what_a_waited_step_took(seconds, most):
+    """The ring by itself: a handle that was waited for and went from
+    dispatch to its end in under ``seconds`` buys the next call one more
+    slot, up to ``most``; one that took half as much again gives it back;
+    a call never waits for more than the handles over its window."""
+    ring = al.InflightRing("t")
+    seen = []
+    for i in range(30):
+        before = ring.depth
+        ring.make_room(2, deep=(5, seconds))
+        assert before - ring.depth <= 1
+        seen.append(ring.admit(_slow_handle(i, ring)))
+    assert max(seen) == most and seen[-1] == most, seen
+    ring.make_room(2)                   # no deepening: the limit alone
+    assert ring.depth == 1
+    ring.drain()
+
+
+@pytest.mark.parametrize("env", [None, "3", "0", "junk"])
+def test_unset_window_is_the_compiled_steps_to_deepen(monkeypatch, env):
+    """Unset, the compiled step (a handle pins one scalar) may deepen its
+    window to eight; the variable, where set, is the count, for it and
+    for the Trainer/Module fences (a fence pins a generation of buffers),
+    which never deepen."""
+    if env is None:
+        monkeypatch.delenv("MX_ASYNC_INFLIGHT", raising=False)
+    else:
+        monkeypatch.setenv("MX_ASYNC_INFLIGHT", env)
+    limit, deep = al.compiled_step_window()
+    assert limit == al.inflight_limit() == (
+        2 if env in (None, "junk") else int(env))
+    assert deep == (None if env is not None else (8, 1.25))
+    step = _build()
+    seen = []
+    for x, y in _batches(11):
+        step.step(x, y)
+        seen.append(step.inflight_depth)
+    assert max(seen) <= (8 if env is None else limit), seen
+    if env is not None and limit:
+        assert max(seen) == limit
+    step.drain()
+    assert step.inflight_depth == 0
+
+
 def test_drain_on_epoch_end_via_device_prefetcher(monkeypatch):
     monkeypatch.setenv("MX_ASYNC_INFLIGHT", "4")
     step = _build()
