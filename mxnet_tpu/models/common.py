@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from ..gluon.block import HybridBlock
 from ..gluon.parameter import record_aux_update
 from ..ndarray import NDArray
+from ..ops import recompute as _recompute
 from ..ops import registry as _reg
 
 __all__ = ["checkpointed", "vocab_logits", "HeldExperts"]
@@ -17,8 +18,10 @@ __all__ = ["checkpointed", "vocab_logits", "HeldExperts"]
 def checkpointed(block, *xs):
     """``block(*xs)`` with its activations recomputed in the backward pass
     (``jax.checkpoint`` around the call) while a jitted step is being
-    traced; a plain call otherwise.  A layer's boundary is whatever tensors
-    it takes and returns: one, or several as a list."""
+    traced; a plain call otherwise.  What is dear to make again stays from
+    the forward pass, by the one rule of ``ops/recompute.py``: a kernel's
+    named results and the products with a weight.  A layer's boundary is
+    whatever tensors it takes and returns: one, or several as a list."""
     if not isinstance(xs[0]._data, jax.core.Tracer):
         return block(*xs)
     ctx = xs[0].context
@@ -29,7 +32,9 @@ def checkpointed(block, *xs):
             return tuple(o._data for o in out)
         return out._data
 
-    out = jax.checkpoint(pure)(*(x._data for x in xs))
+    with _recompute.layer():
+        out = jax.checkpoint(pure, policy=_recompute.policy)(
+            *(x._data for x in xs))
     if isinstance(out, tuple):
         return [NDArray(o, ctx=ctx) for o in out]
     return NDArray(out, ctx=ctx)

@@ -27,9 +27,10 @@ spread over all experts, and their running maximum are aux state
 statistics: no step syncs to read them, ``DataParallelStep.drain`` hands
 them to ``telemetry.record_moe_load``.
 
-Each layer is recomputed in the backward pass (``jax.checkpoint`` around the
-layer while a step is being traced), so a step keeps one activation per
-layer, not one per operator.
+Each layer is recomputed in the backward pass (``common.checkpointed``), so a
+step keeps a layer's input and what ``ops/recompute.py`` names dear to make
+again (the wide products, flash attention's results), not one activation per
+operator; the scan is made again (docs/NEMOTRON_H.md, Memory).
 """
 from __future__ import annotations
 

@@ -288,7 +288,11 @@ def _flash(cfg: _Cfg, q, k, v):
 
 
 def _flash_fwd(cfg: _Cfg, q, k, v):
-    out, lse = _fwd(cfg, q, k, v)
+    from ..recompute import kernel_out
+
+    # both, or a recomputed layer runs the kernel again: the backward reads
+    # lse, the layer's later ops read out
+    out, lse = kernel_out(*_fwd(cfg, q, k, v))
     return out, (q, k, v, out, lse)
 
 

@@ -525,6 +525,11 @@ def _scan(cfg: _Cfg, x, dt, a_log, b, c, d, dt_bias):
 
 
 def _scan_fwd(cfg: _Cfg, x, dt, a_log, b, c, d, dt_bias):
+    # NOT named for ``ops/recompute.py``: a recomputed layer runs this a
+    # second time.  At 2 x 8,192 positions that is 1.70 ms, and keeping y
+    # (134 MB) and the f32 chunk states (268 MB) instead costs 0.40 GB a
+    # layer: 4 ms saved a GB kept, where flash attention's results give 73
+    # and a product with a weight 16 (PERF.md section 6, PR 32).
     y, starts = _fwd(cfg, x, dt, a_log, b, c, d, dt_bias)
     return y, (x, dt, a_log, b, c, d, dt_bias, starts)
 

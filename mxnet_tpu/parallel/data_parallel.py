@@ -555,6 +555,8 @@ class DataParallelStep:
 
         from jax.sharding import NamedSharding, PartitionSpec
 
+        from ..ops import recompute as _recompute
+
         apply_fn = self._apply
         if self._remat:
             # jax.checkpoint only accepts JAX-typed outputs: strip the
@@ -621,8 +623,12 @@ class DataParallelStep:
                     return loss * scale, (loss, aux)
 
             def run_vg(p, k, d, l):
-                out, grads = jax.value_and_grad(
-                    vg_target, has_aux=True)(p, k, d, l)
+                with _recompute.tally() as kept:
+                    out, grads = jax.value_and_grad(
+                        vg_target, has_aux=True)(p, k, d, l)
+                if kept.layers:     # trace time: once a traced gradient
+                    telemetry.record_recompute_kept(
+                        kept.layers, kept.tensors, kept.bytes)
                 loss, aux = out if scale is None else out[1]
                 return loss, aux, grads
 

@@ -222,6 +222,9 @@ class _State:
         # expert layers' load counters, newest reading per aux leaf
         # (record_moe_load; kept whether or not the recorder is enabled)
         self.moe_load: Dict[str, List[float]] = {}
+        # what the newest traced gradient's recomputed layers keep from
+        # their forward pass (record_recompute_kept; kept like moe_load)
+        self.recompute_kept: Dict[str, int] = {}
         # span name -> {count, total_ms, max_ms}
         self.spans: Dict[str, Dict[str, float]] = {}
         # finished spans, oldest first out (spans_between)
@@ -721,6 +724,20 @@ def moe_load() -> Dict[str, List[float]]:
         return {k: list(v) for k, v in _state.moe_load.items()}
 
 
+def record_recompute_kept(layers: int, tensors: int, nbytes: int) -> None:
+    """What the recomputed layers of the gradient traced last keep from
+    their forward pass: how many layers kept something, and the tensors and
+    bytes that ``ops/recompute.py`` ``policy`` marked in them (an upper
+    bound: jax drops a marked value that no backward operation reads).
+    ``DataParallelStep`` calls this once a traced gradient, never inside a
+    step.  Kept in memory (``summary()["recompute_kept"]``) whether or not
+    the recorder is enabled."""
+    kept = {"layers": layers, "tensors": tensors, "bytes": nbytes}
+    with _state.lock:
+        _state.recompute_kept = kept
+    record("recompute_kept", **kept)
+
+
 def record_fused_update(n_params: int, n_buckets: int, nbytes: int,
                         n_jitted_calls: int, **fields) -> None:
     """One fused optimizer step (docs/PERFORMANCE.md): how many params
@@ -1181,6 +1198,8 @@ def summary() -> dict:
             "checkpoints": {k: (round(v, 3) if isinstance(v, float) else v)
                             for k, v in _state.ckpt.items()},
             "fused_update": dict(_state.fused),
+            "moe_load": moe_load(),
+            "recompute_kept": dict(_state.recompute_kept),
             "serving": _serving_rollup(),
             "spans": {
                 name: {"count": agg["count"],

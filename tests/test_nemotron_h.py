@@ -296,14 +296,27 @@ def _train(steps=2):
     return losses, step, telemetry.moe_load()
 
 
-def test_the_stack_trains_and_recomputation_changes_no_number(monkeypatch):
+@pytest.mark.parametrize("other", ["nothing recomputed",
+                                   "everything recomputed"])
+def test_the_stack_trains_and_recomputation_changes_no_number(monkeypatch,
+                                                              other):
+    """The rule of ``ops/recompute.py`` against a plain call, and to the
+    bit against a bare ``jax.checkpoint`` (what it kept was made once)."""
     from mxnet_tpu.models import nemotron_h as model
+    from mxnet_tpu.ops import recompute
 
     with_remat, step, _ = _train()
-    monkeypatch.setattr(model, "checkpointed", lambda block, *xs: block(*xs))
+    if other == "nothing recomputed":
+        monkeypatch.setattr(model, "checkpointed",
+                            lambda block, *xs: block(*xs))
+    else:
+        monkeypatch.setattr(recompute, "policy", None)
     without, _, _ = _train()
     assert with_remat[1] < with_remat[0]
-    np.testing.assert_allclose(with_remat, without, rtol=1e-6)
+    if other == "nothing recomputed":
+        np.testing.assert_allclose(with_remat, without, rtol=1e-6)
+    else:
+        assert with_remat == without
     frozen = [n for n in step.params if n.endswith("e_score_correction_bias")]
     assert frozen and all(n in step.opt_state[0] for n in frozen)
 

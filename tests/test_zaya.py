@@ -419,8 +419,14 @@ def test_the_stack_matches_the_reference_loss_every_leafs_gradient_three_steps(
                                    err_msg=name)
 
 
-def test_recomputation_over_the_pair_changes_no_gradient(ref, monkeypatch):
+@pytest.mark.parametrize("other", ["nothing recomputed",
+                                   "everything recomputed"])
+def test_recomputation_over_the_pair_changes_no_gradient(ref, monkeypatch,
+                                                         other):
+    """The rule of ``ops/recompute.py`` against a plain call, and to the
+    bit against a bare ``jax.checkpoint`` (what it kept was made once)."""
     from mxnet_tpu.models import zaya as model
+    from mxnet_tpu.ops import recompute
 
     def first_moment():
         net, step, _w, _tokens, x, y = _step(ref)
@@ -428,13 +434,19 @@ def test_recomputation_over_the_pair_changes_no_gradient(ref, monkeypatch):
         return loss, _strip(net, step.opt_state[0])
 
     loss0, g0 = first_moment()
-    monkeypatch.setattr(model, "checkpointed",
-                        lambda block, *xs: block(*xs))
+    if other == "nothing recomputed":
+        monkeypatch.setattr(model, "checkpointed",
+                            lambda block, *xs: block(*xs))
+    else:
+        monkeypatch.setattr(recompute, "policy", None)
     loss1, g1 = first_moment()
     assert loss0 == loss1
     for name in g0:
-        np.testing.assert_allclose(g0[name], g1[name], rtol=1e-5, atol=1e-9,
-                                   err_msg=name)
+        if other == "nothing recomputed":
+            np.testing.assert_allclose(g0[name], g1[name], rtol=1e-5,
+                                       atol=1e-9, err_msg=name)
+        else:
+            np.testing.assert_array_equal(g0[name], g1[name], err_msg=name)
     # the router's representation reaches the next layer: layer 1's depth
     # gain has a gradient only through layer 0's r
     assert float(jnp.abs(g0["layer1_moe_router_depth_gain"]).max()) > 0
