@@ -23,3 +23,4 @@ from . import quantization  # noqa: F401
 from . import warp_ops  # noqa: F401
 from . import ssm_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
+from . import hc_ops  # noqa: F401

@@ -26,13 +26,33 @@ def _dense_attention(q, k, v, causal, sm_scale):
     return jnp.einsum("nqk,nkd->nqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _yarn_blend(freq, rot, theta, factor, beta_fast, beta_slow, original_max):
+    """YaRN's frequencies from the plain ones (``rot / 2`` of them): pair
+    ``i`` keeps ``f_i`` below ``lo``, takes ``f_i / factor`` above ``hi`` and
+    a linear blend between, ``lo`` and ``hi`` the pairs that turn
+    ``beta_fast`` and ``beta_slow`` times over ``original_max`` positions."""
+    def pair(beta):
+        return rot * math.log(original_max / (beta * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    lo = max(math.floor(pair(beta_fast)), 0)
+    hi = min(math.ceil(pair(beta_slow)), rot - 1)
+    ramp = jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - lo)
+                    / max(hi - lo, 1e-3), 0, 1)
+    return freq / factor * ramp + freq * (1 - ramp)
+
+
 @register("_contrib_rotary")
-def rotary(data, theta=10000.0, fraction=1.0, axis=1):
+def rotary(data, theta=10000.0, fraction=1.0, axis=1, yarn_factor=None,
+           yarn_beta_fast=32.0, yarn_beta_slow=1.0, yarn_original_max=4096):
     """Rotary position on the first ``fraction`` of the last axis of
     ``data``; positions 0, 1, ... run along ``axis``.  With ``R = fraction *
     D`` rotated channels (even), channel ``i < R / 2`` pairs with ``i + R /
     2`` (rotate-half) and the pair turns by ``position * theta^(-2 i / R)``;
-    channels from R on pass unchanged.  Angles and the rotation in f32."""
+    channels from R on pass unchanged.  Angles and the rotation in f32.
+    With ``yarn_factor`` the frequencies are YaRN's (``_yarn_blend``: a
+    context stretched ``yarn_factor`` times past ``yarn_original_max``);
+    cos and sin are not rescaled."""
     with jax.named_scope("mx_rotary"):
         d = data.shape[-1]
         rot = int(round(d * float(fraction)))
@@ -41,6 +61,10 @@ def rotary(data, theta=10000.0, fraction=1.0, axis=1):
         axis = axis % data.ndim
         half = rot // 2
         freq = float(theta) ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        if yarn_factor is not None:
+            freq = _yarn_blend(freq, rot, float(theta), float(yarn_factor),
+                               yarn_beta_fast, yarn_beta_slow,
+                               yarn_original_max)
         pos = jnp.arange(data.shape[axis], dtype=jnp.float32)
         shape = [1] * data.ndim
         shape[axis], shape[-1] = data.shape[axis], half

@@ -141,7 +141,7 @@ def _add_rows(acc, rows, token, scale, here, n_live, fresh):
     does not read a fresh one (``ops/pallas/moe_rows.py``); elsewhere a
     scatter-add into ``acc``."""
     if _kernels(rows.shape[0]) and moe_rows.fits(
-            acc.shape[0], *rows.shape, here.shape[0]):
+            acc.shape[0], *rows.shape, here.shape[0], rows.dtype.itemsize):
         return moe_rows.combine(acc, rows, token, scale, here, n_live, fresh,
                                 interpret=_pk.interpret())
     live = jnp.arange(rows.shape[0]) < n_live

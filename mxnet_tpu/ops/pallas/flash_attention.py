@@ -9,8 +9,11 @@ Reference parity: supersedes src/operator/contrib/transformer.cc
 (interleaved_matmul_selfatt_qk/valatt ~L1-300), which fused only the
 attention matmuls and still materialised scores for a separate softmax op.
 
-Shapes: q (N, Lq, D), k/v (N, Lk, D) with N = batch*heads; 4D
-(B, H, L, D) inputs are reshaped.  The MXU takes its operands in the input
+Shapes: q (N, Lq, D), k (N, Lk, D), v (N, Lk, Dv) with N = batch*heads; 4D
+(B, H, L, D) inputs are reshaped.  The values may be narrower or wider than
+the channels the scores are taken over (latent attention scores over a
+head's plain and rotary channels and sums values of the plain width): the
+output, the forward's accumulator and dV take Dv, everything else D.  The MXU takes its operands in the input
 dtype and accumulates in f32; softmax statistics are f32.
 
 Grouped-query heads: k/v may carry fewer heads than q, (B, Hkv, Lk, D) with
@@ -91,7 +94,7 @@ def _fwd_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, o_ref, lse_ref):
 
     m0 = jnp.full((bq, 1), _NEG, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    a0 = jnp.zeros((bq, q.shape[-1]), jnp.float32)
+    a0 = jnp.zeros((bq, v_ref.shape[-1]), jnp.float32)
 
     def body(masked, kj, carry):
         m, l, acc = carry
@@ -122,7 +125,7 @@ def _fwd_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, o_ref, lse_ref):
 
 def _fwd(cfg: _Cfg, q, k, v):
     n, lq, d = q.shape
-    lk = k.shape[1]
+    lk, dv = k.shape[1], v.shape[-1]
     nqb = lq // cfg.block_q
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, cfg),
@@ -131,14 +134,14 @@ def _fwd(cfg: _Cfg, q, k, v):
         in_specs=[
             pl.BlockSpec((1, cfg.block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, lk, d), lambda b, i: (b // cfg.group, 0, 0)),
-            pl.BlockSpec((1, lk, d), lambda b, i: (b // cfg.group, 0, 0)),
+            pl.BlockSpec((1, lk, dv), lambda b, i: (b // cfg.group, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, cfg.block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, cfg.block_q, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, cfg.block_q, _LANES), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, lq, d), q.dtype),
+            jax.ShapeDtypeStruct((n, lq, dv), q.dtype),
             jax.ShapeDtypeStruct((n, lq, _LANES), jnp.float32),
         ],
         interpret=cfg.interpret,
@@ -197,6 +200,7 @@ def _dkv_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     whole = ((kj + 1) * bk + bq - 2) // bq if cfg.causal else 0
     whole = jnp.where((kj + 1) * bk <= cfg.kv_len, whole, nqb)
     zero = jnp.zeros(k.shape, jnp.float32)
+    zero_v = zero if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)
 
     def body(masked, qi, carry):
         dk, dv = carry
@@ -226,7 +230,7 @@ def _dkv_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     whole = jnp.clip(whole, lo, nqb)
     carry = jax.lax.fori_loop(lo, whole, functools.partial(body, True),
-                              (zero, zero))
+                              (zero, zero_v))
     dk, dv = jax.lax.fori_loop(whole, nqb, functools.partial(body, False),
                                carry)
 
@@ -243,38 +247,48 @@ def _dkv_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_impl(cfg: _Cfg, q, k, v, out, lse, do):
     n, lq, d = q.shape
-    nkv, lk = k.shape[0], k.shape[1]
+    nkv, lk, dv = k.shape[0], k.shape[1], v.shape[-1]
     grp = cfg.group
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                                   # (n, lq)
     lse3 = jnp.broadcast_to(lse[..., None], (n, lq, _LANES))
     delta3 = jnp.broadcast_to(delta[..., None], (n, lq, _LANES))
-    q_blk = pl.BlockSpec((1, cfg.block_q, d), lambda b, i: (b, i, 0))
-    kv_all = pl.BlockSpec((1, lk, d), lambda b, i: (b // grp, 0, 0))
+    def q_blk(w):
+        return pl.BlockSpec((1, cfg.block_q, w), lambda b, i: (b, i, 0))
+
+    def kv_all(w):
+        return pl.BlockSpec((1, lk, w), lambda b, i: (b // grp, 0, 0))
+
     stat_blk = pl.BlockSpec((1, cfg.block_q, _LANES), lambda b, i: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, cfg),
         name="mx_flash_dq",
         grid=(n, lq // cfg.block_q),
-        in_specs=[q_blk, kv_all, kv_all, q_blk, stat_blk, stat_blk],
-        out_specs=q_blk,
+        in_specs=[q_blk(d), kv_all(d), kv_all(dv), q_blk(dv), stat_blk,
+                  stat_blk],
+        out_specs=q_blk(d),
         out_shape=jax.ShapeDtypeStruct((n, lq, d), q.dtype),
         interpret=cfg.interpret,
     )(q, k, v, do, lse3, delta3)
 
     # grid (key-value head, key block, query head of the group)
-    q_all = pl.BlockSpec((1, lq, d), lambda b, j, g: (b * grp + g, 0, 0))
-    kv_blk = pl.BlockSpec((1, cfg.block_k, d), lambda b, j, g: (b, j, 0))
+    def q_all(w):
+        return pl.BlockSpec((1, lq, w), lambda b, j, g: (b * grp + g, 0, 0))
+
+    def kv_blk(w):
+        return pl.BlockSpec((1, cfg.block_k, w), lambda b, j, g: (b, j, 0))
+
     stat_row = pl.BlockSpec((1, 1, lq), lambda b, j, g: (b * grp + g, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, cfg),
         name="mx_flash_dkv",
         grid=(nkv, lk // cfg.block_k, grp),
-        in_specs=[q_all, kv_blk, kv_blk, q_all, stat_row, stat_row],
-        out_specs=[kv_blk, kv_blk],
+        in_specs=[q_all(d), kv_blk(d), kv_blk(dv), q_all(dv), stat_row,
+                  stat_row],
+        out_specs=[kv_blk(d), kv_blk(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((nkv, lk, d), jnp.float32),
-            jax.ShapeDtypeStruct((nkv, lk, d), jnp.float32),
+            jax.ShapeDtypeStruct((nkv, lk, dv), jnp.float32),
         ],
         interpret=cfg.interpret,
     )(q, k, v, do, lse[:, None, :], delta[:, None, :])
@@ -310,7 +324,8 @@ def flash_attention(q, k, v, causal: bool = False,
                     return_lse: bool = False):
     """Fused attention: softmax(q @ k^T * sm_scale [+ causal mask]) @ v.
 
-    q: (N, Lq, D) or (B, H, Lq, D); k, v likewise with Lk, or with fewer
+    q: (N, Lq, D) or (B, H, Lq, D); k, v likewise with Lk (v of any width
+    Dv: the output is Dv wide; `sm_scale` defaults to D^-0.5), or with fewer
     heads, (B, Hkv, Lk, D), H a multiple of Hkv (grouped-query attention: the
     module docstring).  Differentiable in q/k/v (FA2 backward).  Blocks of 512 x 512
     (shorter lengths take one block): at 8,192 positions and 128-wide heads
@@ -351,7 +366,7 @@ def flash_attention(q, k, v, causal: bool = False,
         out = _flash(cfg, qp, kp, vp)[:, :lq]
         lse = None
     if q4:
-        out = out.reshape(b, h, lq, d)
+        out = out.reshape(b, h, lq, v.shape[-1])
         if lse is not None:
             lse = lse.reshape(b, h, lq)
     return (out, lse) if return_lse else out

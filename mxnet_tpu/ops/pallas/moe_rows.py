@@ -24,21 +24,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
-#: tokens of a tile, rows of a slab (whole row tiles of bf16), and the most
-#: experts a call unrolls over; VMEM: about 14 MB at 2,688 columns
+#: tokens of a tile at most, rows of a slab (whole row tiles of bf16), and
+#: the most experts a call unrolls over; VMEM: about 14 MB at 2,688 columns
 TOKENS, SLAB, MAX_GROUPS = 256, 32, 16
+#: what a call's blocks and scratch may take of the 16 MB it gets unasked
+VMEM_BUDGET = 15 * 2 ** 20
 
 
-def _token_tile(tokens):
-    return next((t for t in (TOKENS, 128, 64, 32, 16, 8) if tokens % t == 0),
-                None)
+def _vmem(tile, width, groups, itemsize):
+    """Bytes a call holds: the tokens' tile in and out (f32, two buffers
+    each), two sets of every expert's slab, one slab widened."""
+    return (4 * tile * 4 + 2 * groups * SLAB * itemsize + SLAB * 4) * width
 
 
-def fits(tokens, rows, width, groups):
+def _token_tile(tokens, width, groups, itemsize=2):
+    """The most tokens a tile holds: a whole number of tiles, inside the
+    budget (256 up to 2,688 columns of 8 experts' bf16 rows, 128 at 3,584)."""
+    return next((t for t in (TOKENS, 128, 64, 32, 16, 8) if tokens % t == 0
+                 and _vmem(t, width, groups, itemsize) <= VMEM_BUDGET), None)
+
+
+def fits(tokens, rows, width, groups, itemsize=2):
     """True for shapes the kernel takes: whole lane tiles across, whole
     sublane tiles of tokens, whole slabs of rows, few enough experts."""
-    return (width % LANES == 0 and _token_tile(tokens) is not None
-            and rows % SLAB == 0 and groups <= MAX_GROUPS)
+    return (width % LANES == 0 and rows % SLAB == 0 and groups <= MAX_GROUPS
+            and _token_tile(tokens, width, groups, itemsize) is not None)
 
 
 def _runs(token, group_sizes, n_live, tile, n_tiles):
@@ -72,7 +82,7 @@ def combine(acc, rows, token, scale, group_sizes, n_live, fresh=False,
     (a traced flag) it is taken as all zero and not read."""
     tokens, d = acc.shape
     m, groups = rows.shape[0], group_sizes.shape[0]
-    tile = _token_tile(tokens)
+    tile = _token_tile(tokens, d, groups, rows.dtype.itemsize)
     n_tiles = tokens // tile
     first, last = _runs(token, group_sizes.astype(jnp.int32),
                         jnp.asarray(n_live, jnp.int32), tile, n_tiles)

@@ -439,11 +439,11 @@ class DataParallelStep:
             for n, p in self._param_items
         }
 
-        # aux leaves a block marks as router load (models/nemotron_h.py):
-        # read at drain, never inside a step
-        self._moe_load_names = [
-            n for n, p in self._param_items
-            if getattr(p, "telemetry", None) == "moe_load"]
+        # aux leaves a block marks for telemetry (an expert layer's load, a
+        # second head's loss term): read at drain, never inside a step
+        self._aux_reading_kinds = {
+            n: p.telemetry for n, p in self._param_items
+            if getattr(p, "telemetry", None)}
 
         if optimizer not in ("sgd", "adam"):
             raise MXNetError(f"fused step supports sgd/adam, got {optimizer}")
@@ -1086,22 +1086,23 @@ class DataParallelStep:
         """Force every in-flight step (epoch end, pre-checkpoint, exit);
         raises the first deferred failure."""
         self._inflight.drain()
-        self._record_moe_load()
+        self._record_aux_readings()
 
-    def _record_moe_load(self) -> None:
-        """Hand the expert layers' load counters (aux state the steps wrote
-        on the device) to telemetry: a sync, so only here, where every step
-        in flight has just been forced."""
-        if not self._moe_load_names or self.params is None:
+    def _record_aux_readings(self) -> None:
+        """Hand the aux leaves marked for telemetry (state the steps wrote
+        on the device) to it: a sync, so only here, where every step in
+        flight has just been forced."""
+        if not self._aux_reading_kinds or self.params is None:
             return
         import jax
 
         from .. import telemetry
 
         values = jax.device_get(
-            {n: self.params[n] for n in self._moe_load_names})
+            {n: self.params[n] for n in self._aux_reading_kinds})
         for name, v in values.items():
-            telemetry.record_moe_load(name, [float(x) for x in v])
+            telemetry.record_aux_reading(self._aux_reading_kinds[name], name,
+                                         [float(x) for x in v])
 
     @property
     def inflight_depth(self) -> int:

@@ -219,9 +219,11 @@ class _State:
         # executor -> {"sigs": set, "traces": int, "warned_at": int,
         #              "last_sig": str}
         self.retraces: Dict[str, Dict[str, Any]] = {}
-        # expert layers' load counters, newest reading per aux leaf
-        # (record_moe_load; kept whether or not the recorder is enabled)
-        self.moe_load: Dict[str, List[float]] = {}
+        # kind -> aux leaf -> newest reading of the aux leaves a block marks
+        # with ``telemetry = kind`` (record_aux_reading: the expert layers'
+        # load counters "moe_load", a second loss head's "mtp_loss"; kept
+        # whether or not the recorder is enabled)
+        self.aux_readings: Dict[str, Dict[str, List[float]]] = {}
         # what the newest traced gradient's recomputed layers keep from
         # their forward pass (record_recompute_kept; kept like moe_load)
         self.recompute_kept: Dict[str, int] = {}
@@ -706,22 +708,36 @@ def record_collective(op: str, nbytes: int, wall_s: float,
            wall_ms=round(wall_s * 1e3, 3), traced=bool(traced), **fields)
 
 
-def record_moe_load(name: str, values: List[float]) -> None:
-    """Newest reading of one expert layer's load counter (an aux leaf named
-    ``...load``: pairs landed on each held expert in the last step, or
-    ``...load_max``: their running maximum; both relative to an even spread
-    over all the layer's experts).  ``DataParallelStep.drain`` calls this
-    after its sync; nothing calls it inside a step.  Kept in memory
-    (``moe_load()``) whether or not the recorder is enabled."""
+def record_aux_reading(kind: str, name: str, values: List[float]) -> None:
+    """Newest reading of one aux leaf that its block marks with ``telemetry =
+    kind`` (state the steps write on the device and no step syncs to read).
+    ``DataParallelStep.drain`` calls this after its sync; nothing calls it
+    inside a step.  Kept in memory (``aux_readings(kind)``) whether or not
+    the recorder is enabled."""
     with _state.lock:
-        _state.moe_load[name] = list(values)
-    record("moe_load", name=name, values=list(values))
+        _state.aux_readings.setdefault(kind, {})[name] = list(values)
+    record(kind, name=name, values=list(values))
+
+
+def aux_readings(kind: str) -> Dict[str, List[float]]:
+    """name -> the newest reading ``record_aux_reading`` was given of
+    ``kind``."""
+    with _state.lock:
+        return {k: list(v)
+                for k, v in _state.aux_readings.get(kind, {}).items()}
+
+
+def record_moe_load(name: str, values: List[float]) -> None:
+    """``record_aux_reading`` of kind ``moe_load``: one expert layer's load
+    counter (an aux leaf named ``...load``: pairs landed on each held expert
+    in the last step, or ``...load_max``: their running maximum; both
+    relative to an even spread over all the layer's experts)."""
+    record_aux_reading("moe_load", name, values)
 
 
 def moe_load() -> Dict[str, List[float]]:
     """name -> the newest reading ``record_moe_load`` was given."""
-    with _state.lock:
-        return {k: list(v) for k, v in _state.moe_load.items()}
+    return aux_readings("moe_load")
 
 
 def record_recompute_kept(layers: int, tensors: int, nbytes: int) -> None:
@@ -1199,6 +1215,8 @@ def summary() -> dict:
                             for k, v in _state.ckpt.items()},
             "fused_update": dict(_state.fused),
             "moe_load": moe_load(),
+            "aux_readings": {kind: {k: list(v) for k, v in rows.items()}
+                             for kind, rows in _state.aux_readings.items()},
             "recompute_kept": dict(_state.recompute_kept),
             "serving": _serving_rollup(),
             "spans": {
