@@ -416,6 +416,35 @@ def kernels_phase(ctx, on_path, fused_paged_attention, rows, units, heads,
              f"6 choices: relative delta to the scatter-add form "
              f"{d_fwd:.1e} for the sum, {d_bwd:.1e} over its four gradients")
 
+        # a hyper-connected sublayer at the xing4_0 widths: the stream mix's
+        # kernels against the jax form (the form a partitioned step takes)
+        from mxnet_tpu.ops import hc_ops
+
+        streams, mix_w = rand(1024, 4 * 3584), rand(3584)
+        mix_leaves = (jnp.ones((4 * 3584,), jnp.bfloat16),
+                      (rand(24, 4 * 3584) * 0.02).astype(jnp.bfloat16),
+                      jnp.full((3,), 0.01, jnp.bfloat16), rand(24))
+
+        def mix_loss(x, gain, phi, a, b):
+            c = hc_ops.mhc_coefficients(x, gain, phi, a, b)
+            out = hc_ops.mhc_post(x, hc_ops.mhc_pre(x, c) * mix_w, c)
+            return (out.astype(jnp.float32)
+                    * streams.astype(jnp.float32)).sum(), out
+
+        mix = lambda: jax.jit(jax.value_and_grad(mix_loss, has_aux=True))(
+            streams, *mix_leaves)
+        got = mix()
+        with pallas.compute_on(dev.platform, partitioned=True):
+            want = mix()
+        d_fwd, d_bwd = delta(got[0][1], want[0][1]), delta(got[1], want[1])
+        check(d_fwd < 3e-2 and d_bwd < 3e-2,
+              f"mhc_mix differs: forward {d_fwd}, backward {d_bwd}")
+        line("mhc_mix", ("mx_mhc_coef", "mx_mhc_pre", "mx_mhc_post",
+                         "mx_mhc_post_bwd", "mx_mhc_coef_pre_bwd"),
+             f"alone at {tuple(streams.shape)} bf16, 4 streams of 3584, 20 "
+             f"iterations: relative delta to the jax form {d_fwd:.1e} for "
+             f"the streams after the sublayer, {d_bwd:.1e} for gX")
+
         x, r = rand(rows, units), rand(rows, units)
         g = jnp.ones((units,), jnp.bfloat16)
         b = jnp.zeros((units,), jnp.bfloat16)

@@ -20,10 +20,23 @@ shrinks them).  The iterations run with tokens on the LANES, as ``(n, n,
 tokens)``: a ``(tokens, n, n)`` tensor would fill 4 of every 128 lanes.
 Everything is differentiable jax (the iterations are a ``lax.scan``); the ops
 trace once a shape (``jax.jit`` inside), not once a sublayer.
+
+On a TPU, where the code is per-device and the streams are whole lane tiles
+wide (``pallas.mhc_mix.supported``), the three ops are Mosaic kernels under
+two ``custom_vjp``s with a written-out backward (``ops/pallas/mhc_mix.py``);
+anywhere else they are the jax form below, which stays the one statement of
+the equations.  The kernels want a sublayer whole: ``mhc_coefficients`` there
+makes ``u`` in the same op and remembers it for the ``mhc_pre`` that comes
+with the SAME streams and coefficients (the very arrays, inside one trace),
+and ``mhc_post`` reads the streams through that op, so that one backward
+kernel gets every part of the streams' gradient.  A call that does not come
+so (other streams, an eager call op by op) takes ``u`` from the jax form.
 """
 from __future__ import annotations
 
 import functools
+from contextvars import ContextVar
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +45,41 @@ import numpy as np
 from .registry import register
 
 SCOPE = "mx_mhc_mix"
+
+class _Sublayer(NamedTuple):
+    """What the kernel form of ``mhc_coefficients`` made beside ``coeffs``."""
+    streams: jax.Array
+    coeffs: jax.Array
+    u: jax.Array
+    through: jax.Array      # the streams as ``coefficients_pre`` hands them on
+
+
+#: the last kernel-made sublayer of this trace
+_sublayer: ContextVar = ContextVar("mhc_sublayer", default=None)
+
+
+def _kernels(streams, n, k):
+    """The Mosaic kernels' module where they are selected, else None."""
+    from . import pallas as _pk
+    from .pallas import mhc_mix as _kernel
+
+    if _pk.enabled() and _pk.use_compiled() \
+            and _kernel.supported(streams, n, k):
+        return _kernel
+    return None
+
+
+def _flat(v):
+    return v.reshape((1, -1, v.shape[-1]))
+
+
+def _linked(streams, coeffs):
+    """What ``mhc_coefficients`` remembered, if these are its arrays."""
+    link = _sublayer.get()
+    if link is not None and link.streams is streams \
+            and link.coeffs is coeffs:
+        return link
+    return None
 
 
 def sinkhorn(r, iters, eps):
@@ -81,10 +129,19 @@ def mhc_coefficients(streams, gain, phi, a, b, n=4, iters=20, eps=1e-6,
     n^2 to ``R`` row-major; a (3,) the scalars of P, Q, R; b (2 n + n^2,).
     Returns (..., 2 n + n^2) f32: ``H_pre | H_post | H_res`` row-major
     (``H_res[i, j]`` at ``2 n + i n + j``)."""
+    attrs = (int(n), int(iters), float(eps), float(clamp_min),
+             float(clamp_max), float(rms_eps))
+    kernel = _kernels(streams, int(n), phi.shape[0])
     with jax.named_scope(SCOPE):
-        return _coefficients(streams, gain, phi, a, b, int(n), int(iters),
-                             float(eps), float(clamp_min), float(clamp_max),
-                             float(rms_eps))
+        if kernel is None:
+            return _coefficients(streams, gain, phi, a, b, *attrs)
+        c, u, through = kernel.coefficients_pre(
+            kernel.config(*attrs), _flat(streams), gain, phi, a, b)
+        c = c.reshape(streams.shape[:-1] + c.shape[-1:])
+        _sublayer.set(_Sublayer(
+            streams, c, u.reshape(streams.shape[:-1] + (-1,)),
+            through.reshape(streams.shape)))
+        return c
 
 
 def _split(streams, n):
@@ -104,6 +161,9 @@ def _pre(streams, coeffs, n):
 def mhc_pre(streams, coeffs, n=4):
     """What the sublayer reads: ``sum_i H_pre[i] X_i`` (..., d), summed in
     f32 and rounded once."""
+    link = _linked(streams, coeffs)
+    if link is not None:
+        return link.u
     with jax.named_scope(SCOPE):
         return _pre(streams, coeffs, int(n))
 
@@ -125,5 +185,15 @@ def _post(streams, y, coeffs, n):
 def mhc_post(streams, y, coeffs, n=4):
     """The streams after the sublayer: ``X'_i = sum_j H_res[i, j] X_j +
     H_post[i] y`` (..., n d), summed in f32 and rounded once."""
+    n = int(n)
+    kernel = _kernels(streams, n, coeffs.shape[-1])
     with jax.named_scope(SCOPE):
-        return _post(streams, y, coeffs, int(n))
+        if kernel is None or y.dtype != streams.dtype:
+            return _post(streams, y, coeffs, n)
+        link = _linked(streams, coeffs)
+        if link is not None:
+            _sublayer.set(None)         # the sublayer's last op
+        out = kernel.post(kernel.config(n),
+                          _flat(streams if link is None else link.through),
+                          _flat(y), _flat(coeffs))
+        return out.reshape(streams.shape)

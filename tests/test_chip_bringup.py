@@ -305,6 +305,66 @@ def test_chunked_scan_lowers_as_its_kernels_at_the_published_shapes():
     assert exp.out_avals[0].dtype == BF16
 
 
+def test_the_stream_mix_lowers_as_its_kernels_at_the_cells_shapes():
+    """A hyper-connected sublayer's gradient at the Xing cell's shapes (4
+    streams of 3,584, 4,096 tokens, bf16): only ``mx_mhc_*`` Mosaic calls,
+    each found by the cell's UNCHANGED ``mhc_mix`` trace pattern and by none
+    of the other three; no loop is left (Sinkhorn's iterations are inside
+    ``mx_mhc_coef`` and ``mx_mhc_coef_pre_bwd``) and outside the calls no
+    sum over a stream's lanes into one f32 a token."""
+    import re
+
+    from mxnet_tpu.ops import hc_ops
+
+    def grads(ct, x, gain, phi, a, b, w):
+        def sublayer(x, gain, phi, a, b, w):
+            c = hc_ops.mhc_coefficients(x, gain, phi, a, b)
+            u = hc_ops.mhc_pre(x, c)
+            return hc_ops.mhc_post(x, u * w, c)
+
+        out, vjp = jax.vjp(sublayer, x, gain, phi, a, b, w)
+        return (out,) + vjp(ct)
+
+    x = _s((1, 4096, 14336))
+    with pallas.compute_on("tpu"):
+        exp = jax.export.export(jax.jit(grads), platforms=["tpu"])(
+            x, x, _s((14336,)), _s((24, 14336)), _s((3,)), _s((24,)),
+            _s((3584,)))
+    text = exp.mlir_module()
+    calls = [ln for ln in text.splitlines() if "kernel_name" in ln]
+    names = [re.search(r'kernel_name = "(\w+)"', ln).group(1) for ln in calls]
+    assert names == ["mx_mhc_coef", "mx_mhc_pre", "mx_mhc_post",
+                     "mx_mhc_post_bwd", "mx_mhc_coef_pre_bwd"]
+    with open(os.path.join(_REPO, "benchmark", "checks",
+                           "xing4_0_29b_a4b.train_1x4k.json")) as f:
+        patterns = json.load(f)["kernels"]
+
+    def as_hlo(mlir_type):              # 1x4096x24xf32 -> f32[1,4096,24]
+        *dims, dtype = mlir_type.split("x")
+        return f"{dtype}[{','.join(dims)}]"
+
+    for name, ln in zip(names, calls):
+        operands, results = re.search(
+            r" : \((.*?)\) -> \(?(.*?)\)? loc", ln).groups()
+        shapes = [as_hlo(t) for t in re.findall(r"tensor<([\w]+)>",
+                                                operands + results)]
+        assert "bf16[1,4096,14336]" in shapes
+        hlo = f"%{name}.1 = ({', '.join(shapes)}) custom-call()"
+        assert re.search(patterns["mhc_mix"], hlo)
+        for other in ("mla_front", "moe_experts", "flash_attention"):
+            assert not re.search(patterns[other], hlo)
+    outside = "\n".join(ln for ln in text.splitlines()
+                        if "kernel_name" not in ln)
+    assert "stablehlo.while" not in outside
+    assert "4x4x4096xf32" not in outside
+    # a reduction over lanes to one f32 a token would print this result
+    assert not re.search(r"stablehlo.reduce.*-> tensor<(1x)?4096xf32>",
+                         outside)
+    assert [tuple(o.shape) for o in exp.out_avals] == [
+        (1, 4096, 14336), (1, 4096, 14336), (14336,), (24, 14336), (3,),
+        (24,), (3584,)]
+
+
 def test_expert_products_lower_as_grouped_kernels_at_the_published_widths():
     from mxnet_tpu.ops import moe_ops
 
@@ -519,6 +579,19 @@ def test_kernels_are_not_selected_where_gspmd_partitions():
             _s((1024, 256)), _s((1024, 6), jnp.int32),
             _s((1024, 6), jnp.float32), _s((8, 256, 128)),
             _s((8, 128, 256)), partitioned=True) == []
+        # the stream mix: the jax form, Sinkhorn's scan and all
+        from mxnet_tpu.ops import hc_ops
+
+        def mix(x, gain, phi, a, b, y):
+            c = hc_ops.mhc_coefficients(x, gain, phi, a, b)
+            return hc_ops.mhc_post(x, y * hc_ops.mhc_pre(x, c), c)
+
+        assert hc_ops._kernels(_s((8, 512)), 4, 24) is None
+        assert _mosaic_calls(
+            jax.grad(lambda *args: mix(*args).astype(jnp.float32).sum(),
+                     argnums=(0, 1, 2, 3, 4)),
+            _s((2, 64, 512)), _s((512,)), _s((24, 512)), _s((3,)), _s((24,)),
+            _s((2, 64, 128)), partitioned=True) == []
         # a shard_map body is per-device code again
         seen = []
         mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
