@@ -92,6 +92,11 @@ class Block:
         self._empty_prefix = prefix == ""
         self._prefix, self._params = _scope.create(prefix, params, self._alias())
         self._name = self._prefix[:-1] if self._prefix.endswith("_") else self._prefix
+        # what a trace calls this block's ops: class, and the name it has
+        # inside its parent (docs/OBSERVABILITY.md, Device time by scope)
+        parent = _scope._current
+        local = self._prefix[len(parent.prefix) if parent else 0:]
+        self._trace_name = f"{type(self).__name__}.{local}"
         self._scope_counters: Dict[str, int] = {}
         self._children: "OrderedDict[str, Block]" = OrderedDict()
         self._reg_params: Dict[str, Parameter] = {}
@@ -241,10 +246,23 @@ class Block:
     def __call__(self, *args):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        out = self.forward(*args)
+        if trace_active():
+            with self.trace_scope():
+                out = self.forward(*args)
+        else:
+            out = self.forward(*args)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
+
+    def trace_scope(self):
+        """The scope of this block's ops in a traced program: the Gluon
+        hierarchy is the scope path of every compiled instruction
+        (``DataParallelStep.scope_map``).  Metadata alone; entered only
+        while a trace is active."""
+        import jax
+
+        return jax.named_scope(self._trace_name)
 
     def forward(self, *args):
         raise NotImplementedError

@@ -10,6 +10,7 @@ all-reduces on ICI.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from ..gluon import nn
@@ -83,8 +84,9 @@ class BERTForMLM(HybridBlock):
 
     def hybrid_forward(self, F, inputs, token_types=None, valid_length=None):
         seq, _ = self.bert(inputs, token_types, valid_length)
-        h = self.mlm_ln(F.LeakyReLU(self.mlm_dense(seq), act_type="gelu"))
-        return self.decoder(h)
+        with jax.named_scope("mx_head"):
+            h = self.mlm_ln(F.LeakyReLU(self.mlm_dense(seq), act_type="gelu"))
+            return self.decoder(h)
 
 
 def bert_sharding_rules() -> ShardingRules:

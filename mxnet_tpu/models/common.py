@@ -42,11 +42,12 @@ def checkpointed(block, *xs):
 
 def vocab_logits(h, weight):
     """``h`` (B, L, d) against the rows of ``weight`` (V, d): logits (B, L,
-    V) accumulated and kept in f32."""
-    return _reg.invoke_fn(
-        lambda a, w: jnp.einsum("bld,vd->blv", a, w,
-                                preferred_element_type=jnp.float32),
-        [h, weight])
+    V) accumulated and kept in f32, under the scope ``mx_head``."""
+    with jax.named_scope("mx_head"):
+        return _reg.invoke_fn(
+            lambda a, w: jnp.einsum("bld,vd->blv", a, w,
+                                    preferred_element_type=jnp.float32),
+            [h, weight])
 
 
 class HeldExperts(HybridBlock):

@@ -356,9 +356,11 @@ class XingModel(HybridBlock):
 
     def hybrid_forward(self, F, tokens, embed_weight, head_weight,
                        mtp_loss=None):
-        e = F.Embedding(tokens, embed_weight, input_dim=embed_weight.shape[0],
-                        output_dim=embed_weight.shape[1])
-        x = _spread(e, self._n)
+        with jax.named_scope("mx_embed"):
+            e = F.Embedding(tokens, embed_weight,
+                            input_dim=embed_weight.shape[0],
+                            output_dim=embed_weight.shape[1])
+            x = _spread(e, self._n)
         for layer in self.layers:
             x = self._run(layer, x)
         h = _gather(x, self._n)

@@ -271,8 +271,10 @@ class ZayaModel(HybridBlock):
                                      prefix="norm_f_")
 
     def hybrid_forward(self, F, tokens, embed_weight):
-        x = F.Embedding(tokens, embed_weight, input_dim=embed_weight.shape[0],
-                        output_dim=embed_weight.shape[1])
+        with jax.named_scope("mx_embed"):
+            x = F.Embedding(tokens, embed_weight,
+                            input_dim=embed_weight.shape[0],
+                            output_dim=embed_weight.shape[1])
         # depth averaging starts from nought before the first held layer
         r = F.zeros_like(F.slice_axis(x, axis=-1, begin=0,
                                       end=self._router_width))

@@ -32,6 +32,11 @@ from .sharding import ShardingRules, replicated, shard_batch
 __all__ = ["DataParallelStep", "make_train_step", "compile_step_with_plan",
            "dp_plan"]
 
+# scope_map's compile names an option (at its default: the program is the
+# same) so that jax looks the executable up anew instead of handing back the
+# running one, whose metadata may be an older tree's (_scope_source)
+_OWN_COMPILE = {"xla_dump_max_hlo_modules": -1}
+
 def _global_put(arr, sharding):
     """device_put that also works on multi-process (multi-controller)
     meshes: every process passes the same host-global value and installs
@@ -186,7 +191,8 @@ def _block_apply_fn(block, ctx, train: bool):
         prev_train = autograd.set_training(train)
         prev_key = _random.set_trace_key_provider(_random._TraceKeyProvider(key))
         try:
-            out = block.forward(*nd_inputs)
+            with block.trace_scope():
+                out = block.forward(*nd_inputs)
         finally:
             state = end_trace(prev_trace)
             autograd.set_recording(prev_rec)
@@ -470,6 +476,10 @@ class DataParallelStep:
         # never run memory/analysis APIs, mxlint hot-sync) stamps what it
         # knows at the traced call; step() hands it to memwatch after
         self._pending_compile: Optional[Dict[str, Any]] = None
+        # scope_map(): the batch side of the traced call's signature (shape
+        # mirrors, stamped with it) and the map once somebody asked
+        self._scope_args = None
+        self._scope_map: Optional[Dict[str, dict]] = None
         # compiled allgather for state_dict's sharded->host baseline,
         # built lazily once per step object
         self._gather_jit = None
@@ -598,9 +608,10 @@ class DataParallelStep:
             out, aux = apply_fn(params, key, *data)  # data: tuple of arrays
             out_nd = (NDArray(out, ctx=ctx) if not isinstance(out, list)
                       else [NDArray(o, ctx=ctx) for o in out])
-            loss = loss_fn(out_nd, NDArray(label, ctx=ctx))
-            larr = loss._data if isinstance(loss, NDArray) else loss
-            return jnp.mean(larr.astype(jnp.float32)), aux
+            with jax.named_scope("mx_loss"):
+                loss = loss_fn(out_nd, NDArray(label, ctx=ctx))
+                larr = loss._data if isinstance(loss, NDArray) else loss
+                return jnp.mean(larr.astype(jnp.float32)), aux
 
         accum = self.plan.accum_steps
         ls_cfg = self._loss_scale_cfg
@@ -658,26 +669,29 @@ class DataParallelStep:
                         lambda a, b: a + b, grads, g_i))
                 grads = jax.tree_util.tree_map(lambda g: g / accum, grads)
                 aux = [(n, v / accum) for n, v in aux_sums.items()]
-            base_rescale = rescale if scale is None else rescale / scale
-            eff_rescale = base_rescale
-            if clip_global is not None:
-                # ONE fused global-norm reduction over the rescaled grads of
-                # the trainable params, folded into the per-param rescale
-                sq = sum(
-                    jnp.sum(jnp.square(grads[n].astype(jnp.float32)
-                                       * base_rescale))
-                    for n in grads if mults.get(n, (1.0, 1.0))[0] is not None)
-                gnorm = jnp.sqrt(sq)
-                eff_rescale = base_rescale * jnp.minimum(
-                    1.0, clip_global / (gnorm + 1e-12))
-            if opt == "sgd":
-                new_params, new_state = _sgd_tree_update(
-                    params, grads, opt_state, lr, momentum, wd, eff_rescale,
-                    mults, clip_elem)
-            else:
-                new_params, new_state = _adam_tree_update(
-                    params, grads, opt_state, lr, beta1, beta2, eps, wd,
-                    eff_rescale, mults, clip_elem)
+            with jax.named_scope("mx_update"):
+                base_rescale = rescale if scale is None else rescale / scale
+                eff_rescale = base_rescale
+                if clip_global is not None:
+                    # ONE fused global-norm reduction over the rescaled grads
+                    # of the trainable params, folded into the per-param
+                    # rescale
+                    sq = sum(
+                        jnp.sum(jnp.square(grads[n].astype(jnp.float32)
+                                           * base_rescale))
+                        for n in grads
+                        if mults.get(n, (1.0, 1.0))[0] is not None)
+                    gnorm = jnp.sqrt(sq)
+                    eff_rescale = base_rescale * jnp.minimum(
+                        1.0, clip_global / (gnorm + 1e-12))
+                if opt == "sgd":
+                    new_params, new_state = _sgd_tree_update(
+                        params, grads, opt_state, lr, momentum, wd,
+                        eff_rescale, mults, clip_elem)
+                else:
+                    new_params, new_state = _adam_tree_update(
+                        params, grads, opt_state, lr, beta1, beta2, eps, wd,
+                        eff_rescale, mults, clip_elem)
             # aux (BN stats): already averaged over the global batch by XLA
             for name, val in aux:
                 new_params[name] = val.astype(new_params[name].dtype)
@@ -699,16 +713,17 @@ class DataParallelStep:
 
             new_params, new_state, loss, grads = _update_core(
                 params, opt_state, key, lr, data, label, scaler["scale"])
-            finite = _ls.grads_finite(grads, mults)
             # skip-step selection: weights, momenta, Adam's t AND the
             # forward's aux stats all hold when any grad is non-finite
             def hold(new, old):
                 return jax.tree_util.tree_map(
                     lambda a, b: jnp.where(finite, a, b), new, old)
 
-            new_params = hold(new_params, params)
-            new_state = hold(new_state, opt_state)
-            new_scaler = _ls.scaler_update(scaler, finite, ls_cfg)
+            with jax.named_scope("mx_update"):
+                finite = _ls.grads_finite(grads, mults)
+                new_params = hold(new_params, params)
+                new_state = hold(new_state, opt_state)
+                new_scaler = _ls.scaler_update(scaler, finite, ls_cfg)
             return new_params, new_state, new_scaler, loss
 
         repl = replicated(self.mesh)
@@ -930,6 +945,10 @@ class DataParallelStep:
                  loss) = outs
             else:
                 self.params, self.opt_state, loss = outs
+        if traced:
+            self._scope_args = (memwatch.shape_structs(
+                (key, lr_val, data_arrs, label_arr)), sp_active)
+            self._scope_map = None
         if traced and telemetry.enabled():
             # what step() needs to book the compile once the hot body is
             # done: structural fingerprint parts + arg shape mirrors
@@ -1087,6 +1106,65 @@ class DataParallelStep:
         raises the first deferred failure."""
         self._inflight.drain()
         self._record_aux_readings()
+        # the MEANS to the scope map, not the map: whoever reads a trace of
+        # this step asks telemetry, maybe after the step object is gone
+        source = self._scope_map or self._scope_source()
+        if source is not None:
+            telemetry.record_scope_map(self._tele_name, source)
+
+    def scope_map(self) -> Dict[str, dict]:
+        """``{instruction name: {"scope", "block", "dir", "entry",
+        "mixed"}}`` for every instruction of the executable this step runs
+        that can be an event of a device trace: the Gluon blocks and
+        ``mx_*`` scopes it lies under (``Block.trace_scope``; this step
+        adds ``mx_loss`` and ``mx_update``), its pass (``fwd``, ``remat``,
+        ``bwd``), whether it is in the ENTRY computation, and what XLA fused
+        into it from another block (``hlo_scopes`` has the rules).  Built
+        from the compiled module's text on the FIRST ask and kept; a
+        ``step()`` never builds it.  Also handed to
+        ``telemetry.record_scope_map``.  Empty before the first step."""
+        if self._scope_map is None:
+            source = self._scope_source()
+            if source is None:
+                return {}
+            self._scope_map = source()
+        telemetry.record_scope_map(self._tele_name, self._scope_map)
+        return self._scope_map
+
+    def _scope_source(self):
+        """A function of no arguments that makes ``scope_map``'s dict, or
+        None before the first step.  It holds the traced program (jax's own
+        record of the last traced call: no second trace) and shapes, no
+        parameter, no block and not this object, so ``drain`` can hand it to
+        telemetry and the map of a step that is gone can still be asked for.
+        The compile behind it is a load from the persistent cache wherever
+        this program's own compile is there: metadata is in the key for
+        this one lookup, because an entry that an older tree wrote under
+        the usual key holds that tree's scopes."""
+        if self._jitted is None or self._scope_args is None:
+            return None
+        from jax._src import config as _jax_config
+
+        from ..ops import pallas as _pk
+
+        batch, sp_active = self._scope_args
+        state = memwatch.shape_structs(
+            (self.params, self.opt_state) if self.scaler_state is None
+            else (self.params, self.opt_state, self.scaler_state))
+        ring_cm, pp_cm = self._dispatch_scopes(sp_active)
+        mesh_platform = next(iter(self.mesh.devices.flat)).platform
+        with _pk.compute_on(mesh_platform, self.mesh.size > 1), \
+                ring_cm, pp_cm:
+            traced = self._jitted.trace(*state, *batch)
+
+        def make():
+            from ..hlo_scopes import scope_map_of
+
+            with _jax_config.compilation_cache_include_metadata_in_key(True):
+                compiled = traced.lower().compile(compiler_options=_OWN_COMPILE)
+            return scope_map_of(compiled.as_text())
+
+        return make
 
     def _record_aux_readings(self) -> None:
         """Hand the aux leaves marked for telemetry (state the steps wrote

@@ -227,6 +227,9 @@ class _State:
         # what the newest traced gradient's recomputed layers keep from
         # their forward pass (record_recompute_kept; kept like moe_load)
         self.recompute_kept: Dict[str, int] = {}
+        # executor -> the scope of each instruction it compiled, or the
+        # function that makes it (record_scope_map; kept like moe_load)
+        self.scope_maps: Dict[str, Any] = {}
         # span name -> {count, total_ms, max_ms}
         self.spans: Dict[str, Dict[str, float]] = {}
         # finished spans, oldest first out (spans_between)
@@ -738,6 +741,31 @@ def record_moe_load(name: str, values: List[float]) -> None:
 def moe_load() -> Dict[str, List[float]]:
     """name -> the newest reading ``record_moe_load`` was given."""
     return aux_readings("moe_load")
+
+
+def record_scope_map(executor: str, scope_map) -> None:
+    """The scope of every instruction of the executable ``executor`` runs
+    (``DataParallelStep.scope_map``: instruction name -> scope path, block,
+    direction, entry, mixed), or a function of no arguments that makes it:
+    ``DataParallelStep.drain`` hands the function, so the map costs nothing
+    until ``scope_map()`` asks, and can be asked for after the step object
+    is gone.  Kept in memory whether or not the recorder is enabled."""
+    with _state.lock:
+        _state.scope_maps[executor] = scope_map
+
+
+def scope_map() -> Dict[str, Dict[str, dict]]:
+    """executor -> the map ``record_scope_map`` was given.  This is the ask:
+    a function handed in a map's place is called here, once, and its map
+    kept."""
+    with _state.lock:
+        maps = dict(_state.scope_maps)
+    for executor, value in maps.items():
+        if callable(value):
+            maps[executor] = value = value()
+            with _state.lock:
+                _state.scope_maps[executor] = value
+    return maps
 
 
 def record_recompute_kept(layers: int, tensors: int, nbytes: int) -> None:

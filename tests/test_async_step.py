@@ -449,7 +449,8 @@ def test_one_dispatch_path_is_the_whole_surface():
         "stage", "step", "drain", "inflight_depth", "learning_rate",
         "set_learning_rate", "sync_to_block", "layout", "state_dict",
         "shard_state_dict", "load_state_dict",
-        "snapshot_requires_collective"}
+        "snapshot_requires_collective",
+        "scope_map"}        # PR 35: read-only, built on ask, never by step()
 
 
 def test_drain_all_preemption_path(monkeypatch):
