@@ -416,6 +416,37 @@ def kernels_phase(ctx, on_path, fused_paged_attention, rows, units, heads,
              f"6 choices: relative delta to the scatter-add form "
              f"{d_fwd:.1e} for the sum, {d_bwd:.1e} over its four gradients")
 
+        # the same op at the zaya widths: one choice a token over 16 gated
+        # experts all held, 8,192 rows in one chunk of uneven groups, at the
+        # tiles ``grouped_tiling`` picks for that shape
+        zu, zw = rand(8192, 2048), rand(8192, 2048)
+        zmats = tuple((rand(16, 2048, 2048) * 0.02).astype(jnp.bfloat16)
+                      for _ in range(3))
+        zexperts = jax.random.categorical(
+            jax.random.PRNGKey(7), jnp.linspace(1.5, -1.5, 16),
+            shape=(8192, 1)).astype(jnp.int32)
+        zgates = jax.nn.sigmoid(rand(8192, 1).astype(jnp.float32))
+
+        def gated_loss(u, gates, gate, up, down):
+            out = moe_ops.moe_experts(u, zexperts, gates, up, down, gate,
+                                      activation="swiglu")[0]
+            return (out.astype(jnp.float32) * zw.astype(jnp.float32)).sum()
+
+        gated = lambda: jax.jit(jax.value_and_grad(
+            gated_loss, argnums=(0, 1, 2, 3, 4)))(zu, zgates, *zmats)
+        got = gated()
+        with pallas.compute_on(dev.platform, partitioned=True):
+            want = gated()
+        d_fwd = delta(got[0], want[0])
+        d_bwd = max(delta(a, b) for a, b in zip(got[1], want[1]))
+        check(d_fwd < 3e-2 and d_bwd < 3e-2,
+              f"gated moe_experts differs: forward {d_fwd}, backward {d_bwd}")
+        tiles = moe_ops.grouped_tiling("gmm", 8192, 2048, 2048, 16, 2)
+        log(f"kernel moe_experts (gated): alone at {tuple(zu.shape)} bf16, 16 "
+            f"experts of 2048 held, 1 choice, gmm tiles {tiles}: relative "
+            f"delta to the ragged_dot form {d_fwd:.1e} for the sum, "
+            f"{d_bwd:.1e} over its five gradients")
+
         # a hyper-connected sublayer at the xing4_0 widths: the stream mix's
         # kernels against the jax form (the form a partitioned step takes)
         from mxnet_tpu.ops import hc_ops

@@ -6,9 +6,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from ..gluon.block import HybridBlock
 from ..gluon.parameter import record_aux_update
 from ..ndarray import NDArray
+from ..ops import moe_ops
 from ..ops import recompute as _recompute
 from ..ops import registry as _reg
 
@@ -82,8 +84,11 @@ class HeldExperts(HybridBlock):
                                         grad_req="null")
             self.load_max = self.params.get("load_max", shape=(count,),
                                             init="zeros", grad_req="null")
-        # DataParallelStep.drain reads what carries this mark
+        # DataParallelStep.drain reads what carries this mark, and hands
+        # the reading to ``on_reading`` where a leaf has one
         self.load.telemetry = self.load_max.telemetry = "moe_load"
+        self.load.on_reading = self._record_rows_run
+        self._tokens = None         # of the last trace
 
     def routed(self, F, flat, experts, weights, up_weight, down_weight, load,
                gate_weight=None):
@@ -94,8 +99,21 @@ class HeldExperts(HybridBlock):
         out, landed = F._contrib_moe_experts(
             flat, experts, weights, *mats, first=self._first,
             activation=self._activation)
-        even = flat.shape[0] * self._k / self._e     # pairs an expert gets
+        self._tokens = flat.shape[0]
+        even = self._tokens * self._k / self._e      # pairs an expert gets
         return out, (landed.astype("float32") / even).astype(load.dtype)
+
+    def _record_rows_run(self, name, load):
+        """``rows_run_over_rows`` of the last step from its ``load`` (to that
+        leaf's precision), for ``telemetry.record_rows_run``."""
+        if self._tokens is None:
+            return
+        even = self._tokens * self._k / self._e
+        ratio = moe_ops.rows_run_over_rows(
+            [round(x * even) for x in load], self._tokens, self._k,
+            telemetry.grouped_tiles())
+        if ratio is not None:
+            telemetry.record_rows_run(name, ratio)
 
     def record_load(self, load):
         """The aux write of this step's ``load`` (outside any recomputed

@@ -24,20 +24,112 @@ and adds its weighted results to its tokens' rows of the loop's f32 carry
 (one Mosaic call on a TPU, ``ops/pallas/moe_rows.py``; a scatter-add
 elsewhere).  The work follows the landed pairs a chunk at a time; inside a
 chunk every row runs, the rows past the landed pairs with no weight.
+
+megablox visits a tile of rows once for EVERY group with rows in it, each
+visit a whole tile's product with the store masked, and fetches a group's
+matrix block again at every step unless the whole contraction is one tile.
+So the products' tiles follow their shapes (``grouped_tiling``, by the cost
+written out in ``grouped_cost``): row tiles small where boundaries are many
+beside the rows, the contraction whole wherever a block of it fits VMEM, so
+that a group's matrix stays there across its row tiles.  Telemetry is told
+each pick at trace time and, at ``drain``, the rows run over the rows had.
 """
 from __future__ import annotations
 
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from .. import telemetry
 from . import pallas as _pk
 from .pallas import moe_rows
 from .registry import register
 
-#: m, k and n tile of the grouped products on the chip (VMEM: about 10 MB)
-GMM_TILING = (512, 1024, 1024)
+# What a grouped product costs on the chip, as far as its shapes tell: the
+# matrix units' pace in bf16, the rate HBM feeds VMEM, what a grid step costs
+# whatever it does, what ``tgmm`` spends an element of its row blocks to mask
+# and turn them (in f32, on the vector units), and the VMEM a Mosaic call gets
+# unasked less room for the kernel's own temporaries.  Fitted to one sweep on
+# a v5e (tools/grouped_tiles.py measures the candidates beside this estimate;
+# docs/ZAYA.md has its table): within 10% on the three models' shapes where
+# the widths are whole lanes
+MXU_FLOPS, HBM_BYTES, STEP_S, VMEM_BYTES = 197e12, 819e9, 0.35e-6, 14 << 20
+MASK_S = 0.76e-12
+ROW_TILES = (128, 256, 512)
+
+
+def _tile_sizes(width):
+    """``width`` whole, its even splits into 2 to 8 tiles of whole lanes (the
+    last tile may hang over: what hangs over is masked and still runs), and
+    1,024 and 512, which fit VMEM at any width."""
+    split = {-(-width // (j * 128)) * 128 for j in range(2, 9)} | {1024, 512}
+    return [width] + sorted((t for t in split if 256 <= t < width),
+                            reverse=True)
+
+
+def grouped_vmem(kind, tiling, itemsize, carry=False):
+    """Bytes of VMEM a grouped product holds at ``tiling``: two buffers of
+    every block, the f32 accumulator and, where an f32 ``carry`` is added to,
+    its block in and out."""
+    tm, tk, tn = tiling
+    if kind == "gmm":
+        return 2 * (tm * tk + tk * tn + tm * tn) * itemsize + tm * tn * 4
+    out = 2 * tk * tn * (2 * 4 if carry else itemsize)
+    return 2 * tm * (tk + tn) * itemsize + out + tk * tn * 4
+
+
+def grouped_cost(kind, tiling, m, k, n, groups, itemsize, carry=False):
+    """Seconds a grouped product takes at ``tiling`` by the worst case of its
+    grid: a row tile is visited once by every group with rows in it, ``m / tm
+    + groups - 1`` visits of a whole tile's work; a grid step takes the
+    longer of its matrix-unit time and the fetch of the blocks whose index
+    changed, and a fixed cost (``tgmm``'s grows with the rows it masks).
+    ``gmm`` (rows (m, k) times (groups, k, n)) fetches a group's matrix block
+    once a (group, n tile) where the whole contraction is one tile, and every
+    step otherwise; ``tgmm`` (the matrices' gradient (groups, k, n), m
+    contracted) writes a block, and reads the ``carry``'s, where the group
+    changes."""
+    tm, tk, tn = tiling
+    visits = -(-m // tm) + groups - 1
+    tiles_k, tiles_n = -(-k // tk), -(-n // tn)
+    steps = visits * tiles_k * tiles_n
+    mxu, step = 2 * tm * tk * tn / MXU_FLOPS, STEP_S
+    if kind == "gmm":
+        rows, matrix = tm * (tk + tn / tiles_k) * itemsize, tk * tn * itemsize
+        changes = groups * tiles_n if tiles_k == 1 else steps
+    else:
+        rows = tm * (tk + tn) * itemsize
+        matrix = tk * tn * (2 * 4 if carry else itemsize)
+        changes = groups * tiles_k * tiles_n
+        step += MASK_S * tm * (tk + tn)
+    plain = max(mxu, rows / HBM_BYTES)
+    # a carry's f32 block comes and goes beside the row blocks, not after
+    change = (max(plain, matrix / HBM_BYTES) if carry
+              else max(mxu, (rows + matrix) / HBM_BYTES))
+    return (steps - changes) * plain + changes * change + steps * step
+
+
+def grouped_candidates(kind, m, k, n, itemsize, carry=False,
+                       vmem=VMEM_BYTES):
+    """The tilings a grouped product may take: row tiles that divide ``m``
+    where it is whole lanes of rows, ``tk`` and ``tn`` the width whole or a
+    split of it (``tgmm``'s k and n are its result's: it has no contraction
+    tile to mask), inside ``vmem`` by ``grouped_vmem``'s count."""
+    tms = [t for t in ROW_TILES if m % t == 0] if m % 128 == 0 else ROW_TILES
+    return [t for t in itertools.product(tms, _tile_sizes(k), _tile_sizes(n))
+            if grouped_vmem(kind, t, itemsize, carry) <= vmem]
+
+
+@functools.lru_cache(maxsize=None)
+def grouped_tiling(kind, m, k, n, groups, itemsize, carry=False):
+    """The (tm, tk, tn) a grouped product runs at on the chip: the cheapest
+    of ``grouped_candidates`` by ``grouped_cost``, from the shapes alone."""
+    return min(grouped_candidates(kind, m, k, n, itemsize, carry),
+               key=lambda t: (grouped_cost(kind, t, m, k, n, groups,
+                                           itemsize, carry), t))
 
 
 def _choose(scores, bias, top_k, norm, scaling):
@@ -80,22 +172,78 @@ def moe_route_softmax(logits, balance_bias, top_k=1):
         return _choose(p, balance_bias, top_k, False, 1.0)
 
 
-def _kernels(rows):
+def row_tiles_visited(kind, group_sizes, tm):
+    """Grid steps along the rows that megablox makes of sorted rows in
+    groups of ``group_sizes``: every group's own run of tiles of ``tm`` rows,
+    so a tile that holds two groups' rows counts for both; ``tgmm`` also
+    visits an empty group once, to write its zeros."""
+    sizes = np.asarray(group_sizes, np.int64)
+    ends = np.cumsum(sizes)
+    tiles = np.where(sizes > 0, -(-ends // tm) - (ends - sizes) // tm,
+                     kind == "tgmm")
+    return int(tiles.sum())
+
+
+def rows_run_over_rows(landed, tokens, top_k, tilings):
+    """The row tiles that an expert layer's grouped products visited in one
+    step, times their ``tm``, over the rows they had: 1 is the least, and a
+    boundary between two experts inside a tile adds a tile's rows.  From
+    ``landed`` (pairs on each held expert, what ``moe_experts`` returns), the
+    layer's ``tokens`` and ``top_k``, and ``tilings``, the rows of
+    ``telemetry.grouped_tiles()``: those of this layer's chunk count, each
+    as often as it was traced.  None where no product ran as a kernel."""
+    landed = np.asarray(landed, np.int64)
+    worst = tokens * min(top_k, len(landed))
+    rows = min(tokens, worst)
+    mine = [t for t in tilings if t["groups"] == len(landed)
+            and rows <= t["m"] < rows + max(ROW_TILES)]
+    if not mine:
+        return None
+    chunk = mine[0]["m"]
+    ends = np.cumsum(landed)
+    starts = ends - landed
+    n_chunks = 1 if worst == rows else -(-int(ends[-1]) // chunk)
+    run = had = 0
+    for lo in range(0, n_chunks * chunk, chunk):    # as _chunk_index has it
+        here = np.clip(np.minimum(ends, lo + chunk) - np.maximum(starts, lo),
+                       0, None)
+        here[-1] += chunk - here.sum()
+        for t in mine:
+            tm = t["tiling"][0]
+            run += t["sites"] * tm * row_tiles_visited(t["kind"], here, tm)
+            had += t["sites"] * chunk
+    return run / had if had else None
+
+
+def _kernels(rows, tm):
     """True where a chunk of ``rows`` sorted rows runs as Mosaic calls: on a
-    TPU, in per-device code, at whole row tiles.  Elsewhere (the CPU, a step
-    that GSPMD partitions, other row counts) the same chunk runs as XLA ops."""
-    return (_pk.enabled() and _pk.use_compiled()
-            and rows % GMM_TILING[0] == 0)
+    TPU, in per-device code, at whole row tiles of ``tm``.  Elsewhere (the
+    CPU, a step that GSPMD partitions, other row counts) the same chunk runs
+    as XLA ops."""
+    return _pk.enabled() and _pk.use_compiled() and rows % tm == 0
+
+
+def _tiling(kind, lhs, k, n, groups, carry=False):
+    """``grouped_tiling`` for ``lhs``'s rows and type, told to telemetry
+    (trace time: once a call site)."""
+    shape = (lhs.shape[0], int(k), int(n), int(groups))
+    tiling = grouped_tiling(kind, *shape, lhs.dtype.itemsize, carry)
+    if _kernels(lhs.shape[0], tiling[0]):
+        telemetry.record_grouped_tiles(kind, *shape, carry, tiling)
+        return tiling
+    return None
 
 
 def _grouped_dot(lhs, rhs, group_sizes, transpose_rhs=False):
     """Rows of ``lhs`` (m, k), sorted by group, times their group's
     ``rhs[g]`` (k, n), or its transpose (rhs (groups, n, k)) -> (m, n) in
     lhs's type.  ``group_sizes`` sum to m."""
-    if _kernels(lhs.shape[0]):
+    tiling = _tiling("gmm", lhs, lhs.shape[1],
+                     rhs.shape[1 if transpose_rhs else 2], rhs.shape[0])
+    if tiling:
         from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
-        return gmm(lhs, rhs, group_sizes, lhs.dtype, GMM_TILING,
+        return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling,
                    transpose_rhs=transpose_rhs, interpret=_pk.interpret())
     if transpose_rhs:
         rhs = rhs.swapaxes(1, 2)
@@ -115,18 +263,16 @@ def _grouped_dot_weights_grad(acc, lhs, d_out, group_sizes):
     summed in f32 into what is there (megablox ``tgmm`` adds to
     ``existing_out`` and aliases it).  With ``acc`` None (the only chunk of
     a loop that needs none) the sum alone, rounded once to ``lhs``'s type."""
-    if _kernels(lhs.shape[0]):
+    tiling = _tiling("tgmm", lhs, lhs.shape[1], d_out.shape[1],
+                     group_sizes.shape[0], acc is not None)
+    if tiling:
         from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
 
         if acc is None:
             return tgmm(lhs.swapaxes(0, 1), d_out, group_sizes, lhs.dtype,
-                        GMM_TILING, interpret=_pk.interpret())
-        # an f32 block that is read and written takes four times a bf16
-        # result's VMEM: half the n tile
-        tm, tk, tn = GMM_TILING
+                        tiling, interpret=_pk.interpret())
         return tgmm(lhs.swapaxes(0, 1), d_out, group_sizes, jnp.float32,
-                    (tm, tk, tn // 2), existing_out=acc,
-                    interpret=_pk.interpret())
+                    tiling, existing_out=acc, interpret=_pk.interpret())
     grad = jax.lax.ragged_dot_general(
         lhs, d_out, group_sizes, _ROWS_CONTRACTED,
         preferred_element_type=jnp.float32)
@@ -140,7 +286,7 @@ def _add_rows(acc, rows, token, scale, here, n_live, fresh):
     On the chip one Mosaic call that writes every row of ``acc`` once and
     does not read a fresh one (``ops/pallas/moe_rows.py``); elsewhere a
     scatter-add into ``acc``."""
-    if _kernels(rows.shape[0]) and moe_rows.fits(
+    if _kernels(rows.shape[0], moe_rows.SLAB) and moe_rows.fits(
             acc.shape[0], *rows.shape, here.shape[0], rows.dtype.itemsize):
         return moe_rows.combine(acc, rows, token, scale, here, n_live, fresh,
                                 interpret=_pk.interpret())
@@ -170,6 +316,16 @@ def _chunk_index(i, order, flat_w, starts, ends, k, chunk):
 
 
 ACTIVATIONS = {"relu2": 2, "swiglu": 3}     # matrices an expert has
+
+
+def _row_tile(rows, d, f, groups, itemsize, carry):
+    """The rows a chunk of about ``rows`` is rounded up to whole multiples
+    of: the largest row tile of its grouped products, forward (d -> f, f ->
+    d) and backward (the same two shapes for the rows' gradients, both
+    matrices' for the weights')."""
+    return max(grouped_tiling(kind, rows, k, n, groups, itemsize, c)[0]
+               for k, n in ((d, f), (f, d))
+               for kind, c in (("gmm", False), ("tgmm", carry)))
 
 
 def _activate(pre, activation):
@@ -318,9 +474,11 @@ def moe_experts(data, experts, weights, up_weight, down_weight,
         gate_weight, up_weight, down_weight)
     tokens, k = experts.shape
     count = up_weight.shape[0]
-    tm = GMM_TILING[0]
     worst = tokens * min(k, count)
-    chunk = -(-min(tokens, worst) // tm) * tm    # as many rows as tokens
+    rows = min(tokens, worst)                    # as many rows as tokens
+    tm = _row_tile(rows, *up_weight.shape[1:], count, data.dtype.itemsize,
+                   carry=worst > rows)
+    chunk = -(-rows // tm) * tm
     n_chunks = -(-worst // chunk)
     local = experts - int(first)
     held = (local >= 0) & (local < count)

@@ -450,6 +450,10 @@ class DataParallelStep:
         self._aux_reading_kinds = {
             n: p.telemetry for n, p in self._param_items
             if getattr(p, "telemetry", None)}
+        # what a marked leaf's block derives from the same reading
+        self._aux_readers = {
+            n: p.on_reading for n, p in self._param_items
+            if n in self._aux_reading_kinds and hasattr(p, "on_reading")}
 
         if optimizer not in ("sgd", "adam"):
             raise MXNetError(f"fused step supports sgd/adam, got {optimizer}")
@@ -1179,8 +1183,11 @@ class DataParallelStep:
         values = jax.device_get(
             {n: self.params[n] for n in self._aux_reading_kinds})
         for name, v in values.items():
+            reading = [float(x) for x in v]
             telemetry.record_aux_reading(self._aux_reading_kinds[name], name,
-                                         [float(x) for x in v])
+                                         reading)
+            if name in self._aux_readers:
+                self._aux_readers[name](name, reading)
 
     @property
     def inflight_depth(self) -> int:

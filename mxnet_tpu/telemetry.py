@@ -227,6 +227,12 @@ class _State:
         # what the newest traced gradient's recomputed layers keep from
         # their forward pass (record_recompute_kept; kept like moe_load)
         self.recompute_kept: Dict[str, int] = {}
+        # (kind, m, k, n, groups, carry) -> {"tiling", "sites"}: the tiles
+        # the traced grouped products took (record_grouped_tiles), and expert
+        # layer -> rows run over rows had in the last step (record_rows_run;
+        # both kept like moe_load)
+        self.grouped_tiles: Dict[tuple, Dict[str, Any]] = {}
+        self.rows_run: Dict[str, float] = {}
         # executor -> the scope of each instruction it compiled, or the
         # function that makes it (record_scope_map; kept like moe_load)
         self.scope_maps: Dict[str, Any] = {}
@@ -782,6 +788,50 @@ def record_recompute_kept(layers: int, tensors: int, nbytes: int) -> None:
     record("recompute_kept", **kept)
 
 
+def record_grouped_tiles(kind: str, m: int, k: int, n: int, groups: int,
+                         carry: bool, tiling) -> None:
+    """The (tm, tk, tn) one grouped product of ``_contrib_moe_experts`` was
+    traced at on the chip (``ops/moe_ops.py`` ``grouped_tiling``): ``kind``
+    ``gmm`` (rows (m, k) times (groups, k, n)) or ``tgmm`` (the matrices'
+    gradient (groups, k, n) with m contracted, added to an f32 ``carry`` or
+    not).  Called where a trace reaches the product, never inside a step, so
+    ``sites`` counts traces: a recomputed layer's forward is traced for its
+    backward pass too.  Kept in memory (``summary()["grouped_tiles"]``)
+    whether or not the recorder is enabled."""
+    key = (kind, int(m), int(k), int(n), int(groups), bool(carry))
+    tiling = [int(t) for t in tiling]
+    with _state.lock:
+        row = _state.grouped_tiles.setdefault(key, {"sites": 0})
+        row["tiling"] = tiling
+        row["sites"] += 1
+    record("grouped_tiles", product=kind, m=m, k=k, n=n, groups=groups,
+           carry=bool(carry), tiling=tiling)
+
+
+def grouped_tiles() -> List[Dict[str, Any]]:
+    """What ``record_grouped_tiles`` was told, a row a distinct product:
+    ``{"kind", "m", "k", "n", "groups", "carry", "tiling", "sites"}``."""
+    names = ("kind", "m", "k", "n", "groups", "carry")
+    with _state.lock:
+        return [dict(zip(names, key), tiling=list(row["tiling"]),
+                     sites=row["sites"])
+                for key, row in _state.grouped_tiles.items()]
+
+
+def record_rows_run(name: str, ratio: float) -> None:
+    """``rows_run_over_rows`` of the expert layer whose load counter is the
+    aux leaf ``name``: the row tiles its grouped products visited in the
+    last step, times their ``tm``, over the rows they had (1 is the least; a
+    row tile that two experts share runs once for each).
+    ``DataParallelStep.drain`` has the layer work it out from the reading it
+    hands ``record_moe_load``; nothing inside a step.  Kept in memory
+    (``summary()["grouped_tiles"]["rows_run_over_rows"]``) whether or not
+    the recorder is enabled."""
+    with _state.lock:
+        _state.rows_run[name] = float(ratio)
+    record("rows_run_over_rows", name=name, value=float(ratio))
+
+
 def record_fused_update(n_params: int, n_buckets: int, nbytes: int,
                         n_jitted_calls: int, **fields) -> None:
     """One fused optimizer step (docs/PERFORMANCE.md): how many params
@@ -1246,6 +1296,8 @@ def summary() -> dict:
             "aux_readings": {kind: {k: list(v) for k, v in rows.items()}
                              for kind, rows in _state.aux_readings.items()},
             "recompute_kept": dict(_state.recompute_kept),
+            "grouped_tiles": {"tilings": grouped_tiles(),
+                              "rows_run_over_rows": dict(_state.rows_run)},
             "serving": _serving_rollup(),
             "spans": {
                 name: {"count": agg["count"],

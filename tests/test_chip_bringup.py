@@ -571,7 +571,7 @@ def test_kernels_are_not_selected_where_gspmd_partitions():
         # the experts' chunk loop: ragged_dot and a scatter-add there
         from mxnet_tpu.ops import moe_ops
 
-        assert not moe_ops._kernels(16384)
+        assert not moe_ops._kernels(16384, 128)
         assert _mosaic_calls(
             jax.grad(lambda x, idx, w, up, down: moe_ops.moe_experts(
                 x, idx, w, up, down)[0].astype(jnp.float32).sum(),

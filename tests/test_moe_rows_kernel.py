@@ -149,9 +149,9 @@ def _value_and_grads(p, chosen, kernels, monkeypatch):
     """The held experts' sum and its four gradients with every token's
     choices pinned to ``chosen``; ``kernels`` picks the back end."""
     tiling = (8, 128, 256)
-    monkeypatch.setattr(moe_ops, "GMM_TILING", tiling)
+    monkeypatch.setattr(moe_ops, "grouped_tiling", lambda *a, **k: tiling)
     monkeypatch.setattr(moe_ops, "_kernels",
-                        lambda rows: kernels and rows % tiling[0] == 0)
+                        lambda rows, tm: kernels and rows % tm == 0)
     combines, combine = [], moe_rows.combine
     monkeypatch.setattr(moe_rows, "combine",
                         lambda *a, **k: combines.append(1) or combine(*a, **k))
@@ -211,14 +211,15 @@ def test_the_kernel_form_in_bf16_is_as_near_f32_as_the_xla_form(monkeypatch,
 
 def test_rows_that_are_no_multiple_of_the_tile_take_the_xla_form(monkeypatch):
     with pallas.compute_on("tpu"):
-        assert moe_ops._kernels(16384) and not moe_ops._kernels(16400)
+        assert moe_ops._kernels(16384, 256)
+        assert not moe_ops._kernels(16400, 256)
     with pallas.compute_on("tpu", partitioned=True):
-        assert not moe_ops._kernels(16384)
-    assert not moe_ops._kernels(16384)                    # the CPU
-    # the products' tile is 512 rows: 24 tokens make a chunk of 512 sorted
-    # rows for the grouped products, and their combine falls to the scatter
-    # where the width is no whole lane tile
-    monkeypatch.setattr(moe_ops, "_kernels", lambda rows: True)
+        assert not moe_ops._kernels(16384, 256)
+    assert not moe_ops._kernels(16384, 256)               # the CPU
+    # where the products' tile is 512 rows, 24 tokens make a chunk of 512
+    # sorted rows for the grouped products, and their combine falls to the
+    # scatter where the width is no whole lane tile
+    monkeypatch.setattr(moe_ops, "_kernels", lambda rows, tm: True)
     called = []
     monkeypatch.setattr(moe_rows, "combine",
                         lambda *a, **k: called.append(a) or a[0])

@@ -187,7 +187,8 @@ def test_every_token_sent_to_held_experts_loses_no_pair(monkeypatch, chosen,
     (as many rows as tokens) carries a pair a token and two are skipped; with
     all three held the load is three times the tokens, the worst case, and
     all three chunks run."""
-    monkeypatch.setattr(moe_ops, "GMM_TILING", (8, 128, 128))
+    monkeypatch.setattr(moe_ops, "grouped_tiling",
+                        lambda *a, **k: (8, 128, 128))
     wide, tokens, d, f = 16, 24, 16, 12
     w = _expert_weights(wide, seed=5)
     bias = jnp.full((wide,), -5.0).at[jnp.array(chosen)].set(5.0)

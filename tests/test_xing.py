@@ -293,7 +293,8 @@ def test_gated_experts_at_four_choices_run_more_than_one_chunk(monkeypatch):
     """Four choices a token over 6 experts, 3 of them held: the held ones
     draw 43 pairs of 24 tokens' 96, two chunks of 24 rows; forward and every
     gradient against a loop over the experts."""
-    monkeypatch.setattr(moe_ops, "GMM_TILING", (8, 128, 128))
+    monkeypatch.setattr(moe_ops, "grouped_tiling",
+                        lambda *a, **k: (8, 128, 128))
     tokens, d, f, count, first, k = 24, 16, 12, 3, 2, 4
     u = _rand(20, (tokens, d))
     mats = (_rand(21, (count, d, f), 0.3), _rand(22, (count, d, f), 0.3),

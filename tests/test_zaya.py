@@ -146,7 +146,8 @@ def test_gated_experts_match_a_dense_loop_with_all_gradients(monkeypatch,
                                                              load):
     """Top-1 over 4 held experts: an even spread, and every token on one
     expert (as many rows as tokens on one group: no pair dropped)."""
-    monkeypatch.setattr(moe_ops, "GMM_TILING", (8, 128, 128))
+    monkeypatch.setattr(moe_ops, "grouped_tiling",
+                        lambda *a, **k: (8, 128, 128))
     tokens, count = 24, 4
     u, w = _rand(7, (tokens, 16)), _gated_weights(count)
     experts = (jnp.arange(tokens) % count if load == "even"
@@ -262,7 +263,8 @@ def test_relu2_experts_are_unchanged_to_the_bit(monkeypatch):
     """One seeded case, three choices a token over 4 of 16 experts: the op
     with its ``activation`` argument against the op as it was, forward and
     every gradient, bit for bit."""
-    monkeypatch.setattr(moe_ops, "GMM_TILING", (8, 128, 128))
+    monkeypatch.setattr(moe_ops, "grouped_tiling",
+                        lambda *a, **k: (8, 128, 128))
     tokens, d, f, count, k = 24, 16, 12, 4, 3
     u, up, down = (_rand(20, (tokens, d)), _rand(21, (count, d, f), 0.3),
                    _rand(22, (count, f, d), 0.3))
