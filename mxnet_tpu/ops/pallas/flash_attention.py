@@ -28,10 +28,16 @@ clean copy of one sequence side by side, ``[x_t | x_0]``, 2L positions.
 Position ``i`` has stream ``s = i >= L``, position ``p = i - s L`` and block
 ``j = p // b``; a noisy query sees its own block of the noisy copy and the
 clean copy's earlier blocks, a clean query the clean copy's blocks up to its
-own (``bd_allowed``).  The predicate is computed in the kernel from
-positions; for a query tile the key tiles it leaves whole run unmasked, those
-it empties are not visited (``_k_segments``, ``_q_segments``).  Rows past 2L
-(padding) count as clean queries of later blocks.
+own (``bd_allowed``).  For a query tile the key tiles it leaves whole run
+unmasked, those it empties are not visited (``_k_segments``,
+``_q_segments``).  Where the copies meet on a tile boundary (L a multiple
+of both tiles) a masked run holds one copy, and a tile's mask compares its
+row index with one threshold a column, made from the column's block index
+(``_bd_mask``, ``_bd_mask_t``): one compare a score, two for a noisy block's
+range.  Otherwise a tile may hold both copies or padding and takes the
+general form, a vector of its rows' block indices against one of its
+columns' (``_bd_general``).  Rows past 2L (padding) count as clean queries
+of later blocks.
 """
 from __future__ import annotations
 
@@ -97,21 +103,94 @@ def check_block_diffusion(lq, lk, block, causal):
             f"the block a power of two, and no causal mask")
 
 
-def _pair_allowed(cfg: _Cfg, qpos, kpos):
-    if cfg.bd_block:
-        return bd_allowed(cfg.q_len // 2, cfg.bd_block, qpos, kpos)
-    return qpos >= kpos
-
-
 def _kv_mask(cfg: _Cfg, qi, kj, bq, bk):
-    """Validity mask for a (bq, bk) score tile at q block qi / k block kj."""
+    """Validity mask for a (bq, bk) score tile at q block qi / k block kj
+    (full or causal)."""
     kpos = kj * cfg.block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     mask = kpos < cfg.kv_len
-    if cfg.causal or cfg.bd_block:
+    if cfg.causal:
         qpos = qi * cfg.block_q + jax.lax.broadcasted_iota(
             jnp.int32, (bq, bk), 0)
-        mask = jnp.logical_and(mask, _pair_allowed(cfg, qpos, kpos))
+        mask = jnp.logical_and(mask, qpos >= kpos)
     return mask
+
+
+# A masked run of the block-diffusion tiles is "noisy" or "clean" when all
+# its keys (in the dkv kernel: its query rows) are of that copy, True when
+# its tiles may hold both copies or padding.
+def _bd_aligned(cfg: _Cfg):
+    half = cfg.q_len // 2
+    return half % cfg.block_q == 0 and half % cfg.block_k == 0
+
+
+def _bd_rows(cfg: _Cfg, qpos):
+    """(bound, noisy) of query positions: a clean key of block j is allowed
+    where ``j < bound``, a noisy key where ``j == noisy`` (-1 for a clean
+    row, which no noisy key matches)."""
+    half, shift = cfg.q_len // 2, cfg.bd_block.bit_length() - 1
+    sq = qpos >= half
+    jq = jnp.right_shift(qpos - jnp.where(sq, half, 0), shift)
+    return jq + jnp.where(sq, 1, 0), jnp.where(sq, -1, jq)
+
+
+def _bd_general(cfg: _Cfg, qpos, kpos):
+    """The mask from a vector of query positions and one of key positions
+    (broadcast against each other), exact for any tile: both compares, with
+    sentinels that fail them for the other copy's keys and for padding."""
+    half, shift = cfg.q_len // 2, cfg.bd_block.bit_length() - 1
+    bound, noisy = _bd_rows(cfg, qpos)
+    sk = kpos >= half
+    jk = jnp.right_shift(kpos - jnp.where(sk, half, 0), shift)
+    clean = jnp.where(jnp.logical_and(sk, kpos < cfg.kv_len), jk, 1 << 30)
+    return jnp.logical_or(clean < bound, jnp.where(sk, -2, jk) == noisy)
+
+
+def _bd_mask(cfg: _Cfg, qi, kj, copy):
+    """The block-diffusion mask of the forward's and dq's (bq, bk) tile at
+    query block qi, key block kj.  For a run of one key copy (the query tile
+    is then of one copy too) the row index r is compared with a threshold a
+    key: clean keys ``r >= first`` (``j_k < j_q + s_q``), noisy keys ``first
+    <= r < first + b`` (a noisy row of the key's block)."""
+    bq, bk = cfg.block_q, cfg.block_k
+    kpos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    if copy is True:
+        return _bd_general(cfg, qi * bq + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, 1), 0), kpos)
+    half, b = cfg.q_len // 2, cfg.bd_block
+    shift = b.bit_length() - 1
+    sq = (qi * bq >= half).astype(jnp.int32)
+    p0 = qi * bq - sq * half            # the tile's first row in its copy
+    r = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    if copy == "clean":
+        jk = jnp.right_shift(kpos - half, shift)
+        return r >= jnp.left_shift(jk - sq + 1, shift) - p0
+    first = jnp.left_shift(jnp.right_shift(kpos, shift), shift) - p0
+    return jnp.logical_and(r >= jnp.maximum(first, sq * bq), r < first + b)
+
+
+def _bd_mask_t(cfg: _Cfg, kj, qi, copy):
+    """The same for the dkv kernel's transposed (bk, bq) tile; a run names
+    its ROWS' copy, and the key row index r is compared with a threshold a
+    query.  Clean rows see clean keys only: ``r < (bound << shift) - (k0 -
+    half)``; noisy rows take the key block's copy, a noisy block the rows of
+    its block, a clean one the rows of later blocks."""
+    bq, bk = cfg.block_q, cfg.block_k
+    qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+    if copy is True:
+        return _bd_general(cfg, qpos, kj * bk + jax.lax.broadcasted_iota(
+            jnp.int32, (bk, 1), 0))
+    half, b = cfg.q_len // 2, cfg.bd_block
+    shift = b.bit_length() - 1
+    k0 = kj * bk
+    bound, noisy = _bd_rows(cfg, qpos)
+    r = jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+    clean_end = jnp.left_shift(bound, shift) - (k0 - half)
+    if copy == "clean":
+        return r < clean_end
+    nk = (k0 < half).astype(jnp.int32)  # the key block is noisy
+    first = nk * (jnp.left_shift(noisy, shift) - k0)
+    end = clean_end + nk * (first + b - clean_end)
+    return jnp.logical_and(r >= first, r < end)
 
 
 def _k_blocks(cfg: _Cfg, qi, nkb):
@@ -139,9 +218,12 @@ def _segments(reach, whole, blk, xp, start=0):
     return [(a, w0, True), (w0, w1, False), (w1, c, True)], c
 
 
-def _bd_runs(noisy, noisy_whole, clean, clean_whole, blk, xp):
+def _bd_runs(noisy, noisy_whole, clean, clean_whole, blk, xp, aligned):
     runs, end = _segments(noisy, noisy_whole, blk, xp)
     more, _ = _segments(clean, clean_whole, blk, xp, start=end)
+    if aligned:  # no tile holds both copies: a run's copy is known here
+        runs = [(a, c, m and "noisy") for a, c, m in runs]
+        more = [(a, c, m and "clean") for a, c, m in more]
     return runs + more
 
 
@@ -149,7 +231,8 @@ def _k_segments(cfg: _Cfg, qi, nkb, xp=jnp):
     """(first, last + 1, masked) runs of the key blocks query block qi
     visits: ``_k_blocks``' two for a full or causal mask; under block
     diffusion the noisy copy's blocks its rows share, then the clean copy's
-    earlier blocks, each split into masked and whole runs."""
+    earlier blocks, each split into masked and whole runs (a masked run
+    names its keys' copy where the copies meet on a tile boundary)."""
     if not cfg.bd_block:
         whole, reached = _k_blocks(cfg, qi, nkb)
         return [(0, whole, False), (whole, reached, True)]
@@ -172,14 +255,15 @@ def _k_segments(cfg: _Cfg, qi, nkb, xp=jnp):
     width = xp.minimum(xp.where(has_n, jlo * b, half), xp.where(
         has_c, xp.minimum((jlo_c + 1) * b, half), half))
     return _bd_runs(noisy, noisy_whole, (half, half + reach),
-                    (half, half + width), cfg.block_k, xp)
+                    (half, half + width), cfg.block_k, xp, _bd_aligned(cfg))
 
 
 def _q_segments(cfg: _Cfg, kj, nqb, xp=jnp):
     """(first, last + 1, masked) runs of the query blocks that key block kj
     reaches under block diffusion (the dkv kernel's loops): the noisy rows
     that see its keys, then the clean rows; a key block that holds padding
-    or both copies is masked throughout."""
+    or both copies is masked throughout.  A masked run names its rows' copy
+    where the copies meet on a tile boundary."""
     bq, bk = cfg.block_q, cfg.block_k
     half, b = cfg.q_len // 2, cfg.bd_block
     k0 = kj * bk
@@ -208,27 +292,31 @@ def _q_segments(cfg: _Cfg, kj, nqb, xp=jnp):
                  xp.where(tail, half, 0)))
     clean_whole = (xp.where(tail, half + jhi_c * b, 0),
                    xp.where(tail, nqb * bq, 0))
-    return _bd_runs(noisy, noisy_whole, clean, clean_whole, bq, xp)
+    return _bd_runs(noisy, noisy_whole, clean, clean_whole, bq, xp,
+                    _bd_aligned(cfg))
 
 
 def bd_tiles(length, block, block_q=512, block_k=512):
     """How the kernels visit the tiles of a block-diffusion stream of
-    ``length`` (2L) positions: {"visited", "whole", "masked", "square"} of
-    the forward's and ``dq``'s (query block, key block) tiles, counted from
-    the same runs the kernels loop over (numpy, no trace)."""
+    ``length`` (2L) positions: {"visited", "whole", "masked", "square",
+    "per_copy", "general"} of the forward's and ``dq``'s (query block, key
+    block) tiles, counted from the same runs the kernels loop over (numpy,
+    no trace); ``masked`` = ``per_copy`` (keys of one copy: one compare a
+    score) + ``general`` (both compares)."""
     import numpy as np
 
     bq, bk = _pick_block(length, block_q), _pick_block(length, block_k)
     lp_q, lp_k = _round_up(length, bq), _round_up(length, bk)
     cfg = _Cfg(False, 1.0, bq, bk, length, length, True, 1, block)
     nqb, nkb = lp_q // bq, lp_k // bk
-    whole = masked = 0
+    n = {False: 0, True: 0, "noisy": 0, "clean": 0}
     for qi in range(nqb):
         for lo, hi, m in _k_segments(cfg, np.int64(qi), nkb, xp=np):
-            n = max(int(hi) - int(lo), 0)
-            masked, whole = (masked + n, whole) if m else (masked, whole + n)
-    return {"visited": whole + masked, "whole": whole, "masked": masked,
-            "square": nqb * nkb}
+            n[m] += max(int(hi) - int(lo), 0)
+    per_copy = n["noisy"] + n["clean"]
+    return {"visited": sum(n.values()), "whole": n[False],
+            "masked": per_copy + n[True], "square": nqb * nkb,
+            "per_copy": per_copy, "general": n[True]}
 
 
 # ---------------------------------------------------------------------------
@@ -252,7 +340,9 @@ def _fwd_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, o_ref, lse_ref):
                                 preferred_element_type=jnp.float32)
         s = s * cfg.sm_scale
         if masked:
-            s = jnp.where(_kv_mask(cfg, qi, kj, bq, bk), s, _NEG)
+            mask = (_bd_mask(cfg, qi, kj, masked) if cfg.bd_block
+                    else _kv_mask(cfg, qi, kj, bq, bk))
+            s = jnp.where(mask, s, _NEG)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -318,7 +408,9 @@ def _dq_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                 preferred_element_type=jnp.float32)
         s = s * cfg.sm_scale
         if masked:
-            s = jnp.where(_kv_mask(cfg, qi, kj, bq, bk), s, _NEG)
+            mask = (_bd_mask(cfg, qi, kj, masked) if cfg.bd_block
+                    else _kv_mask(cfg, qi, kj, bq, bk))
+            s = jnp.where(mask, s, _NEG)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -361,13 +453,15 @@ def _dkv_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         st = st * cfg.sm_scale
-        if masked:
+        if masked and cfg.bd_block:
+            st = jnp.where(_bd_mask_t(cfg, kj, qi, masked), st, _NEG)
+        elif masked:
             kpos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
             mask = kpos < cfg.kv_len
-            if cfg.causal or cfg.bd_block:
+            if cfg.causal:
                 qpos = qi * bq + jax.lax.broadcasted_iota(
                     jnp.int32, (bk, bq), 1)
-                mask = jnp.logical_and(mask, _pair_allowed(cfg, qpos, kpos))
+                mask = jnp.logical_and(mask, qpos >= kpos)
             st = jnp.where(mask, st, _NEG)
         pt = jnp.exp(st - lse)
         dv = dv + jax.lax.dot(pt.astype(do.dtype), do,

@@ -827,13 +827,15 @@ def record_bd_tiles(length: int, block: int, block_q: int, block_k: int,
     """The (query block, key block) tiles one flash call under the
     block-diffusion mask visits over a stream of ``length`` positions
     (``ops/pallas/flash_attention.py`` ``bd_tiles``): ``visited`` = ``whole``
-    (run unmasked) + ``masked``, out of the ``square``'s.  Called where a
+    (run unmasked) + ``masked``, out of the ``square``'s; ``masked`` =
+    ``per_copy`` (keys of one copy: one compare a score) + ``general``
+    (tiles that may hold both copies or padding: two).  Called where a
     trace reaches the call, never inside a step, so ``sites`` counts traces.
     Kept in memory (``summary()["bd_tiles"]``) whether or not the recorder
     is enabled."""
     key = (int(length), int(block), int(block_q), int(block_k))
     counts = {k: int(counts[k]) for k in ("visited", "whole", "masked",
-                                          "square")}
+                                          "square", "per_copy", "general")}
     with _state.lock:
         row = _state.bd_tiles.setdefault(key, {"sites": 0})
         row.update(counts)
@@ -845,7 +847,7 @@ def record_bd_tiles(length: int, block: int, block_q: int, block_k: int,
 def bd_tiles() -> List[Dict[str, Any]]:
     """What ``record_bd_tiles`` was told, a row a distinct call shape:
     ``{"length", "block", "block_q", "block_k", "visited", "whole",
-    "masked", "square", "sites"}``."""
+    "masked", "square", "per_copy", "general", "sites"}``."""
     names = ("length", "block", "block_q", "block_k")
     with _state.lock:
         return [dict(zip(names, key), **row)

@@ -79,6 +79,9 @@ def _dense(q, k, v, block):
     (64, 16, 16, 2, 2),      # a block longer than a tile: whole noisy tiles
     (100, 4, 64, 2, 1),      # 2L = 200: not a multiple of the tile
     (48, 4, 32, 8, 1),       # grouped-query heads 8:1
+    (256, 4, 32, 4, 1),      # the cell's 16 x 16 tiles, scaled down
+    (96, 4, 64, 2, 1),       # L not a multiple of the tile: the general form
+    (100, 4, 48, 2, 2),      # padded, and L not a multiple of the tile
 ])
 def test_flash_under_the_block_diffusion_mask_forward_and_all_gradients(
         length, block, tile, heads, kv_heads):
@@ -156,8 +159,74 @@ def test_the_tiles_visited_are_those_the_mask_reaches(length, block, tile):
     assert by_key == counts["visited"]
     if (length, block, tile) == (8192, 4, 512):
         assert counts == {"visited": 80, "whole": 56, "masked": 24,
-                          "square": 256}
+                          "square": 256, "per_copy": 24, "general": 0}
         assert counts["whole"] == whole
+
+
+@pytest.mark.parametrize("length, block, tile", [
+    (8192, 4, 512),   # the cell: L a multiple of the tile
+    (512, 4, 32),     # its 16 x 16 tiles scaled down
+    (96, 16, 16),     # blocks of a tile: no tile is masked
+    (192, 16, 48),    # blocks that straddle tiles of 48
+    (192, 4, 64),     # L not a multiple of the tile: tiles hold both copies
+    (200, 4, 48)])    # and padding
+def test_each_visited_tile_masks_as_the_predicate(length, block, tile):
+    """For every (query tile, key tile) pair the kernels visit, the mask the
+    tile builds from its rows' and columns' indices (the run's own form and
+    the general one) is ``bd_allowed``; the forward's and dq's tiles, and the
+    dkv kernel's transposed ones.  Runs name a copy exactly where L is a
+    multiple of the tiles."""
+    n = -(-length // tile)
+    cfg = fa._Cfg(False, 1.0, tile, tile, length, length, True, 1, block)
+    aligned = (length // 2) % tile == 0
+    pos = jnp.arange(n * tile)
+    keep = np.asarray(fa.bd_allowed(length // 2, block, pos[:, None],
+                                    pos[None, :]) & (pos[None, :] < length))
+    forms = set()
+
+    def check(mask, want, copy):
+        forms.add(copy)
+        for form in {copy, True}:
+            np.testing.assert_array_equal(mask(form), want, err_msg=str(
+                (form, copy)))
+
+    for i in range(n):
+        for lo, hi, copy in fa._k_segments(cfg, np.int64(i), n, np):
+            for j in range(int(lo), int(hi)):
+                want = keep[i * tile:(i + 1) * tile, j * tile:(j + 1) * tile]
+                if not copy:
+                    assert want.all()
+                    continue
+                check(lambda f: fa._bd_mask(cfg, jnp.int32(i), jnp.int32(j),
+                                            f), want, copy)
+    for j in range(n):
+        for lo, hi, copy in fa._q_segments(cfg, np.int64(j), n, np):
+            for i in range(int(lo), int(hi)):
+                want = keep[i * tile:(i + 1) * tile, j * tile:(j + 1) * tile]
+                if not copy:
+                    assert want.all()
+                    continue
+                check(lambda f: fa._bd_mask_t(cfg, jnp.int32(j),
+                                              jnp.int32(i), f), want.T, copy)
+    if block < tile:
+        assert forms == ({"noisy", "clean"} if aligned else {True})
+    else:
+        assert not forms
+
+
+def test_the_cells_masked_tiles_all_take_the_per_copy_form():
+    """At L = 4,096, blocks of 4 and 512 x 512 tiles a traced call reports
+    its 24 masked tiles of 80 all of one key copy: one compare a score."""
+    telemetry.reset()
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: fa.flash_attention(
+        q, k, v, block_diffusion=4), q, kv, kv)
+    (row,) = telemetry.summary()["bd_tiles"]
+    assert (row["length"], row["block"], row["block_q"], row["block_k"]) == (
+        8192, 4, 512, 512)
+    assert (row["visited"], row["whole"], row["masked"], row["per_copy"],
+            row["general"]) == (80, 56, 24, 24, 0)
 
 
 def test_a_traced_call_tells_telemetry_its_tiles():
@@ -468,6 +537,26 @@ def test_the_block_diffusion_kernels_compile_at_the_cells_widths(one_chip):
     assert sorted(set(re.findall(r"mx_flash_(fwd|dq|dkv)", text))) == [
         "dkv", "dq", "fwd"]
     assert not re.search(r"\[\d*,?8192,8192\]", text)
+
+
+def test_the_general_form_compiles_for_the_chip(one_chip):
+    """2L = 1,200 over 512 x 512 tiles: tiles that hold both copies and
+    padding take the general form, which Mosaic must take too."""
+    q = jax.ShapeDtypeStruct((1, 4, 1200, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 2, 1200, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, block_diffusion=4).astype(
+            jnp.float32).sum()
+
+    assert fa.bd_tiles(1200, 4)["general"] > 0
+    with pk.compute_on("tpu"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile().as_text()
+    assert sorted(set(re.findall(r"mx_flash_(fwd|dq|dkv)", text))) == [
+        "dkv", "dq", "fwd"]
 
 
 @pytest.mark.parametrize("shape, rows", [
