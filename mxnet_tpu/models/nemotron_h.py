@@ -189,14 +189,19 @@ class GQAttention(HybridBlock):
     ``block_diffusion=b``: the input is a noisy and a clean copy of one
     sequence side by side (``2L`` positions), both copies take positions 0 ..
     L-1, and the mask is the block-diffusion mask of ``b``-position blocks
-    (``ops/pallas/flash_attention.py``) in place of the causal one."""
+    (``ops/pallas/flash_attention.py``) in place of the causal one;
+    ``sparse``: each query attends to the keys a selection chose for it
+    (``ops/dsa_ops.py``), handed as packed bits beside the input, and the
+    block returns ``[out, q, k, lse]``: the rotated heads and the rows'
+    logsumexp, for the loss that trains the selection."""
 
     def __init__(self, units, num_heads=32, num_kv_heads=2, head_dim=128,
                  qk_norm=False, eps=1e-6, rope_theta=None, block_diffusion=0,
-                 prefix=None, params=None):
+                 sparse=False, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         self._h, self._hkv, self._d = num_heads, num_kv_heads, head_dim
         self._theta, self._bd = rope_theta, int(block_diffusion)
+        self._sparse = bool(sparse)
 
         def dense(n, in_units, prefix):
             return nn.Dense(n, flatten=False, use_bias=False,
@@ -223,7 +228,7 @@ class GQAttention(HybridBlock):
         return F._contrib_rotary(copies, theta=self._theta,
                                  axis=2).reshape(t.shape)
 
-    def hybrid_forward(self, F, u):
+    def hybrid_forward(self, F, u, bits=None, bits_t=None):
         def heads(t, n, norm=None, rotate=False):
             t = t.reshape((0, 0, n, self._d))
             if norm is not None:
@@ -236,6 +241,14 @@ class GQAttention(HybridBlock):
         q = heads(self.q_proj(u), self._h, self.q_norm, rotate)
         k = heads(self.k_proj(u), self._hkv, self.k_norm, rotate)
         v = heads(self.v_proj(u), self._hkv)
+        if self._sparse:
+            from ..ops import dsa_ops
+
+            with jax.named_scope("mx_dsa_attention"):
+                o, lse = _reg.invoke_fn(dsa_ops.sparse_attention,
+                                        [q, k, v, bits, bits_t])
+            y = self.o_proj(o.transpose((0, 2, 1, 3)).reshape((0, 0, -1)))
+            return [y, q, k, lse]
         if self._bd:
             with jax.named_scope("mx_bd_attention"):
                 o = F._contrib_flash_attention(q, k, v,

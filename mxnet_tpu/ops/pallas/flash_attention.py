@@ -38,6 +38,15 @@ range.  Otherwise a tile may hold both copies or padding and takes the
 general form, a vector of its rows' block indices against one of its
 columns' (``_bd_general``).  Rows past 2L (padding) count as clean queries
 of later blocks.
+
+Selection (``sparse_flash_attention``): each query attends to the keys a
+selection outside the kernel chose for it (a learned indexer's top-k,
+``ops/dsa_ops.py``), one selection for all heads of a batch row, handed as
+packed bits.  The loops visit every causal tile and mask each by its bits
+(``_unpack``).  Words are laid out so that a tile's mask needs no lane
+shuffle: in ``bits`` (B, Lq/32, Lk) query row ``r = 256 a + 8 j + s`` of a
+key column is bit ``j`` of word row ``8 a + s``; ``bits_t`` (B, Lk/32, Lq) is
+the same of the transposed selection (key rows, for the dkv kernel).
 """
 from __future__ import annotations
 
@@ -48,6 +57,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 _LANES = 128  # TPU lane width: per-row stats (lse/delta) carry a trailing
@@ -65,6 +75,7 @@ class _Cfg(NamedTuple):
     interpret: bool
     group: int = 1  # query heads per key-value head
     bd_block: int = 0  # block length of a block-diffusion stream (0: none)
+    select: int = 0  # query heads a batch row under a selection (0: none)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -101,6 +112,50 @@ def check_block_diffusion(lq, lk, block, causal):
             f"block_diffusion={block}: needs one stream of 2L positions "
             f"(got {lq} queries over {lk} keys), L a multiple of the block, "
             f"the block a power of two, and no causal mask")
+
+
+#: rows a group of packed words covers: 8 sublanes of 32 bits
+SELECT_GROUP = 256
+#: scoped VMEM of a call under a selection: K and V of a whole row (4 MB each
+#: at 16,384 positions) and a row of words, each double-buffered
+_SELECT_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 2 ** 20)
+
+
+def select_block(length):
+    """The tile of a call under a selection: 512, or 256 for a short one
+    (a tile's rows are whole groups of packed words)."""
+    return 512 if length >= 512 else SELECT_GROUP
+
+
+def check_selection(q_shape, k_shape, bits, bits_t):
+    """Refuse a call under a selection whose bits do not describe it:
+    self-attention of (B, H, L, D) queries over (B, Hkv, L, D) keys, and
+    int32 words ``bits`` (B, Lp/32, Lp), ``bits_t`` the same, Lp the length
+    padded to the tile (``select_block``)."""
+    b, _h, lq = q_shape[:3]
+    lk = k_shape[2]
+    lp = _round_up(lq, select_block(lq))
+    want = (b, lp // 32, lp)
+    for name, x in (("bits", bits), ("bits_t", bits_t)):
+        if tuple(x.shape) != want or x.dtype != jnp.int32:
+            raise ValueError(
+                f"selection: {name} {tuple(x.shape)} {x.dtype}, want int32 "
+                f"{want} for {lq} queries")
+    if lk != lq or len(q_shape) != 4 or k_shape[0] != b:
+        raise ValueError(f"selection: self-attention of (B, H, L, D) only, "
+                         f"got queries {tuple(q_shape)}, keys {tuple(k_shape)}")
+
+
+def _unpack(words, rows):
+    """The (rows, C) bool mask of a tile from its (rows / 32, C) words: row
+    ``256 a + 8 j + s`` is bit ``j`` of word row ``8 a + s`` (sublane groups
+    repeated, one shift a score)."""
+    c = words.shape[-1]
+    g = rows // SELECT_GROUP
+    w = jnp.broadcast_to(words.reshape(g, 1, 8, c), (g, 32, 8, c))
+    shift = jnp.right_shift(
+        jax.lax.broadcasted_iota(jnp.int32, (rows, c), 0), 3) & 31
+    return (jnp.right_shift(w.reshape(rows, c), shift) & 1) != 0
 
 
 def _kv_mask(cfg: _Cfg, qi, kj, bq, bk):
@@ -233,6 +288,8 @@ def _k_segments(cfg: _Cfg, qi, nkb, xp=jnp):
     diffusion the noisy copy's blocks its rows share, then the clean copy's
     earlier blocks, each split into masked and whole runs (a masked run
     names its keys' copy where the copies meet on a tile boundary)."""
+    if cfg.select:
+        return [(0, _k_blocks(cfg, qi, nkb)[1], "select")]
     if not cfg.bd_block:
         whole, reached = _k_blocks(cfg, qi, nkb)
         return [(0, whole, False), (whole, reached, True)]
@@ -322,7 +379,8 @@ def bd_tiles(length, block, block_q=512, block_k=512):
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, o_ref, lse_ref):
+def _fwd_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, *refs):
+    bits_ref, (o_ref, lse_ref) = _split_bits(cfg, refs)
     qi = pl.program_id(1)
     bq, bk = cfg.block_q, cfg.block_k
     q = q_ref[0]                                             # (bq, D)
@@ -339,7 +397,10 @@ def _fwd_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, o_ref, lse_ref):
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * cfg.sm_scale
-        if masked:
+        if masked == "select":
+            s = jnp.where(_unpack(bits_ref[0, :, pl.ds(kj * bk, bk)], bq), s,
+                          _NEG)
+        elif masked:
             mask = (_bd_mask(cfg, qi, kj, masked) if cfg.bd_block
                     else _kv_mask(cfg, qi, kj, bq, bk))
             s = jnp.where(mask, s, _NEG)
@@ -361,10 +422,27 @@ def _fwd_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, o_ref, lse_ref):
     lse_ref[0] = jnp.broadcast_to(m + jnp.log(safe_l), (bq, _LANES))
 
 
-def _fwd(cfg: _Cfg, q, k, v):
+def _split_bits(cfg: _Cfg, refs):
+    """(the words' ref or None, the other refs) of a kernel's trailing
+    refs: under a selection the words come first."""
+    return (refs[0], refs[1:]) if cfg.select else (None, refs)
+
+
+def _select_extras(cfg: _Cfg, words, rows_blk, index_map):
+    """(in_specs, operands, pallas_call keywords) a kernel adds under a
+    selection: the words of its rows' block, every column."""
+    if not cfg.select:
+        return [], [], {}
+    spec = pl.BlockSpec((1, rows_blk // 32, words.shape[-1]), index_map)
+    return [spec], [words], {"compiler_params": _SELECT_PARAMS}
+
+
+def _fwd(cfg: _Cfg, q, k, v, bits=None):
     n, lq, d = q.shape
     lk, dv = k.shape[1], v.shape[-1]
     nqb = lq // cfg.block_q
+    specs, extra, kw = _select_extras(
+        cfg, bits, cfg.block_q, lambda b, i: (b // max(cfg.select, 1), i, 0))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, cfg),
         name="mx_flash_fwd",
@@ -373,7 +451,7 @@ def _fwd(cfg: _Cfg, q, k, v):
             pl.BlockSpec((1, cfg.block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, lk, d), lambda b, i: (b // cfg.group, 0, 0)),
             pl.BlockSpec((1, lk, dv), lambda b, i: (b // cfg.group, 0, 0)),
-        ],
+        ] + specs,
         out_specs=[
             pl.BlockSpec((1, cfg.block_q, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, cfg.block_q, _LANES), lambda b, i: (b, i, 0)),
@@ -382,8 +460,8 @@ def _fwd(cfg: _Cfg, q, k, v):
             jax.ShapeDtypeStruct((n, lq, dv), q.dtype),
             jax.ShapeDtypeStruct((n, lq, _LANES), jnp.float32),
         ],
-        interpret=cfg.interpret,
-    )(q, k, v)
+        interpret=cfg.interpret, **kw,
+    )(q, k, v, *extra)
     return out, lse[..., 0]
 
 
@@ -391,7 +469,8 @@ def _fwd(cfg: _Cfg, q, k, v):
 # backward: dq kernel (parallel over q blocks), dkv kernel (over k blocks)
 # ---------------------------------------------------------------------------
 def _dq_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref):
+               *refs):
+    bits_ref, (dq_ref,) = _split_bits(cfg, refs)
     qi = pl.program_id(1)
     bq, bk = cfg.block_q, cfg.block_k
     q = q_ref[0]
@@ -407,7 +486,10 @@ def _dq_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * cfg.sm_scale
-        if masked:
+        if masked == "select":
+            s = jnp.where(_unpack(bits_ref[0, :, pl.ds(kj * bk, bk)], bq), s,
+                          _NEG)
+        elif masked:
             mask = (_bd_mask(cfg, qi, kj, masked) if cfg.bd_block
                     else _kv_mask(cfg, qi, kj, bq, bk))
             s = jnp.where(mask, s, _NEG)
@@ -425,11 +507,12 @@ def _dq_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dkv_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref):
+                *refs):
     """One key block of one key-value head against one of its query heads
     (grid axis 2, innermost): dK/dV accumulate in the f32 output block,
     which stays resident while that axis runs.  Scores are held transposed,
     (bk, bq), so that the per-query statistics are rows of lanes."""
+    bits_ref, (dk_ref, dv_ref) = _split_bits(cfg, refs)
     kj, g = pl.program_id(1), pl.program_id(2)
     bq, bk = cfg.block_q, cfg.block_k
     k = k_ref[0]
@@ -453,7 +536,10 @@ def _dkv_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         st = st * cfg.sm_scale
-        if masked and cfg.bd_block:
+        if masked == "select":
+            st = jnp.where(_unpack(bits_ref[0, :, pl.ds(qi * bq, bq)], bk),
+                           st, _NEG)
+        elif masked and cfg.bd_block:
             st = jnp.where(_bd_mask_t(cfg, kj, qi, masked), st, _NEG)
         elif masked:
             kpos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
@@ -472,7 +558,9 @@ def _dkv_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk = dk + jax.lax.dot(dst, q, preferred_element_type=jnp.float32)
         return dk, dv
 
-    if cfg.bd_block:
+    if cfg.select:
+        runs = [((kj * bk) // bq, nqb, "select")]
+    elif cfg.bd_block:
         runs = _q_segments(cfg, kj, nqb)
     else:
         whole = jnp.clip(whole, lo, nqb)
@@ -494,7 +582,7 @@ def _dkv_kernel(cfg: _Cfg, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] += dv
 
 
-def _bwd_impl(cfg: _Cfg, q, k, v, out, lse, do):
+def _bwd_impl(cfg: _Cfg, q, k, v, out, lse, do, bits=None, bits_t=None):
     n, lq, d = q.shape
     nkv, lk, dv = k.shape[0], k.shape[1], v.shape[-1]
     grp = cfg.group
@@ -509,16 +597,18 @@ def _bwd_impl(cfg: _Cfg, q, k, v, out, lse, do):
         return pl.BlockSpec((1, lk, w), lambda b, i: (b // grp, 0, 0))
 
     stat_blk = pl.BlockSpec((1, cfg.block_q, _LANES), lambda b, i: (b, i, 0))
+    specs, extra, kw = _select_extras(
+        cfg, bits, cfg.block_q, lambda b, i: (b // max(cfg.select, 1), i, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, cfg),
         name="mx_flash_dq",
         grid=(n, lq // cfg.block_q),
         in_specs=[q_blk(d), kv_all(d), kv_all(dv), q_blk(dv), stat_blk,
-                  stat_blk],
+                  stat_blk] + specs,
         out_specs=q_blk(d),
         out_shape=jax.ShapeDtypeStruct((n, lq, d), q.dtype),
-        interpret=cfg.interpret,
-    )(q, k, v, do, lse3, delta3)
+        interpret=cfg.interpret, **kw,
+    )(q, k, v, do, lse3, delta3, *extra)
 
     # grid (key-value head, key block, query head of the group)
     def q_all(w):
@@ -528,19 +618,22 @@ def _bwd_impl(cfg: _Cfg, q, k, v, out, lse, do):
         return pl.BlockSpec((1, cfg.block_k, w), lambda b, j, g: (b, j, 0))
 
     stat_row = pl.BlockSpec((1, 1, lq), lambda b, j, g: (b * grp + g, 0, 0))
+    kv_rows = max(cfg.select // grp, 1)     # key-value heads a batch row
+    specs, extra, kw = _select_extras(
+        cfg, bits_t, cfg.block_k, lambda b, j, g: (b // kv_rows, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, cfg),
         name="mx_flash_dkv",
         grid=(nkv, lk // cfg.block_k, grp),
         in_specs=[q_all(d), kv_blk(d), kv_blk(dv), q_all(dv), stat_row,
-                  stat_row],
+                  stat_row] + specs,
         out_specs=[kv_blk(d), kv_blk(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((nkv, lk, d), jnp.float32),
             jax.ShapeDtypeStruct((nkv, lk, dv), jnp.float32),
         ],
-        interpret=cfg.interpret,
-    )(q, k, v, do, lse[:, None, :], delta[:, None, :])
+        interpret=cfg.interpret, **kw,
+    )(q, k, v, do, lse[:, None, :], delta[:, None, :], *extra)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -565,6 +658,65 @@ def _flash_bwd(cfg: _Cfg, res, do):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash_select(cfg: _Cfg, q, k, v, bits, bits_t):
+    return _fwd(cfg, q, k, v, bits)
+
+
+def _flash_select_fwd(cfg: _Cfg, q, k, v, bits, bits_t):
+    from ..recompute import kernel_out
+
+    out, lse = kernel_out(*_fwd(cfg, q, k, v, bits))
+    return (out, lse), (q, k, v, out, lse, bits, bits_t)
+
+
+def _flash_select_bwd(cfg: _Cfg, res, cts):
+    import numpy as np
+
+    q, k, v, out, lse, bits, bits_t = res
+    dq, dk, dv = _bwd_impl(cfg, q, k, v, out, lse, cts[0], bits, bits_t)
+    none = [np.zeros(x.shape, jax.dtypes.float0) for x in (bits, bits_t)]
+    return (dq, dk, dv, *none)
+
+
+_flash_select.defvjp(_flash_select_fwd, _flash_select_bwd)
+
+
+def sparse_flash_attention(q, k, v, bits, bits_t, sm_scale=None):
+    """Causal attention of each query over the keys a selection chose for it
+    (module docstring): q (B, H, L, D), k and v (B, Hkv, L, D) grouped-query
+    heads, ``bits`` / ``bits_t`` the packed selection of every batch row
+    (``check_selection``; ``ops/dsa_ops.py`` ``pack_selection`` makes
+    them).  Returns (out (B, H, L, Dv), lse (B, H, L) f32); differentiable in
+    q, k and v, the row logsumexp is not."""
+    check_selection(q.shape, k.shape, bits, bits_t)
+    b, h, lq, d = q.shape
+    hkv = k.shape[1]
+    if h % hkv or v.shape[1] != hkv:
+        raise ValueError(f"selection: {h} query heads over {hkv} key and "
+                         f"{v.shape[1]} value heads")
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    from . import interpret
+    from ... import telemetry
+
+    blk = select_block(lq)
+    lp = _round_up(lq, blk)
+    n = lp // blk
+    telemetry.record_dsa_tiles(lq, blk, {"visited": n * (n + 1) // 2,
+                                         "square": n * n})
+    cfg = _Cfg(True, float(sm_scale), blk, blk, lq, lq, interpret(),
+               h // hkv, 0, h)
+
+    def pad(x):
+        x = x.reshape(-1, *x.shape[2:])
+        return jnp.pad(x, ((0, 0), (0, lp - lq), (0, 0)))
+
+    out, lse = _flash_select(cfg, pad(q), pad(k), pad(v), bits, bits_t)
+    return (out[:, :lq].reshape(b, h, lq, v.shape[-1]),
+            lse[:, :lq].reshape(b, h, lq))
 
 
 def flash_attention(q, k, v, causal: bool = False,

@@ -237,6 +237,9 @@ class _State:
         # "masked", "square", "sites"}: the tiles the traced block-diffusion
         # flash calls visit (record_bd_tiles; kept like moe_load)
         self.bd_tiles: Dict[tuple, Dict[str, int]] = {}
+        # (length, block) -> {"visited", "square", "sites"}: the tiles the
+        # traced flash calls under a selection visit (record_dsa_tiles)
+        self.dsa_tiles: Dict[tuple, Dict[str, int]] = {}
         # executor -> the scope of each instruction it compiled, or the
         # function that makes it (record_scope_map; kept like moe_load)
         self.scope_maps: Dict[str, Any] = {}
@@ -854,6 +857,31 @@ def bd_tiles() -> List[Dict[str, Any]]:
                 for key, row in _state.bd_tiles.items()]
 
 
+def record_dsa_tiles(length: int, block: int,
+                     counts: Dict[str, int]) -> None:
+    """The (query block, key block) tiles one flash call under a selection
+    (``ops/pallas/flash_attention.py`` ``sparse_flash_attention``) visits
+    over ``length`` positions in tiles of ``block``: ``visited`` (every
+    causal tile) of the ``square``'s.  Called where a trace reaches the
+    call, never inside a step, so ``sites`` counts traces.  Kept in memory
+    (``summary()["dsa_tiles"]``) whether or not the recorder is enabled."""
+    key = (int(length), int(block))
+    counts = {k: int(counts[k]) for k in ("visited", "square")}
+    with _state.lock:
+        row = _state.dsa_tiles.setdefault(key, {"sites": 0})
+        row.update(counts)
+        row["sites"] += 1
+    record("dsa_tiles", length=key[0], block=key[1], **counts)
+
+
+def dsa_tiles() -> List[Dict[str, Any]]:
+    """What ``record_dsa_tiles`` was told, a row a distinct call shape:
+    ``{"length", "block", "visited", "square", "sites"}``."""
+    with _state.lock:
+        return [dict(length=key[0], block=key[1], **row)
+                for key, row in _state.dsa_tiles.items()]
+
+
 def record_rows_run(name: str, ratio: float) -> None:
     """``rows_run_over_rows`` of the expert layer whose load counter is the
     aux leaf ``name``: the row tiles its grouped products visited in the
@@ -1335,6 +1363,7 @@ def summary() -> dict:
             "grouped_tiles": {"tilings": grouped_tiles(),
                               "rows_run_over_rows": dict(_state.rows_run)},
             "bd_tiles": bd_tiles(),
+            "dsa_tiles": dsa_tiles(),
             "serving": _serving_rollup(),
             "spans": {
                 name: {"count": agg["count"],
