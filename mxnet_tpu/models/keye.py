@@ -22,10 +22,12 @@ writes the equations out and lists what the public config leaves open):
 The model returns ``[logits, the layers' KL summed]`` (B,) a row; the loss
 adds the two (``benchmark/programs/next_token_dsa.py``).  The indexer's
 weights get gradient from the KL alone, the trunk from the cross-entropy
-alone.  Each layer is recomputed in the backward pass, the selection with it
-(the flash calls' results and the KL's statistics are kept).  Aux state read at drain: ``dsa_kl`` (a layer's
-KL in the last step) and ``dsa_tile_hit`` (the share of visited tiles that
-hold a selected pair).  Training only: no vision tower and no serving path.
+alone.  Each layer is recomputed in the backward pass; the flash calls'
+results, the KL's statistics and the selection's words are kept, so a
+layer makes its selection once a step.  Aux state read at drain: ``dsa_kl``
+(a layer's KL in the last step) and ``dsa_tile_hit`` (the share of visited
+tiles that hold a selected pair).  Training only: no vision tower and no
+serving path.
 """
 from __future__ import annotations
 

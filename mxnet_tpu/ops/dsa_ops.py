@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import recompute
 from .pallas.flash_attention import (_NEG, SELECT_GROUP, check_selection,
                                      select_block, sparse_flash_attention)
 
@@ -147,16 +148,20 @@ def _selection_blocks(qi, ki, w, topk, length):
 
 def select(qi, ki, w, topk):
     """(bits, bits_t) of each batch row's selection: int32 words (B, Lp/32,
-    Lp), Lp the length padded to the tile.  Not differentiable."""
+    Lp), Lp the length padded to the tile.  Not differentiable.  Both words
+    are named as a kernel's results: a recomputed layer keeps them from its
+    forward pass, since the attention's and the KL's backward read them."""
     qi, ki, w = (jax.lax.stop_gradient(x) for x in (qi, ki, w))
     length = qi.shape[1]
     with jax.named_scope("mx_dsa_select"):
         if _compiled():
-            return _selection_blocks(qi, ki, w, topk, length)
-        sel = _selection_dense(qi, ki, w, topk)
-        _blk, lp = padded(length)
-        sel = jnp.pad(sel, ((0, 0), (0, lp - length), (0, lp - length)))
-        return pack(sel, lp), pack(jnp.swapaxes(sel, 1, 2), lp)
+            words = _selection_blocks(qi, ki, w, topk, length)
+        else:
+            sel = _selection_dense(qi, ki, w, topk)
+            _blk, lp = padded(length)
+            sel = jnp.pad(sel, ((0, 0), (0, lp - length), (0, lp - length)))
+            words = pack(sel, lp), pack(jnp.swapaxes(sel, 1, 2), lp)
+        return recompute.kernel_out(*words)
 
 
 def _selected(bits, length):
@@ -224,7 +229,6 @@ def _kl_kernels(qi, ki, w, q, k, lse, bits_t):
 
 
 def _kl_kernels_fwd(qi, ki, w, q, k, lse, bits_t):
-    from . import recompute
     from .pallas import dsa
 
     length = qi.shape[1]

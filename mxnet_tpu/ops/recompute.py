@@ -2,12 +2,17 @@
 
 ``models/common.py`` ``checkpointed`` recomputes a layer in the backward pass
 under ONE rule, ``policy``: a result that is dear to make again stays from
-the forward pass, everything else is made again.  Two kinds are dear:
+the forward pass, everything else is made again.  Three kinds are dear:
 
 * a kernel's results that its own backward reads, passed through
   ``kernel_out`` where the kernel's ``custom_vjp`` forward rule returns them
   (flash attention's output and row sums: 73 ms of kernel time a GB kept,
   PERF.md section 6, PR 32);
+* a kernel's results that a LATER kernel's backward reads, passed through
+  ``kernel_out`` where the op that makes them returns (``dsa_ops.select``'s
+  packed words, read by the backward of the attention under the selection
+  and of the indexer's KL: 64 MiB a layer at 16,384 positions for 13.9 ms
+  of scores, bisection and packing a layer on a TPU v5e, PERF.md section 6);
 * a product of activations with a weight (a ``dot_general`` with no batch
   dimensions: 16 ms a GB for the widest).
 

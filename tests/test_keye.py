@@ -5,7 +5,8 @@ selection mode) against the plain reference
 CPU: the indexer's scores, the thresholds and the packed selection (rows
 before ``topk``, a planted tie), the kernels in interpret mode against the
 dense compositions (forward and gradients), which weights each loss
-reaches, and the experts' shares against the uncut layer."""
+reaches, the experts' shares against the uncut layer, and a recomputed
+layer that keeps its selection's words and makes the selection once."""
 import importlib
 import importlib.util
 import os
@@ -18,7 +19,7 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import autograd, nd, telemetry
-from mxnet_tpu.ops import dsa_ops
+from mxnet_tpu.ops import dsa_ops, recompute
 from mxnet_tpu.ops.pallas import dsa
 
 fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
@@ -235,14 +236,15 @@ def test_the_tile_counter_reads_the_words(case):
 # ---------------------------------------------------------------------------
 # the model
 # ---------------------------------------------------------------------------
-def _net(ref, seed=3):
+def _net(ref, seed=3, layers=1):
     sys.path.insert(0, _REPO)
     from benchmark import weights
 
     from mxnet_tpu.models.keye import keye
 
-    w = weights.make_weights(ref.param_spec(TINY), seed, "float32")
-    net = keye(**KWARGS)
+    cfg = dict(TINY, layer_types=["sparse"] * layers)
+    w = weights.make_weights(ref.param_spec(cfg), seed, "float32")
+    net = keye(**dict(KWARGS, num_layers=layers))
     net.initialize(mx.init.Zero(), ctx=mx.cpu())
     weights.install(net, w, mx.cpu())
     return net, w
@@ -321,3 +323,116 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(ref):
         out, _load = moe(nd.array(u))
         total += out.asnumpy()
     np.testing.assert_allclose(total, whole, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the recomputed layer keeps its selection
+# ---------------------------------------------------------------------------
+LAYERS = 2
+WORDS = 1 * (256 // 32) * 256 * 4     # int32 (B, Lp / 32, Lp) at Lp = 256
+
+
+@pytest.fixture(scope="module")
+def stack(ref):
+    """A two-layer model's losses as a function of all its weights, as the
+    training step takes their gradient (each layer under ``checkpointed``).
+    Each check takes ``jax.grad`` anew: a traced function is cached, and
+    the checks trace again after patching what the trace reads."""
+    from mxnet_tpu.parallel.data_parallel import _block_apply_fn
+
+    net, _w = _net(ref, seed=5, layers=LAYERS)
+    fn, items = _block_apply_fn(net, mx.cpu(), True)
+    params = {name: p.data()._data for name, p in items}
+    tokens = jnp.asarray(np.random.default_rng(2).integers(0, 40, (1, 16)),
+                         jnp.int32)
+
+    def loss(params):
+        (logits, kl), _aux = fn(params, jax.random.PRNGKey(0), tokens)
+        return jnp.mean(jnp.square(logits)) + jnp.sum(kl)
+
+    return loss, params
+
+
+def _on(path, monkeypatch):
+    if path == "kernels":
+        monkeypatch.setattr(dsa_ops, "_compiled", lambda: True)
+
+
+def _float_results(*xs):
+    """``recompute.kernel_out`` that names a kernel's float results only:
+    the selection's int32 words then fall to the rule's default."""
+    return tuple(x if x.dtype == jnp.int32 else
+                 jax.ad_checkpoint.checkpoint_name(x, recompute.KERNEL_OUT)
+                 for x in xs)
+
+
+def _selections(jaxpr):
+    """name -> how often the jaxpr makes a selection, at any depth:
+    ``top_k`` under ``mx_dsa_select`` (the dense path; the routers' own
+    ``top_k`` is not counted), the ``mx_dsa_scores`` and ``mx_dsa_topk``
+    calls (the kernels)."""
+    counts = {}
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            name = eqn.params["name"]
+        elif "mx_dsa_select" not in str(eqn.source_info.name_stack):
+            name = None
+        if name in ("top_k", "mx_dsa_scores", "mx_dsa_topk"):
+            counts[name] = counts.get(name, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            for key, n in _selections(sub).items():
+                counts[key] = counts.get(key, 0) + n
+    return counts
+
+
+@pytest.mark.parametrize("path", ["dense", "kernels"])
+def test_a_recomputed_layer_makes_its_selection_once(stack, monkeypatch,
+                                                     path):
+    """The forward pass makes each layer's selection and its backward reads
+    the kept words: one ``top_k`` (or one ``mx_dsa_scores`` and one
+    ``mx_dsa_topk``) a layer.  Words left unnamed are made twice."""
+    loss, params = stack
+    _on(path, monkeypatch)
+    once = ({"top_k": LAYERS} if path == "dense" else
+            {"mx_dsa_scores": LAYERS, "mx_dsa_topk": LAYERS})
+    assert _selections(jax.make_jaxpr(jax.grad(loss))(params).jaxpr) == once
+    monkeypatch.setattr(recompute, "kernel_out", _float_results)
+    assert _selections(jax.make_jaxpr(jax.grad(loss))(params).jaxpr) == {
+        name: 2 * LAYERS for name in once}
+
+
+@pytest.mark.parametrize("path", ["dense", "kernels"])
+def test_a_recomputed_layer_keeps_both_words(stack, monkeypatch, path):
+    """``tally`` counts the two words of every layer, with their bytes, on
+    top of what the rule keeps of the layer's other kernels and products."""
+    loss, params = stack
+    _on(path, monkeypatch)
+    with recompute.tally() as kept:
+        jax.make_jaxpr(jax.grad(loss))(params)
+    monkeypatch.setattr(recompute, "kernel_out", _float_results)
+    with recompute.tally() as unnamed:
+        jax.make_jaxpr(jax.grad(loss))(params)
+    assert kept.layers == unnamed.layers == LAYERS
+    assert kept.tensors - unnamed.tensors == 2 * LAYERS
+    assert kept.bytes - unnamed.bytes == 2 * LAYERS * WORDS
+
+
+@pytest.mark.parametrize("path", ["dense", "kernels"])
+def test_the_kept_selection_gives_the_plain_calls_gradients(stack,
+                                                            monkeypatch, path):
+    """Every leaf's gradient with the layers recomputed equals that of the
+    layers called plainly."""
+    from mxnet_tpu.models import keye as keye_mod
+
+    loss, params = stack
+    _on(path, monkeypatch)
+    got = jax.jit(jax.grad(loss))(params)
+    monkeypatch.setattr(keye_mod, "checkpointed",
+                        lambda block, *xs: block(*xs))
+    want = jax.jit(jax.grad(loss))(params)
+    (indexer,) = [n for n in want if n.endswith("layer1_indexer_wq_weight")]
+    assert float(jnp.linalg.norm(want[indexer])) > 0     # the KL's gradient
+    for name in want:
+        np.testing.assert_allclose(got[name], want[name], rtol=2e-5,
+                                   atol=2e-5, err_msg=name)

@@ -139,6 +139,38 @@ def test_the_rule_counts_what_it_marks_an_upper_bound_of_what_stays(stack):
     assert kept.tensors == 12
 
 
+@pytest.mark.parametrize("block_diffusion", [0, 4])
+def test_an_attention_layer_keeps_its_kernels_results_and_products(
+        monkeypatch, block_diffusion):
+    """A ``GQAttention`` layer as the Nemotron (causal) and SDAR cells
+    (block diffusion, q/k norms, rotary) recompute it keeps the flash
+    kernel's output and row sums and its four products with a weight,
+    nothing more: the rule's names reach no other tensor of it."""
+    from mxnet_tpu.models.nemotron_h import GQAttention
+    from mxnet_tpu.ops import pallas
+
+    monkeypatch.setattr(pallas, "use_compiled", lambda: True)
+    length, units, heads, kv_heads, dim = 16, 32, 4, 2, 8
+    mx.random.seed(2)
+    attn = GQAttention(units, heads, kv_heads, dim,
+                       qk_norm=bool(block_diffusion),
+                       rope_theta=1e6 if block_diffusion else None,
+                       block_diffusion=block_diffusion, prefix="attn_")
+    attn.initialize(mx.init.Normal(0.1), ctx=mx.cpu())
+
+    def loss(x):
+        y = checkpointed(attn, NDArray(x, ctx=mx.cpu()))
+        return jnp.sum(jnp.square(y._data))
+
+    with recompute.tally() as kept:
+        jax.make_jaxpr(jax.grad(loss))(jnp.ones((1, length, units)))
+    out, lse = heads * length * dim * F32, heads * length * F32
+    q, o = length * heads * dim * F32, length * units * F32
+    k = v = length * kv_heads * dim * F32
+    assert (kept.layers, kept.tensors) == (1, 2 + 4)
+    assert kept.bytes == out + lse + q + k + v + o
+
+
 def test_outside_a_step_the_call_is_plain_and_nothing_is_counted(stack):
     layer = _Layer("plain_")
     layer.initialize(mx.init.Normal(0.3), ctx=mx.cpu())
