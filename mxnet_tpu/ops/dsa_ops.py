@@ -114,20 +114,26 @@ def _selection_dense(qi, ki, w, topk):
     return jnp.logical_and(score >= theta[..., None], causal)
 
 
+def _pad_to(x, axis, lp):
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, lp - x.shape[axis])
+    return jnp.pad(x, widths)
+
+
+def _index_operands(qi, ki, w, lp):
+    """The indexer's operands of its kernels, padded to ``lp``: qi (B, J,
+    Lp, Dk), ki (B, Lp, Dk), w (B, J, Lp) f32."""
+    return (jnp.swapaxes(_pad_to(qi, 1, lp), 1, 2), _pad_to(ki, 1, lp),
+            jnp.swapaxes(_pad_to(w.astype(jnp.float32), 1, lp), 1, 2))
+
+
 def _selection_blocks(qi, ki, w, topk, length):
     """(bits, bits_t) by the kernels, a block of queries at a time."""
     from .pallas import dsa
 
     blk, lp = padded(length)
-    b, _, heads, dk = qi.shape
-
-    def pad(x):
-        return jnp.pad(x, [(0, 0), (0, lp - length)]
-                       + [(0, 0)] * (x.ndim - 2))
-
-    qt = jnp.swapaxes(pad(qi), 1, 2)                 # (B, J, Lp, Dk)
-    wt = jnp.swapaxes(pad(w.astype(jnp.float32)), 1, 2)   # (B, J, Lp)
-    kp = pad(ki)
+    b = qi.shape[0]
+    qt, kp, wt = _index_operands(qi, ki, w, lp)
     interpret = _interpret()
 
     def block(q0):
@@ -210,51 +216,43 @@ def _kl_dense(qi, ki, w, q, k, lse, bits_t):
                              0.0), axis=-1)
 
 
-def _kl_operands(qi, ki, w, q, k, lse, length):
-    blk, lp = padded(length)
-
-    def pad(x, axis):
-        widths = [(0, 0)] * x.ndim
-        widths[axis] = (0, lp - length)
-        return jnp.pad(x, widths)
-
-    return (pad(q, 2), pad(k, 2), pad(lse.astype(jnp.float32), 2),
-            jnp.swapaxes(pad(qi, 1), 1, 2), pad(ki, 1),
-            jnp.swapaxes(pad(w.astype(jnp.float32), 1), 1, 2))
-
-
 @jax.custom_vjp
 def _kl_kernels(qi, ki, w, q, k, lse, bits_t):
     return _kl_kernels_fwd(qi, ki, w, q, k, lse, bits_t)[0]
 
 
 def _kl_kernels_fwd(qi, ki, w, q, k, lse, bits_t):
+    """The KL and the residuals of its backward: the target ``p`` that
+    ``kl_fwd`` makes, named with its statistics so that a recomputed layer
+    keeps both and its backward makes the target no second time."""
     from .pallas import dsa
 
     length = qi.shape[1]
-    ops = _kl_operands(qi, ki, w, q, k, lse, length)
-    st, = recompute.kernel_out(dsa.kl_fwd(*ops, bits_t, _interpret()))
+    _blk, lp = padded(length)
+    target = (_pad_to(x, 2, lp) for x in (q, k, lse.astype(jnp.float32)))
+    st, p = recompute.kernel_out(*dsa.kl_fwd(
+        *target, *_index_operands(qi, ki, w, lp), bits_t, _interpret()))
     lse_i = st[:, 0] + jnp.log(st[:, 1])
     kl = st[:, 2] - st[:, 3] + st[:, 4] * lse_i
-    return kl[:, :length], (qi, ki, w, q, k, lse, bits_t, lse_i, st[:, 4])
+    return kl[:, :length], (qi, ki, w, bits_t, p, lse_i, st[:, 4])
 
 
 def _kl_kernels_bwd(res, g):
     from .pallas import dsa
 
-    qi, ki, w, q, k, lse, bits_t, lse_i, total = res
+    qi, ki, w, bits_t, p, lse_i, total = res
     b, length = g.shape
-    ops = _kl_operands(qi, ki, w, q, k, lse, length)
-    lp = ops[0].shape[2]
+    lp = bits_t.shape[-1]
     rows = jnp.zeros((b, 8, lp), jnp.float32)
     rows = rows.at[:, 0].set(lse_i).at[:, 1].set(total).at[:, 2, :length].set(
         g.astype(jnp.float32))
-    dqt, dwt, dk = dsa.kl_bwd(*ops, bits_t, rows, _interpret())
+    dqt, dwt, dk = dsa.kl_bwd(p, *_index_operands(qi, ki, w, lp), bits_t,
+                              rows, _interpret())
     dqi = jnp.moveaxis(dqt, 3, 1)[:, :length]         # (B, L, J, Dk)
     dw = jnp.swapaxes(dwt, 1, 2)[:, :length]
-    zero = [jnp.zeros_like(x) for x in (q, k, lse)]
+    # q, k and lse make the target: no gradient reaches them
     return (dqi.astype(qi.dtype), dk[:, :length].astype(ki.dtype),
-            dw.astype(w.dtype), *zero,
+            dw.astype(w.dtype), None, None, None,
             np.zeros(bits_t.shape, jax.dtypes.float0))
 
 

@@ -14,8 +14,9 @@ weight broadcasts down the sublanes:
   float's bits over a VMEM-resident column block (no sort);
 * ``kl_fwd`` / ``kl_bwd``: ``KL(p_t || softmax_{S_t} I_t)`` a query, with
   ``p`` the attention heads' mean probability over the selection ``S_t``
-  (made again from q, k and the attention's row logsumexp, tile by tile),
-  and its gradient in ``qi``, ``ki`` and ``w``.
+  (made again from q, k and the attention's row logsumexp, tile by tile, in
+  ``kl_fwd``, which returns it for ``kl_bwd`` to read), and its gradient in
+  ``qi``, ``ki`` and ``w``.
 
 The selection arrives as the transposed packed words of
 ``flash_attention.py`` (``bits_t``, unpacked by ``_unpack``).  Lengths are
@@ -176,7 +177,7 @@ def _mean_probs(k_ref, q_ref, lse_ref, heads, group, scale, mask):
 
 
 def _kl_fwd_kernel(heads, group, jheads, scale, q_ref, k_ref, lse_ref,
-                   qi_ref, ki_ref, w_ref, bits_ref, st_ref):
+                   qi_ref, ki_ref, w_ref, bits_ref, st_ref, p_ref):
     qi, kj = pl.program_id(1), pl.program_id(2)
     bk, bq = ki_ref.shape[1], q_ref.shape[2]
 
@@ -189,6 +190,7 @@ def _kl_fwd_kernel(heads, group, jheads, scale, q_ref, k_ref, lse_ref,
     def _tile():
         mask = _unpack(bits_ref[0], bk)
         p = _mean_probs(k_ref, q_ref, lse_ref, heads, group, scale, mask)
+        p_ref[0, 0] = p
         score = _index_scores(ki_ref[0], qi_ref, w_ref, jheads)
         m_old = st_ref[0, 0:1, :]
         m_new = jnp.maximum(m_old, jnp.max(jnp.where(mask, score, _NEG),
@@ -203,28 +205,39 @@ def _kl_fwd_kernel(heads, group, jheads, scale, q_ref, k_ref, lse_ref,
         st_ref[0, 4:5, :] += jnp.sum(p, axis=0, keepdims=True)
 
 
-def _kl_specs(b, heads, hkv, lp, d, jheads, dk, blk):
-    """In-specs of q, k, lse, qi, ki, w and the words over the grid (batch
-    row, query block, key block); a key block past the diagonal maps to the
-    diagonal's (no copy, the step is skipped)."""
-    def kv(i, q, j):
-        return jnp.minimum(j, q)
+def _kv(q, j):
+    """The key block of grid step (q, j): past the diagonal, the
+    diagonal's (the same block again is no copy; the step is skipped)."""
+    return jnp.minimum(j, q)
 
+
+def _kl_specs(jheads, dk, blk):
+    """In-specs of qi, ki, w and the words over the grid (batch row, query
+    block, key block)."""
     return [
-        pl.BlockSpec((1, heads, blk, d), lambda i, q, j: (i, 0, q, 0)),
-        pl.BlockSpec((1, hkv, blk, d), lambda i, q, j: (i, 0, kv(i, q, j), 0)),
-        pl.BlockSpec((1, heads, blk), lambda i, q, j: (i, 0, q)),
         pl.BlockSpec((1, jheads, blk, dk), lambda i, q, j: (i, 0, q, 0)),
-        pl.BlockSpec((1, blk, dk), lambda i, q, j: (i, kv(i, q, j), 0)),
+        pl.BlockSpec((1, blk, dk), lambda i, q, j: (i, _kv(q, j), 0)),
         pl.BlockSpec((1, jheads, blk), lambda i, q, j: (i, 0, q)),
-        pl.BlockSpec((1, blk // 32, blk), lambda i, q, j: (i, kv(i, q, j), q)),
+        pl.BlockSpec((1, blk // 32, blk), lambda i, q, j: (i, _kv(q, j), q)),
     ]
 
 
+def _target_spec(blk):
+    """The target's tile of grid step (q, j): causal tiles packed by query
+    block, tile ``q (q + 1) / 2 + j`` for ``j <= q``."""
+    def tile(i, q, j):
+        return i, q * (q + 1) // 2 + _kv(q, j), 0, 0
+
+    return pl.BlockSpec((1, 1, blk, blk), tile)
+
+
 def kl_fwd(q, k, lse, qi, ki, w, bits_t, interpret=False):
-    """(B, 8, Lp) f32 statistics a query, rows 0 to 4: the running max and
-    sum of ``exp(I)`` over the selection, ``sum p log p``, ``sum p I`` and
-    ``sum p``.  q (B, H, Lp, D), k (B, Hkv, Lp, D), lse (B, H, Lp) f32 of the
+    """(st, p).  ``st`` (B, 8, Lp) f32 statistics a query, rows 0 to 4: the
+    running max and sum of ``exp(I)`` over the selection, ``sum p log p``,
+    ``sum p I`` and ``sum p``.  ``p`` (B, T, blk, blk) f32, the target: the
+    heads' mean probability of each of the T = n (n + 1) / 2 causal tiles
+    (``_target_spec``), transposed (keys as rows), 0 off the selection.
+    q (B, H, Lp, D), k (B, Hkv, Lp, D), lse (B, H, Lp) f32 of the
     attention; qi (B, J, Lp, Dk), ki (B, Lp, Dk), w (B, J, Lp) f32 of the
     indexer; ``bits_t`` (B, Lp/32, Lp)."""
     b, heads, lp, d = q.shape
@@ -235,18 +248,25 @@ def kl_fwd(q, k, lse, qi, ki, w, bits_t, interpret=False):
         functools.partial(_kl_fwd_kernel, heads, heads // hkv, jheads,
                           d ** -0.5),
         name="mx_dsa_kl_fwd", grid=(b, n, n),
-        in_specs=_kl_specs(b, heads, hkv, lp, d, jheads, dk, blk),
-        out_specs=pl.BlockSpec((1, 8, blk), lambda i, q, j: (i, 0, q)),
-        out_shape=jax.ShapeDtypeStruct((b, 8, lp), jnp.float32),
+        in_specs=[
+            pl.BlockSpec((1, heads, blk, d), lambda i, q, j: (i, 0, q, 0)),
+            pl.BlockSpec((1, hkv, blk, d),
+                         lambda i, q, j: (i, 0, _kv(q, j), 0)),
+            pl.BlockSpec((1, heads, blk), lambda i, q, j: (i, 0, q)),
+        ] + _kl_specs(jheads, dk, blk),
+        out_specs=[pl.BlockSpec((1, 8, blk), lambda i, q, j: (i, 0, q)),
+                   _target_spec(blk)],
+        out_shape=[jax.ShapeDtypeStruct((b, 8, lp), jnp.float32),
+                   jax.ShapeDtypeStruct((b, n * (n + 1) // 2, blk, blk),
+                                        jnp.float32)],
         compiler_params=_PARAMS, interpret=interpret,
     )(q, k, lse, qi, ki, w, bits_t)
 
 
-def _kl_bwd_kernel(heads, group, jheads, scale, q_ref, k_ref, lse_ref,
-                   qi_ref, ki_ref, w_ref, bits_ref, kit_ref, row_ref,
-                   dqi_ref, dw_ref, dki_ref):
+def _kl_bwd_kernel(jheads, p_ref, qi_ref, ki_ref, w_ref, bits_ref, kit_ref,
+                   row_ref, dqi_ref, dw_ref, dki_ref):
     qi, kj = pl.program_id(1), pl.program_id(2)
-    bk, bq = ki_ref.shape[1], q_ref.shape[2]
+    bk, bq = ki_ref.shape[1], w_ref.shape[2]
 
     @pl.when(kj == 0)
     def _init_rows():
@@ -260,12 +280,11 @@ def _kl_bwd_kernel(heads, group, jheads, scale, q_ref, k_ref, lse_ref,
     @pl.when(kj * bk <= qi * bq + bq - 1)
     def _tile():
         mask = _unpack(bits_ref[0], bk)
-        p = _mean_probs(k_ref, q_ref, lse_ref, heads, group, scale, mask)
         ki = ki_ref[0]
         score = _index_scores(ki, qi_ref, w_ref, jheads)
         lse_i, total, g = (row_ref[0, r:r + 1, :] for r in range(3))
         soft = jnp.where(mask, jnp.exp(score - lse_i), 0.0)
-        ds = g * (total * soft - p)
+        ds = g * (total * soft - p_ref[0, 0])
         kit = kit_ref[0]
         dk = None
         for j in range(jheads):
@@ -281,21 +300,19 @@ def _kl_bwd_kernel(heads, group, jheads, scale, q_ref, k_ref, lse_ref,
         dki_ref[0, pl.ds(kj * bk, bk), :] += dk
 
 
-def kl_bwd(q, k, lse, qi, ki, w, bits_t, rows, interpret=False):
+def kl_bwd(p, qi, ki, w, bits_t, rows, interpret=False):
     """Gradients of ``sum_t g_t KL_t`` in qi, w and ki: (dqi^T (B, J, Dk,
-    Lp), dw (B, J, Lp), dki (B, Lp, Dk)), all f32.  ``rows`` (B, 8, Lp):
-    row 0 the selection's logsumexp of I, row 1 ``sum p``, row 2 ``g``."""
-    b, heads, lp, d = q.shape
-    hkv, jheads, dk = k.shape[1], qi.shape[1], qi.shape[-1]
+    Lp), dw (B, J, Lp), dki (B, Lp, Dk)), all f32.  ``p`` the target as
+    ``kl_fwd`` returns it; ``rows`` (B, 8, Lp): row 0 the selection's
+    logsumexp of I, row 1 ``sum p``, row 2 ``g``."""
+    b, jheads, lp, dk = qi.shape
     blk = min(512, lp)
     n = lp // blk
-    specs = _kl_specs(b, heads, hkv, lp, d, jheads, dk, blk) + [
-        pl.BlockSpec((1, dk, blk),
-                     lambda i, q, j: (i, 0, jnp.minimum(j, q))),
+    specs = [_target_spec(blk)] + _kl_specs(jheads, dk, blk) + [
+        pl.BlockSpec((1, dk, blk), lambda i, q, j: (i, 0, _kv(q, j))),
         pl.BlockSpec((1, 8, blk), lambda i, q, j: (i, 0, q))]
     return pl.pallas_call(
-        functools.partial(_kl_bwd_kernel, heads, heads // hkv, jheads,
-                          d ** -0.5),
+        functools.partial(_kl_bwd_kernel, jheads),
         name="mx_dsa_kl_bwd", grid=(b, n, n), in_specs=specs,
         out_specs=[
             pl.BlockSpec((1, jheads, dk, blk), lambda i, q, j: (i, 0, 0, q)),
@@ -305,4 +322,4 @@ def kl_bwd(q, k, lse, qi, ki, w, bits_t, rows, interpret=False):
                    jax.ShapeDtypeStruct((b, jheads, lp), jnp.float32),
                    jax.ShapeDtypeStruct((b, lp, dk), jnp.float32)],
         compiler_params=_PARAMS, interpret=interpret,
-    )(q, k, lse, qi, ki, w, bits_t, jnp.swapaxes(ki, 1, 2), rows)
+    )(p, qi, ki, w, bits_t, jnp.swapaxes(ki, 1, 2), rows)

@@ -4,11 +4,13 @@ selection mode) against the plain reference
 (``benchmark/references/keye_vl_2_0_30b_a3b.py``) on seeded weights, on the
 CPU: the indexer's scores, the thresholds and the packed selection (rows
 before ``topk``, a planted tie), the kernels in interpret mode against the
-dense compositions (forward and gradients), which weights each loss
-reaches, the experts' shares against the uncut layer, and a recomputed
-layer that keeps its selection's words and makes the selection once."""
+dense compositions (forward and gradients), the KL's target, which weights
+each loss reaches, the experts' shares against the uncut layer, and a
+recomputed layer that keeps its selection's words and the KL's target and
+makes each once."""
 import importlib
 import importlib.util
+import math
 import os
 import sys
 
@@ -226,6 +228,31 @@ def test_the_kl_kernels_match_the_dense_composition(case):
                                    atol=1e-5 * float(jnp.abs(b).max()))
 
 
+@pytest.mark.parametrize("length", [300, 1100])
+def test_the_kl_forward_returns_the_heads_mean_probability(length):
+    """``mx_dsa_kl_fwd``'s second result, the target its backward reads, is
+    ``mean_probs`` on every causal tile (transposed, keys as rows; packed by
+    query block), 0 off the selection and past the length."""
+    qi, ki, w = _indexer_inputs(length, seed=30)
+    bits, bits_t = dsa_ops.select(qi, ki, w, 40)
+    q, k, v = (_rand(33 + i, (1, h, length, 32)) for i, h in enumerate(
+        (4, 2, 2)))
+    _out, lse = dsa_ops.sparse_attention(q, k, v, bits, bits_t)
+    _blk, lp = dsa_ops.padded(length)
+    _st, p = dsa.kl_fwd(*(dsa_ops._pad_to(x, 2, lp) for x in (q, k, lse)),
+                        *dsa_ops._index_operands(qi, ki, w, lp), bits_t,
+                        interpret=True)
+    sel = jnp.swapaxes(dsa_ops.unpack(bits_t)[:, :length, :length], 1, 2)
+    want = jnp.pad(dsa_ops.mean_probs(q, k, lse, sel),
+                   ((0, 0), (0, lp - length), (0, lp - length)))
+    blk = min(512, lp)
+    n = lp // blk
+    tiles = [want[:, a * blk:(a + 1) * blk, c * blk:(c + 1) * blk].mT
+             for a in range(n) for c in range(a + 1)]
+    assert p.shape == (1, n * (n + 1) // 2, blk, blk)
+    np.testing.assert_allclose(p, jnp.stack(tiles, 1), rtol=0, atol=1e-6)
+
+
 def test_the_tile_counter_reads_the_words(case):
     assert float(dsa_ops.tile_hit(case["bits"], case["length"])) == 1.0
     bits = case["bits"].at[:, 8:16, :256].set(0)     # tile (1, 0) emptied
@@ -416,6 +443,65 @@ def test_a_recomputed_layer_keeps_both_words(stack, monkeypatch, path):
     assert kept.layers == unnamed.layers == LAYERS
     assert kept.tensors - unnamed.tensors == 2 * LAYERS
     assert kept.bytes - unnamed.bytes == 2 * LAYERS * WORDS
+
+
+def _kernel_calls(jaxpr):
+    """(name, operand shapes) of every Pallas call of the jaxpr, at any
+    depth."""
+    calls = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls.append((eqn.params["name"],
+                          [v.aval.shape for v in eqn.invars]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            calls += _kernel_calls(sub)
+    return calls
+
+
+@pytest.mark.parametrize("named", [True, False])
+def test_a_recomputed_layer_makes_the_kl_target_once(stack, monkeypatch,
+                                                     named):
+    """The KL's backward reads the target its forward made: one
+    ``mx_dsa_kl_fwd`` a layer, and no ``mx_dsa_kl_bwd`` operand of q's
+    shape.  With ``kernel_out`` naming nothing the recomputed layer runs
+    ``mx_dsa_kl_fwd`` a second time."""
+    loss, params = stack
+    _on("kernels", monkeypatch)
+    if not named:
+        monkeypatch.setattr(recompute, "kernel_out", lambda *xs: xs)
+    calls = _kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    fwd = [shapes for name, shapes in calls if name == "mx_dsa_kl_fwd"]
+    bwd = [shapes for name, shapes in calls if name == "mx_dsa_kl_bwd"]
+    assert len(fwd) == (1 if named else 2) * LAYERS
+    assert len(bwd) == LAYERS
+    q_shape = fwd[0][0]
+    assert q_shape[1] == TINY["num_attention_heads"]
+    assert not any(q_shape in shapes for shapes in bwd)
+
+
+def test_a_recomputed_layer_keeps_the_kl_target(stack, monkeypatch):
+    """``tally`` counts the KL's target, one f32 (B, T, blk, blk) tensor a
+    layer, on top of what the rule keeps with the target left unnamed."""
+    loss, params = stack
+    _on("kernels", monkeypatch)
+    _blk, lp = dsa_ops.padded(16)
+    blk = min(512, lp)
+    n = lp // blk
+    target = (1, n * (n + 1) // 2, blk, blk)
+
+    def all_but_target(*xs):
+        return tuple(x if x.shape == target else
+                     jax.ad_checkpoint.checkpoint_name(x, recompute.KERNEL_OUT)
+                     for x in xs)
+
+    with recompute.tally() as kept:
+        jax.make_jaxpr(jax.grad(loss))(params)
+    monkeypatch.setattr(recompute, "kernel_out", all_but_target)
+    with recompute.tally() as unnamed:
+        jax.make_jaxpr(jax.grad(loss))(params)
+    assert kept.layers == unnamed.layers == LAYERS
+    assert kept.tensors - unnamed.tensors == LAYERS
+    assert kept.bytes - unnamed.bytes == LAYERS * math.prod(target) * 4
 
 
 @pytest.mark.parametrize("path", ["dense", "kernels"])
