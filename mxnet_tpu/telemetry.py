@@ -233,13 +233,10 @@ class _State:
         # both kept like moe_load)
         self.grouped_tiles: Dict[tuple, Dict[str, Any]] = {}
         self.rows_run: Dict[str, float] = {}
-        # (length, block, block_q, block_k) -> {"visited", "whole",
-        # "masked", "square", "sites"}: the tiles the traced block-diffusion
-        # flash calls visit (record_bd_tiles; kept like moe_load)
-        self.bd_tiles: Dict[tuple, Dict[str, int]] = {}
-        # (length, block) -> {"visited", "square", "sites"}: the tiles the
-        # traced flash calls under a selection visit (record_dsa_tiles)
-        self.dsa_tiles: Dict[tuple, Dict[str, int]] = {}
+        # mode -> call shape -> {counts, "sites"}: the tiles the traced
+        # flash calls under a mask mode visit (record_flash_tiles; kept like
+        # moe_load)
+        self.flash_tiles: Dict[str, Dict[tuple, Dict[str, int]]] = {}
         # executor -> the scope of each instruction it compiled, or the
         # function that makes it (record_scope_map; kept like moe_load)
         self.scope_maps: Dict[str, Any] = {}
@@ -825,61 +822,47 @@ def grouped_tiles() -> List[Dict[str, Any]]:
                 for key, row in _state.grouped_tiles.items()]
 
 
-def record_bd_tiles(length: int, block: int, block_q: int, block_k: int,
-                    counts: Dict[str, int]) -> None:
-    """The (query block, key block) tiles one flash call under the
-    block-diffusion mask visits over a stream of ``length`` positions
-    (``ops/pallas/flash_attention.py`` ``bd_tiles``): ``visited`` = ``whole``
-    (run unmasked) + ``masked``, out of the ``square``'s; ``masked`` =
-    ``per_copy`` (keys of one copy: one compare a score) + ``general``
-    (tiles that may hold both copies or padding: two).  Called where a
-    trace reaches the call, never inside a step, so ``sites`` counts traces.
-    Kept in memory (``summary()["bd_tiles"]``) whether or not the recorder
-    is enabled."""
-    key = (int(length), int(block), int(block_q), int(block_k))
-    counts = {k: int(counts[k]) for k in ("visited", "whole", "masked",
-                                          "square", "per_copy", "general")}
+def record_flash_tiles(mode: str, key: Dict[str, int],
+                       counts: Dict[str, int]) -> None:
+    """The (query block, key block) tiles one flash call under a mask mode
+    visits (``ops/pallas/flash_attention.py``, the mode's ``tiles()``,
+    counted from the runs the kernels loop over): ``mode`` names the rows
+    (``bd_tiles``: the block-diffusion mask; ``dsa_tiles``: a learned
+    selection), ``key`` the call's shape, ``counts`` its tiles.  Called
+    where a trace reaches the call, never inside a step, so ``sites`` counts
+    traces.  Kept in memory (``summary()[mode]``) whether or not the
+    recorder is enabled."""
+    key = {k: int(v) for k, v in key.items()}
+    counts = {k: int(v) for k, v in counts.items()}
     with _state.lock:
-        row = _state.bd_tiles.setdefault(key, {"sites": 0})
+        row = _state.flash_tiles.setdefault(mode, {}).setdefault(
+            tuple(key.items()), {"sites": 0})
         row.update(counts)
         row["sites"] += 1
-    record("bd_tiles", length=key[0], block=key[1], block_q=key[2],
-           block_k=key[3], **counts)
+    record(mode, **key, **counts)
+
+
+def _flash_tiles(mode: str) -> List[Dict[str, Any]]:
+    with _state.lock:
+        return [dict(key, **row)
+                for key, row in _state.flash_tiles.get(mode, {}).items()]
 
 
 def bd_tiles() -> List[Dict[str, Any]]:
-    """What ``record_bd_tiles`` was told, a row a distinct call shape:
-    ``{"length", "block", "block_q", "block_k", "visited", "whole",
-    "masked", "square", "per_copy", "general", "sites"}``."""
-    names = ("length", "block", "block_q", "block_k")
-    with _state.lock:
-        return [dict(zip(names, key), **row)
-                for key, row in _state.bd_tiles.items()]
-
-
-def record_dsa_tiles(length: int, block: int,
-                     counts: Dict[str, int]) -> None:
-    """The (query block, key block) tiles one flash call under a selection
-    (``ops/pallas/flash_attention.py`` ``sparse_flash_attention``) visits
-    over ``length`` positions in tiles of ``block``: ``visited`` (every
-    causal tile) of the ``square``'s.  Called where a trace reaches the
-    call, never inside a step, so ``sites`` counts traces.  Kept in memory
-    (``summary()["dsa_tiles"]``) whether or not the recorder is enabled."""
-    key = (int(length), int(block))
-    counts = {k: int(counts[k]) for k in ("visited", "square")}
-    with _state.lock:
-        row = _state.dsa_tiles.setdefault(key, {"sites": 0})
-        row.update(counts)
-        row["sites"] += 1
-    record("dsa_tiles", length=key[0], block=key[1], **counts)
+    """What ``record_flash_tiles`` was told of the block-diffusion mask, a
+    row a distinct call shape: ``{"length", "block", "block_q", "block_k",
+    "visited", "whole", "masked", "square", "per_copy", "general",
+    "sites"}``; ``visited`` = ``whole`` (run unmasked) + ``masked``, and
+    ``masked`` = ``per_copy`` (keys of one copy: one compare a score) +
+    ``general`` (tiles that may hold both copies or padding: two)."""
+    return _flash_tiles("bd_tiles")
 
 
 def dsa_tiles() -> List[Dict[str, Any]]:
-    """What ``record_dsa_tiles`` was told, a row a distinct call shape:
-    ``{"length", "block", "visited", "square", "sites"}``."""
-    with _state.lock:
-        return [dict(length=key[0], block=key[1], **row)
-                for key, row in _state.dsa_tiles.items()]
+    """What ``record_flash_tiles`` was told of a selection, a row a
+    distinct call shape: ``{"length", "block", "visited", "square",
+    "sites"}`` (every causal tile is visited, masked by its bits)."""
+    return _flash_tiles("dsa_tiles")
 
 
 def record_rows_run(name: str, ratio: float) -> None:

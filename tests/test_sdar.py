@@ -1,7 +1,8 @@
 """The mechanisms ``models/sdar.py`` brought, at small sizes on the CPU: the
 flash kernels under the block-diffusion mask (interpret mode) against a dense
-masked softmax with all three gradients, the tiles they visit, the causal and
-full kernels lowered as before, softmax routing renormalised over the chosen
+masked softmax with all three gradients, the tiles every mask mode visits
+and how each visited tile masks, every mode's kernels lowered as before,
+softmax routing renormalised over the chosen
 experts (and ZAYA's unrenormalised default), the shares of an expert layer,
 the noising block, and the stack through ``DataParallelStep`` against the
 plain reference (``benchmark/references/sdar_30b_a3b_chat.py``)."""
@@ -17,10 +18,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 import mxnet_tpu as mx
 from mxnet_tpu import nd, telemetry
-from mxnet_tpu.ops import contrib_ops, moe_ops
+from mxnet_tpu.ops import contrib_ops, dsa_ops, moe_ops
 from mxnet_tpu.models import sdar
 from mxnet_tpu.ops import pallas as pk
 
@@ -123,18 +125,38 @@ def test_the_mask_against_its_definition(ref):
     assert got.diagonal().all() and not got[length:, :length].any()
 
 
-def _brute_tiles(length, block, tile):
-    """(whole, masked) tiles of the square by the predicate itself; rows past
-    the stream count as clean queries of later blocks, as in the kernels."""
+def _mode(length, block, tile):
+    """(mask, words, the predicate over the padded square) of a case:
+    ``block`` is the block-diffusion block, or ``causal``, ``full`` or
+    ``selection`` (a random one, packed as ``ops/dsa_ops.py`` packs it);
+    rows past the stream count as clean queries of later blocks, as in the
+    kernels."""
     n = -(-length // tile) * tile
-    pos = np.arange(n)
-    keep = np.array(fa.bd_allowed(length // 2, block,
-                                    jnp.asarray(pos[:, None]),
-                                    jnp.asarray(pos[None, :])))
-    keep &= pos[None, :] < length
+    pos = jnp.arange(n)
+    words = ()
+    if block == "selection":
+        sel = np.tril(np.random.default_rng(length).random((n, n)) < 0.3)
+        sel[length:] = False
+        sel[:, length:] = False
+        words = (dsa_ops.pack(jnp.asarray(sel)[None], n),
+                 dsa_ops.pack(jnp.asarray(sel.T)[None], n))
+        mask = fa.Selection(tile, tile, length, length, 2)
+    elif block in ("causal", "full"):
+        mask = fa.Causal(tile, tile, length, length, block == "causal")
+    else:
+        mask = fa.BlockDiffusion(tile, tile, length, length, block)
+    keep = np.asarray(mask.allowed(pos[:, None], pos[None, :],
+                                   [w[0] for w in words]))
+    if block == "selection":
+        np.testing.assert_array_equal(keep, sel)
+    return mask, words, keep & (np.arange(n)[None, :] < length)
+
+
+def _brute_tiles(keep, tile):
+    """(whole, masked) tiles of the square by the predicate itself."""
     whole = masked = 0
-    for i in range(0, n, tile):
-        for j in range(0, n, tile):
+    for i in range(0, len(keep), tile):
+        for j in range(0, len(keep), tile):
             t = keep[i:i + tile, j:j + tile]
             whole += bool(t.all())
             masked += bool(t.any() and not t.all())
@@ -143,24 +165,40 @@ def _brute_tiles(length, block, tile):
 
 @pytest.mark.parametrize("length, block, tile", [
     (8192, 4, 512), (8192, 16, 512), (1024, 1, 128), (200, 4, 64),
-    (96, 16, 16), (1200, 8, 512)])
+    (96, 16, 16), (1200, 8, 512), (1024, "causal", 128), (200, "full", 64),
+    (300, "selection", 256)])
 def test_the_tiles_visited_are_those_the_mask_reaches(length, block, tile):
     """The runs the kernels loop over visit every tile the mask reaches, run
     unmasked only tiles it leaves whole, and the dkv kernel's runs visit as
     many tiles as the forward's."""
-    counts = fa.bd_tiles(length, block, tile, tile)
-    whole, masked = _brute_tiles(length, block, tile)
+    mask, _words, keep = _mode(length, block, tile)
+    counts = mask.tiles()
+    whole, masked = _brute_tiles(keep, tile)
     assert counts["visited"] == whole + masked
-    assert counts["whole"] <= whole
+    assert counts.get("whole", 0) <= whole
     n = -(-length // tile)
-    cfg = fa._Cfg(False, 1.0, tile, tile, length, length, True, 1, block)
     by_key = sum(max(int(e) - int(s), 0) for kj in range(n)
-                 for s, e, _m in fa._q_segments(cfg, np.int64(kj), n, np))
+                 for s, e, _m in mask.q_runs(np.int64(kj), n, np))
     assert by_key == counts["visited"]
     if (length, block, tile) == (8192, 4, 512):
-        assert counts == {"visited": 80, "whole": 56, "masked": 24,
-                          "square": 256, "per_copy": 24, "general": 0}
+        assert counts == fa.bd_tiles(length, block, tile, tile) == {
+            "visited": 80, "whole": 56, "masked": 24, "square": 256,
+            "per_copy": 24, "general": 0}
         assert counts["whole"] == whole
+    if block == "selection":
+        assert counts == {"visited": 3, "square": 4}
+
+
+class _Ref:
+    """An array read as a kernel reads its ref (``pl.ds`` slices)."""
+
+    def __init__(self, x):
+        self.x = x
+
+    def __getitem__(self, idx):
+        return self.x[tuple(
+            slice(int(i.start), int(i.start) + i.size)
+            if isinstance(i, pl.Slice) else i for i in idx)]
 
 
 @pytest.mark.parametrize("length, block, tile", [
@@ -169,19 +207,19 @@ def test_the_tiles_visited_are_those_the_mask_reaches(length, block, tile):
     (96, 16, 16),     # blocks of a tile: no tile is masked
     (192, 16, 48),    # blocks that straddle tiles of 48
     (192, 4, 64),     # L not a multiple of the tile: tiles hold both copies
-    (200, 4, 48)])    # and padding
+    (200, 4, 48),     # and padding
+    (1024, "causal", 128),
+    (200, "full", 64),              # keys past the length are masked
+    (300, "selection", 256)])
 def test_each_visited_tile_masks_as_the_predicate(length, block, tile):
     """For every (query tile, key tile) pair the kernels visit, the mask the
     tile builds from its rows' and columns' indices (the run's own form and
-    the general one) is ``bd_allowed``; the forward's and dq's tiles, and the
-    dkv kernel's transposed ones.  Runs name a copy exactly where L is a
+    the general one) is the mode's predicate; the forward's and dq's tiles,
+    and the dkv kernel's transposed ones; every tile they skip is empty
+    under it.  Block-diffusion runs name a copy exactly where L is a
     multiple of the tiles."""
     n = -(-length // tile)
-    cfg = fa._Cfg(False, 1.0, tile, tile, length, length, True, 1, block)
-    aligned = (length // 2) % tile == 0
-    pos = jnp.arange(n * tile)
-    keep = np.asarray(fa.bd_allowed(length // 2, block, pos[:, None],
-                                    pos[None, :]) & (pos[None, :] < length))
+    mask, words, keep = _mode(length, block, tile)
     forms = set()
 
     def check(mask, want, copy):
@@ -190,25 +228,43 @@ def test_each_visited_tile_masks_as_the_predicate(length, block, tile):
             np.testing.assert_array_equal(mask(form), want, err_msg=str(
                 (form, copy)))
 
+    def rows(w, blk):   # the words of one row block, as a kernel sees them
+        return [_Ref(x[:, blk * tile // 32:(blk + 1) * tile // 32])
+                for x in w]
+
+    seen = set()
     for i in range(n):
-        for lo, hi, copy in fa._k_segments(cfg, np.int64(i), n, np):
+        for lo, hi, copy in mask.k_runs(np.int64(i), n, np):
             for j in range(int(lo), int(hi)):
+                seen.add((i, j))
                 want = keep[i * tile:(i + 1) * tile, j * tile:(j + 1) * tile]
                 if not copy:
                     assert want.all()
                     continue
-                check(lambda f: fa._bd_mask(cfg, jnp.int32(i), jnp.int32(j),
-                                            f), want, copy)
+                check(lambda f: mask.tile(f, jnp.int32(i), jnp.int32(j),
+                                          rows(words, i)), want, copy)
+    seen_t = set()
     for j in range(n):
-        for lo, hi, copy in fa._q_segments(cfg, np.int64(j), n, np):
+        for lo, hi, copy in mask.q_runs(np.int64(j), n, np):
             for i in range(int(lo), int(hi)):
+                seen_t.add((i, j))
                 want = keep[i * tile:(i + 1) * tile, j * tile:(j + 1) * tile]
                 if not copy:
                     assert want.all()
                     continue
-                check(lambda f: fa._bd_mask_t(cfg, jnp.int32(j),
-                                              jnp.int32(i), f), want.T, copy)
-    if block < tile:
+                check(lambda f: mask.tile_t(f, jnp.int32(j), jnp.int32(i),
+                                            rows(words[1:], j)), want.T,
+                      copy)
+    assert seen == seen_t
+    for i in range(n):
+        for j in range(n):
+            if (i, j) not in seen:
+                assert not keep[i * tile:(i + 1) * tile,
+                                j * tile:(j + 1) * tile].any()
+    if not isinstance(block, int):
+        assert forms == {True}
+    elif block < tile:
+        aligned = (length // 2) % tile == 0
         assert forms == ({"noisy", "clean"} if aligned else {True})
     else:
         assert not forms
@@ -249,30 +305,54 @@ def test_block_diffusion_refuses_what_the_mask_does_not_describe():
         fa.flash_attention(q, q[:, :, :16], q[:, :, :16], block_diffusion=4)
 
 
-# the Mosaic bodies of the causal and full kernels as the parent commit
-# lowered them, printed without locations (sha256, first 20 hex digits)
+# the Mosaic bodies of the kernels in each mask mode as the commit before the
+# modes became objects lowered them, printed without locations (sha256, first
+# 20 hex digits; fwd, dq, dkv): (length, group, mode) -> bodies
 PARENT_BODIES = {
-    (True, 1024, 2): ("97d104ff72cfd28513a9", "56add080d14af5360fcb",
-                      "8a2b953ff358c3c3175a"),
-    (False, 1000, 1): ("3561fe088684616d4515", "1090a4a33d203656b31f",
-                       "f428e6a3363011bb077f"),
+    "causal": (1024, 2, {"causal": True}, (
+        "97d104ff72cfd28513a9", "56add080d14af5360fcb",
+        "8a2b953ff358c3c3175a")),
+    "full, padded": (1000, 1, {}, (
+        "3561fe088684616d4515", "1090a4a33d203656b31f",
+        "f428e6a3363011bb077f")),
+    "block diffusion, per copy": (1024, 2, {"block_diffusion": 4}, (
+        "3ec4bb417377d317d1c3", "6ab8d559ff607d630f47",
+        "80f75c434a23bda8bd53")),
+    "block diffusion, general": (1200, 2, {"block_diffusion": 4}, (
+        "f38f597cc59b56dd4f3b", "bdb370a30cfaca137e7e",
+        "0056cfce9e96d9198f70")),
+    "selection": (1024, 2, {"select": True}, (
+        "51a41db13e36e780682d", "ce09087370ce3544f5cc",
+        "9c81911b60de8523a5aa")),
 }
 
 
-def _bodies(causal, length, group, block=0):
+def _bodies(length, group, mode):
+    """The (kernel names, body hashes) of the forward and both gradients of
+    one call in ``mode``: ``flash_attention``'s keywords, or ``{"select":
+    True}`` for ``sparse_flash_attention`` over int32 words."""
     from jax._src.lib.mlir import ir
 
     q = jax.ShapeDtypeStruct((1, 4 * group, length, 128), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 4, length, 128), jnp.bfloat16)
+    args = (q, kv, kv)
+    if mode.get("select"):
+        lp = -(-length // fa.select_block(length)) * fa.select_block(length)
+        words = jax.ShapeDtypeStruct((1, lp // 32, lp), jnp.int32)
+        args += (words, words)
 
-    def loss(q, k, v):
-        return fa.flash_attention(q, k, v, causal=causal,
-                                  block_diffusion=block).astype(
-            jnp.float32).sum()
+        def attend(q, k, v, bits, bits_t):
+            return fa.sparse_flash_attention(q, k, v, bits, bits_t)[0]
+    else:
+        def attend(q, k, v):
+            return fa.flash_attention(q, k, v, **mode)
+
+    def loss(*a):
+        return attend(*a).astype(jnp.float32).sum()
 
     with pk.compute_on("tpu"):
         exp = jax.export.export(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-                                platforms=["tpu"])(q, kv, kv)
+                                platforms=["tpu"])(*args)
     text = exp.mlir_module()
     names = re.findall(r'kernel_name = "(\w+)"', text)
     out = []
@@ -286,17 +366,12 @@ def _bodies(causal, length, group, block=0):
     return names, tuple(out)
 
 
-@pytest.mark.parametrize("causal, length, group", sorted(PARENT_BODIES))
-def test_the_causal_and_full_kernels_lower_as_they_did(causal, length, group):
-    names, bodies = _bodies(causal, length, group)
+@pytest.mark.parametrize("mode", sorted(PARENT_BODIES))
+def test_each_mask_mode_lowers_as_it_did(mode):
+    length, group, kwargs, want = PARENT_BODIES[mode]
+    names, bodies = _bodies(length, group, kwargs)
     assert names == ["mx_flash_fwd", "mx_flash_dq", "mx_flash_dkv"]
-    assert bodies == PARENT_BODIES[(causal, length, group)]
-
-
-def test_the_block_diffusion_kernels_lower_for_the_chip():
-    names, bodies = _bodies(False, 1024, 2, block=4)
-    assert names == ["mx_flash_fwd", "mx_flash_dq", "mx_flash_dkv"]
-    assert not set(bodies) & set(PARENT_BODIES[(True, 1024, 2)])
+    assert bodies == want
 
 
 # ---------------------------------------------------------------------------
